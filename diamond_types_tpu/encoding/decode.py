@@ -311,7 +311,7 @@ _native_decode_ok = True  # negative cache: set False on any native failure
 
 def _try_decode_native(data: bytes):
     """Native fresh-load probe with the same broad exception guard +
-    negative caching the codec paths use (native/core.py::_codec_load):
+    negative caching the library loader uses (native/core.py::_load):
     ANY native failure — missing .so, CDLL OSError, stale ABI missing
     dt_decode_new — degrades to the Python decoder instead of breaking
     load_oplog. Genuine corruption (NativeParseError) still raises: the

@@ -3,14 +3,17 @@
 from __future__ import annotations
 
 import ctypes as ct
-import os
+import sys
 from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from .build import OUT as _SO_PATH, build as _build
+from .build import NativeBuildError, build as _build
 
 _lib = None
+# why the build or load failed, once it has (negative cache: a failed
+# g++ run is not retried per call). None while untried or loaded.
+_load_error: Optional[str] = None
 
 # Underwater sentinel base (ids at or above this are pre-zone placeholder
 # text, not real op LVs) — one definition, shared with native/dt_core.cpp's
@@ -19,23 +22,22 @@ from ..core.span import UNDERWATER_START as UNDERWATER  # noqa: E402
 
 
 def _load():
-    global _lib
-    if _lib is not None:
+    """The loaded library, or None when the build or the load failed —
+    library callers then take their pure-Python paths (~1/100 of the
+    speed). The reason is kept in `_load_error`; `native.require_native`
+    turns it into an error where the slow engine must not pass unseen
+    (serve() start-up, chip_smoke.py)."""
+    global _lib, _load_error
+    if _lib is not None or _load_error is not None:
         return _lib
-    path = _build()
-    if path is None or not os.path.exists(path):
-        return None
-    lib = ct.CDLL(path)
     try:
+        lib = ct.CDLL(_build())
         _configure(lib)
-    except AttributeError as e:
-        # an .so predating the current symbol set (e.g. checkout with
-        # equal mtimes skipping the rebuild): degrade to the Python
-        # fallbacks instead of crashing every native call site
-        import sys
-        sys.stderr.write(f"stale native library ({e}); native paths "
-                         f"disabled — rebuild with python -m "
-                         f"diamond_types_tpu.native.build --force\n")
+    except (NativeBuildError, OSError, AttributeError) as e:
+        # AttributeError: a symbol _configure declares is missing
+        _load_error = f"{e.__class__.__name__}: {e}"
+        sys.stderr.write(f"native host core unavailable ({_load_error}); "
+                         "library callers use the pure-Python engine\n")
         return None
     _lib = lib
     return lib
@@ -540,30 +542,8 @@ EVENT_COUNTER_NAMES = (
     "diff_calls")
 
 
-_codec_lib = False  # False = not probed yet; None = unavailable
-
-
-def _codec_load():
-    """Like _load() but with negative caching and a broad exception guard:
-    the codec fast paths sit on hot per-record loops and must degrade to
-    the pure-Python implementations on ANY native failure (stale/ABI-
-    incompatible .so, missing symbols, failed build) without re-probing
-    per call."""
-    global _codec_lib
-    if _codec_lib is False:
-        try:
-            lib = _load()
-            if lib is not None:
-                lib.dt_crc32c  # symbol presence check (stale .so)
-                lib.dt_lz4_compress
-            _codec_lib = lib
-        except Exception:  # noqa: BLE001 - any failure means "no native"
-            _codec_lib = None
-    return _codec_lib
-
-
 def crc32c_native(data: bytes, seed: int = 0):
-    lib = _codec_load()
+    lib = _load()
     if lib is None:
         return None
     buf = np.frombuffer(data, dtype=np.uint8)
@@ -571,7 +551,7 @@ def crc32c_native(data: bytes, seed: int = 0):
 
 
 def lz4_compress_native(data: bytes):
-    lib = _codec_load()
+    lib = _load()
     if lib is None:
         return None
     buf = np.ascontiguousarray(np.frombuffer(data, dtype=np.uint8))

@@ -25,31 +25,32 @@ from typing import Optional
 from ..text.op import DEL, INS, OpRun
 
 _ext = False  # False = not probed; None = unavailable
+_ext_error: Optional[str] = None   # why, when unavailable
 
 
 def _load_ext():
-    global _ext
+    """The extension module, or None when the kill switch is set or the
+    build/load failed (reason in `_ext_error`, surfaced by
+    `native.require_native`); callers then use `PySession`."""
+    global _ext, _ext_error
     if os.environ.get("DT_TPU_NO_NATIVE"):
         # the one kill switch every native fast path honors — an oracle
         # run must be genuinely native-free
         return None
     if _ext is False:
         try:
-            # unconditional: build_ingest no-ops when the .so is fresh,
+            # unconditional: build_ingest no-ops when the stamp matches,
             # and rebuilds when dt_ingest.cpp changed (loading a stale
             # binary would make the parity suite test old code)
             from .build import build_ingest
-            path = build_ingest()
-            if path:
-                spec = importlib.util.spec_from_file_location("_dtingest",
-                                                              path)
-                mod = importlib.util.module_from_spec(spec)
-                spec.loader.exec_module(mod)
-                _ext = mod
-            else:
-                _ext = None
-        except Exception:  # noqa: BLE001 - any failure means "no native"
+            spec = importlib.util.spec_from_file_location(
+                "_dtingest", build_ingest())
+            mod = importlib.util.module_from_spec(spec)
+            spec.loader.exec_module(mod)
+            _ext = mod
+        except Exception as e:  # noqa: BLE001 - any failure means "no native"
             _ext = None
+            _ext_error = f"{e.__class__.__name__}: {e}"
     return _ext
 
 

@@ -220,7 +220,7 @@ def _render_serve(b: _Builder, serve: dict) -> None:
         # device_calls_per_window is the N-dispatches-to-1 signal,
         # mesh_occupancy the super-batch padding efficiency
         for key in ("windows", "device_windows", "dispatches", "docs",
-                    "mesh_docs", "mesh_padded_rows"):
+                    "mesh_docs", "mesh_padded_rows", "shape_classes"):
             if key in window:
                 b.add(f"dt_serve_window_{key}_total", "counter",
                       window[key])

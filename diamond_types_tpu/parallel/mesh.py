@@ -24,17 +24,15 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-try:                       # jax >= 0.4.38 exports it at top level
-    from jax import shard_map
-except ImportError:        # pragma: no cover - version-dependent path
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..tpu.batch import replay_batch
+from ..tpu.runtime import devices
 
 
 def make_mesh(n_devices: int | None = None, axis: str = "docs") -> Mesh:
-    devs = jax.devices()
+    devs = devices()
     if n_devices is not None:
         devs = devs[:n_devices]
     return Mesh(np.array(devs), (axis,))
@@ -46,7 +44,7 @@ def serve_mesh(n_shards: int | None = None, axis: str = "docs") -> Mesh:
     covers the distinct devices actually used, capped at the shard
     count). This is the mesh the flush-window coordinator issues its
     single program over."""
-    devs = jax.devices()
+    devs = devices()
     n = len(devs) if n_shards is None else min(max(n_shards, 1),
                                                len(devs))
     return Mesh(np.array(devs[:n]), (axis,))
@@ -60,7 +58,7 @@ def serve_shard_devices(n_shards: int):
     count). Each SessionBank then builds and steps its sessions under
     `jax.default_device(...)` of its own device, so per-shard work is
     genuinely placed, not just labeled."""
-    devs = jax.devices()
+    devs = devices()
     return [devs[i % len(devs)] for i in range(n_shards)]
 
 
@@ -157,6 +155,52 @@ def mesh_flush_fn(mesh: Mesh, b: int, n: int, mi: int, cap: int):
     return fn
 
 
+def _home(arr):
+    """The one device an array lives on, or None if it is spread."""
+    devs = arr.devices()
+    return next(iter(devs)) if len(devs) == 1 else None
+
+
+def _gather_rows(sh: NamedSharding, rows, bp: int, row_shape: tuple,
+                 fill: int):
+    """Assemble `rows` (each resident on whatever chip its session
+    lives on) plus inert padding rows of `fill` into one `[bp, ...]`
+    int32 array sharded by `sh`, device-side: every row moves
+    chip-to-chip at most once, straight to the mesh device whose slice
+    it falls in, and is stacked there. (`jnp.stack` over rows committed
+    to different chips is an error, and over uncommitted rows a detour
+    through the default device.)"""
+    devs = list(sh.mesh.devices.flat)
+    per = bp // len(devs)
+    blocks = []
+    for k, dev in enumerate(devs):
+        part = [jax.device_put(r, dev)
+                for r in rows[k * per:(k + 1) * per]]
+        if len(part) < per:
+            pad = jnp.full(row_shape, fill, jnp.int32, device=dev)
+            part += [pad] * (per - len(part))
+        blocks.append(jnp.stack(part))
+    return jax.make_array_from_single_device_arrays(
+        (bp,) + row_shape, sh, blocks)
+
+
+def _rows_at(out, homes):
+    """Row i of a docs-sharded result as its own single-device array
+    on `homes[i]` (None: wherever it was computed), cut from the shard
+    that holds it — never from the global array, whose row slices
+    come back replicated on every chip of the mesh."""
+    rows = [None] * len(homes)
+    for shard in out.addressable_shards:
+        start = shard.index[0].start or 0
+        for j in range(shard.data.shape[0]):
+            i = start + j
+            if i < len(homes):
+                row = shard.data[j]
+                rows[i] = row if homes[i] is None \
+                    else jax.device_put(row, homes[i])
+    return rows
+
+
 def mesh_fused_replay(mesh: Mesh, sessions, plans):
     """Replay MANY shards' pending tails in ONE mesh-sharded program.
 
@@ -169,10 +213,18 @@ def mesh_fused_replay(mesh: Mesh, sessions, plans):
       * arena fast path — the previous window's donated output arrays
         are reused verbatim when the same session list recurs in the
         same shape class (zero staging, zero allocation);
-      * device-side gather — otherwise sessions' resident rows are
-        `jnp.stack`-ed and placed with `NamedSharding` without a host
-        round trip; only the host-built op PLAN arrays cross the
-        boundary (accounted as purpose="plan").
+      * device-side gather — otherwise sessions' resident rows move
+        chip-to-chip to the mesh device whose slice they fall in and
+        are stacked there (`_gather_rows`), without a host round trip;
+        only the host-built op PLAN arrays cross the boundary
+        (accounted as purpose="plan").
+
+    Committed rows go back to the chip each session lived on before
+    the window (`_rows_at`), so a bank's sessions stay on the bank's
+    device and its slot budget counts what that chip really holds.
+    Rows are batched in class order, not by placement: a row whose
+    session lives on another chip than the one that replays it crosses
+    the interconnect once each way.
 
     With `DEVICE_STAGE` disabled (the `--no-device-stage` control
     arm) the legacy host-numpy staging runs instead and every state
@@ -229,17 +281,10 @@ def mesh_fused_replay(mesh: Mesh, sessions, plans):
         docs_d, lens_d = reuse
     elif _arena.DEVICE_STAGE.enabled:
         # device-side gather: resident rows never visit host numpy
-        pad = bp - b
-        docs_d = jnp.stack([s.docs for s in sessions])
-        lens_d = jnp.stack([jnp.asarray(s.lens, jnp.int32)
-                            for s in sessions])
-        if pad:
-            docs_d = jnp.concatenate(
-                [docs_d, jnp.zeros((pad, cap), jnp.int32)])
-            lens_d = jnp.concatenate(
-                [lens_d, jnp.full((pad,), -1, jnp.int32)])
-        docs_d = jax.device_put(docs_d, sh)
-        lens_d = jax.device_put(lens_d, sh)
+        docs_d = _gather_rows(sh, [s.docs for s in sessions], bp,
+                              (cap,), 0)
+        lens_d = _gather_rows(sh, [jnp.asarray(s.lens, jnp.int32)
+                                   for s in sessions], bp, (), -1)
     else:
         # control arm: legacy host staging — every resident byte
         # round-trips through numpy and is accounted as staged
@@ -260,7 +305,12 @@ def mesh_fused_replay(mesh: Mesh, sessions, plans):
     t_fence = time.perf_counter()
     got = np.asarray(out_lens)
     device_s = time.perf_counter() - t_fence
-    ok = adopt_results(sessions, plans, out_docs, out_lens, got)
+    # each committed row goes back to the chip its session lives on
+    # (its bank's): a plain `out_docs[i]` of the sharded result comes
+    # back replicated over the whole mesh — one copy per chip
+    homes = [_home(s.docs) for s in sessions]
+    ok = adopt_results(sessions, plans, _rows_at(out_docs, homes),
+                       _rows_at(out_lens, homes), got)
     if _arena.DEVICE_STAGE.enabled:
         _arena.adopt(mesh, cap, mi, out_docs, out_lens, sessions,
                      ok, bp)

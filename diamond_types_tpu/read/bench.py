@@ -209,7 +209,7 @@ def run_read_bench(docs: int = 3, readers: int = 6,
 
     httpds, nodes, addrs = [], [], []
     for _ in range(2):
-        httpd = serve(port=0, serve_shards=serve_shards)
+        httpd = serve(port=0, serve_shards=serve_shards, engine="host")
         # the reader fleet opens a fresh connection per GET; the default
         # listen backlog (5) overflows under that churn whenever the
         # accept loop is briefly starved, and one dropped SYN costs the

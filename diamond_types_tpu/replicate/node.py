@@ -451,14 +451,22 @@ class ReplicaNode:
         g = self.writergroups.get(doc_id)
         if g is not None and epoch is not None and g.epoch > epoch:
             return False
-        if self._group_demote_drains:
-            sched = getattr(self.store, "scheduler", None)
-            if sched is not None:
-                sched.drain()
-        self.writergroups.drop(doc_id, at_or_below=epoch)
-        pending = getattr(self.store, "pending", None)
-        if pending is not None:
-            pending.pop(doc_id, None)
+        try:
+            if self._group_demote_drains:
+                sched = getattr(self.store, "scheduler", None)
+                if sched is not None:
+                    sched.drain()
+        finally:
+            # the fence completes whatever the drain did: a device
+            # error out of an inline drain (mesh windows) surfaces to
+            # the caller AFTER the registration is gone, so a demoted
+            # member can never keep admitting (every admitted op is in
+            # the oplog already; the drain only catches the device
+            # session up)
+            self.writergroups.drop(doc_id, at_or_below=epoch)
+            pending = getattr(self.store, "pending", None)
+            if pending is not None:
+                pending.pop(doc_id, None)
         return True
 
     def route_mutation(self, doc_id: str) -> str:
@@ -568,9 +576,11 @@ class ReplicaNode:
         active mergers: grant → drain → final patch → activate (the
         receiver's activate runs the quorum round for the new epoch).
         Any failure aborts back to ACTIVE (the remote GRANTED lease
-        simply expires). `override_version` (rebalancer migrations)
-        ships the placement-override entry ON the grant message, so the
-        receiver keeps the doc instead of rendezvous handing it back."""
+        simply expires); one that is not a peer's or the wire's — a
+        device error out of the drain — is re-raised after the abort.
+        `override_version` (rebalancer migrations) ships the
+        placement-override entry ON the grant message, so the receiver
+        keeps the doc instead of rendezvous handing it back."""
         t0 = time.monotonic()
         new_epoch = self.leases.begin_handoff(doc_id)
         if new_epoch is None:
@@ -647,8 +657,7 @@ class ReplicaNode:
             self.metrics.observe_handoff_latency(time.monotonic() - t0)
             span.end(outcome="completed")
             return True
-        except (OSError, ValueError, KeyError,
-                urllib.error.HTTPError) as e:
+        except Exception as e:
             self.leases.abort_handoff(doc_id)
             self.metrics.bump("handoffs", "failed")
             if self.obs is not None:
@@ -657,7 +666,14 @@ class ReplicaNode:
                     epoch=new_epoch,
                     error=f"{e.__class__.__name__}: {e}"[:120])
             span.end(outcome="failed")
-            return False
+            if isinstance(e, (OSError, ValueError, KeyError,
+                              urllib.error.HTTPError)):
+                return False
+            # anything else — a device error out of the inline drain —
+            # is not a peer's refusal: the handoff is aborted back to
+            # ACTIVE like any other (the doc stays with an owner whose
+            # oplog is whole) and the error goes on to the caller
+            raise
 
     # ---- lease wire handler (receiver) -----------------------------------
 

@@ -112,7 +112,8 @@ def run_rebalance_soak(servers: int = 3, docs: int = 8, seed: int = 7,
     addrs: List[str] = []
 
     def boot(join_to: Optional[str] = None):
-        httpd = serve(port=0, serve_shards=1, follower_reads=True,
+        httpd = serve(port=0, serve_shards=1, engine="host",
+                      follower_reads=True,
                       obs_opts=dict(obs_opts))
         httpd.socket.listen(128)
         addr = f"127.0.0.1:{httpd.server_address[1]}"
@@ -125,7 +126,8 @@ def run_rebalance_soak(servers: int = 3, docs: int = 8, seed: int = 7,
         return httpd, node, addr
 
     for i in range(servers):
-        httpd = serve(port=0, serve_shards=1, follower_reads=True,
+        httpd = serve(port=0, serve_shards=1, engine="host",
+                      follower_reads=True,
                       obs_opts=dict(obs_opts))
         httpd.socket.listen(128)
         httpds.append(httpd)
@@ -474,7 +476,8 @@ def run_split_soak(servers: int = 3, docs: int = 4, seed: int = 11,
     nodes: List = []
     addrs: List[str] = []
     for i in range(servers):
-        httpd = serve(port=0, serve_shards=1, follower_reads=True,
+        httpd = serve(port=0, serve_shards=1, engine="host",
+                      follower_reads=True,
                       obs_opts=dict(obs_opts))
         httpd.socket.listen(128)
         httpds.append(httpd)

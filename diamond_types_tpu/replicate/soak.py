@@ -131,7 +131,7 @@ def run_replicate_soak(servers: int = 3, docs: int = 4, rounds: int = 20,
         # follower_reads gives each owner a FollowerIndex, whose advert
         # hook closes journeys at advert_usable — without it the
         # verdict's convergence-lag column exists but never populates.
-        httpd = serve(port=port, serve_shards=serve_shards,
+        httpd = serve(port=port, serve_shards=serve_shards, engine="host",
                       data_dir=dirs[i], follower_reads=True,
                       obs_opts=dict(sample_rate=1.0))
         addr = f"127.0.0.1:{httpd.server_address[1]}"
@@ -149,7 +149,7 @@ def run_replicate_soak(servers: int = 3, docs: int = 4, rounds: int = 20,
 
     for i in range(servers):
         dirs.append(_dir(i))
-        httpd = serve(port=0, serve_shards=serve_shards,
+        httpd = serve(port=0, serve_shards=serve_shards, engine="host",
                       data_dir=dirs[i], follower_reads=True,
                       obs_opts=dict(sample_rate=1.0))
         httpds.append(httpd)

@@ -13,7 +13,7 @@ shape-bucketed, per-shard batches:
                    backpressure (JIT dynamic batching, arxiv 1904.07421)
   * `bank`       — per-shard DeviceZoneSession bank with LRU eviction
                    and device-slot capacity accounting
-  * `metrics`    — JSON-exportable counters for bench.py / soak tools
+  * `metrics`    — JSON-exportable counters for serve-bench / soak tools
   * `scheduler`  — the composition: DocStore-facing submit/pump/drain
   * `driver`     — trace-replay bench driver (cli serve-bench) with a
                    byte-parity gate against the single-engine merge

@@ -1,19 +1,21 @@
 """JSON-exportable scheduler metrics.
 
-One plain-counter surface shared by bench.py, the soak tools and the
-sync server's /metrics endpoint. Everything here is host-side Python
+One plain-counter surface shared by serve-bench, the soak tools,
+chip_smoke.py and the sync server's /metrics endpoint. Everything here is host-side Python
 ints/floats — recording a sample never touches the device, so the
 metrics path can run inside flush loops without perturbing timings.
 
 Schema (snapshot()):
 
-  {"version": 7,                   # counter-set schema; bump on change
+  {"version": 14,                  # counter-set schema; bump on change
    "uptime_s": s,                  # monotonic since construction
    "shards": N, "flush_docs": B,
    "totals": {"submits", "coalesced", "rejects", "denied", "fenced",
               "flushes", "flushed_docs", "flushed_ops", "builds",
               "evictions", "resyncs", "syncs", "host_fallbacks",
-              "fused_calls", "fused_docs"},
+              "fused_calls", "fused_docs", "device_errors",
+              "warmup_errors", "pump_errors", "reads_from_device",
+              "reads_from_host"},
    "batch_occupancy": mean(flush size) / flush_docs,   # 0..1
    "host_fallback_ratio": host_fallbacks / max(syncs, 1),
    "flush_reasons": {"size": n, "deadline": n, "force": n},
@@ -24,6 +26,7 @@ Schema (snapshot()):
    "window": {"windows", "device_windows", "dispatches",
               "device_calls_per_window",      # N->1 dispatch signal
               "docs", "mesh_docs", "mesh_padded_rows",
+              "shape_classes",                # one program per class
               "mesh_occupancy",               # docs / padded rows
               "shards_hist": {"2": n, ...}},  # shards per window
    "transform": {"device_docs", "host_docs", "fallbacks", "batches",
@@ -55,7 +58,18 @@ from ..obs.hist import Histogram
 _SHARD_KEYS = ("submits", "coalesced", "rejects", "denied", "fenced",
                "flushes", "flushed_docs", "flushed_ops", "builds",
                "evictions", "resyncs", "syncs", "host_fallbacks",
-               "fused_calls", "fused_docs", "pallas_fallbacks")
+               "fused_calls", "fused_docs",
+               # failures that are NOT data faults (host_fallbacks counts
+               # those): an exception out of a device rung or a session
+               # build, a failed warm-up compile, an exception a pump or
+               # flush-worker loop had to survive. Each comes with a
+               # flight-recorder event carrying the error text; all three
+               # are 0 on a healthy server.
+               "device_errors", "warmup_errors", "pump_errors",
+               # bank.text() answers by source: the resident device
+               # session, or the host checkout (host engine, session
+               # missing or behind the oplog, not the doc's owner)
+               "reads_from_device", "reads_from_host")
 
 # the residency tier's counter set (serve.hydrate.Hydrator feeds these
 # through record_hydration; hydrate.py imports the tuple so the two
@@ -131,8 +145,14 @@ class ServeMetrics:
     # v13 = shape-steered device-resident staging (`staged_bytes` /
     # `staged_bytes_per_window` in the window block — host->device
     # bytes the mesh windows' state staging paid; near-zero when the
-    # arena / device-side gather keeps rows resident)
-    SCHEMA_VERSION = 13
+    # arena / device-side gather keeps rows resident);
+    # v14 = no fallback that hides the device: `pallas_fallbacks` left
+    # with the behaviour it counted (a rung that raises now propagates),
+    # `device_errors` / `warmup_errors` / `pump_errors` count what used
+    # to be swallowed, `reads_from_device` / `reads_from_host` say
+    # where bank.text() answered from, and `window.shape_classes`
+    # counts the (cap, max_ins) classes the mesh windows held
+    SCHEMA_VERSION = 14
 
     def __init__(self, n_shards: int, flush_docs: int,
                  max_pending: int) -> None:
@@ -157,6 +177,7 @@ class ServeMetrics:
         self.mesh_docs = 0           # docs replayed via the mesh prog
         self.mesh_padded_rows = 0    # super-batch rows incl. padding
         self.window_staged_bytes = 0  # host->device staging paid
+        self.window_shape_classes = 0  # (cap, max_ins) classes held
         self.window_shards_hist: Dict[int, int] = {}
         # device-transform planning accounting (scheduler-level: the
         # batched dispatch is shared across a bucket)
@@ -228,7 +249,8 @@ class ServeMetrics:
     def record_window(self, dispatches: int, n_docs: int,
                       n_shards: int, mesh_docs: int = 0,
                       padded_rows: int = 0,
-                      staged_bytes: int = 0) -> None:
+                      staged_bytes: int = 0,
+                      shape_classes: int = 0) -> None:
         """One flush window: `dispatches` device programs (mesh path:
         the number of shard_map calls, 1 for a uniform-shape window) or
         per-shard worker handoffs (the PR-5 control, >= n_shards when
@@ -236,7 +258,9 @@ class ServeMetrics:
         `n_shards` shards. `device_calls_per_window` in the snapshot is
         dispatches / windows-with-device-work — the N-to-1 dispatch
         claim, directly. `staged_bytes` is the host->device staging
-        the window's mesh dispatches paid (v13)."""
+        the window's mesh dispatches paid (v13). `shape_classes` is the
+        number of (cap, max_ins) classes a mesh window held: a mixed
+        window takes one program per class (v14)."""
         with self._lock:
             self.windows += 1
             if dispatches > 0:
@@ -246,6 +270,7 @@ class ServeMetrics:
             self.mesh_docs += mesh_docs
             self.mesh_padded_rows += padded_rows
             self.window_staged_bytes += staged_bytes
+            self.window_shape_classes += shape_classes
             self.window_shards_hist[n_shards] = \
                 self.window_shards_hist.get(n_shards, 0) + 1
 
@@ -372,6 +397,7 @@ class ServeMetrics:
                 "docs": self.window_docs,
                 "mesh_docs": self.mesh_docs,
                 "mesh_padded_rows": self.mesh_padded_rows,
+                "shape_classes": self.window_shape_classes,
                 "mesh_occupancy": round(
                     self.mesh_docs
                     / max(self.mesh_padded_rows, 1), 4),

@@ -27,7 +27,7 @@ bank planning never races handler threads mutating the oplog) — while
 device execution is guarded by a PER-DEVICE lock (shards placed on the
 same chip share one; distinct chips flush concurrently). The one
 remaining process-global serialization point is first-touch JAX
-backend init (`bank._ensure_jax_ready`), which is not thread-safe and
+backend init (`tpu.runtime.first_touch`), which is not thread-safe and
 runs exactly once. Lock order is always
 global → shard → sync(oplog) → device, never reversed. Intended
 callers: (a) HTTP handler threads submitting and reading, (b) pump
@@ -104,9 +104,11 @@ class MergeScheduler:
         see `_flush_window`. `device_plan=True` (fused device engine
         only) plans tails through the device transform
         (tpu/xform.plan_tails_device) instead of the host tracker walk;
-        `pallas=True` adds the Pallas step-kernel replay rung at the
-        top of the flush ladder (pallas → mesh → fused → per-doc →
-        host), each rung falling back to the next on failure."""
+        `pallas=True` replays through the Pallas step kernel where one
+        device holds the window. The ladder below a replay (per-doc →
+        host) answers DATA faults only — an overflowing tail, a
+        poisoned or drifting length. A rung that raises is counted
+        (`device_errors`), recorded and re-raised: see serve/bank.py."""
         self.resolve = resolve
         self._sync_lock = sync_lock if sync_lock is not None \
             else contextlib.nullcontext()
@@ -116,9 +118,14 @@ class MergeScheduler:
                                     flush_deadline_s=flush_deadline_s)
         self.metrics = ServeMetrics(n_shards, flush_docs, max_pending)
         devices: List = [None] * n_shards
-        if place_on_devices and engine == "device":
-            from ..parallel.mesh import serve_shard_devices
-            devices = serve_shard_devices(n_shards)
+        if engine == "device":
+            # the process's first JAX touch: raises here, at
+            # construction, when there is no device to be an engine of
+            from ..tpu.runtime import first_touch
+            first_touch()
+            if place_on_devices:
+                from ..parallel.mesh import serve_shard_devices
+                devices = serve_shard_devices(n_shards)
         self.fused = bool(fused) and engine == "device"
         # mesh flush windows ride on fused sessions (the super-batch is
         # assembled from FusedDocSession plan rows)
@@ -411,12 +418,38 @@ class MergeScheduler:
             reason, items = job
             try:
                 self._flush_items(shard, reason, items)
-            except Exception:   # pragma: no cover - keep the shard alive
-                pass
+            except Exception as e:      # keep the shard alive, loudly
+                self._loop_error("flush_worker", shard, e)
             finally:
                 with self._idle_cv:
                     self._inflight -= 1
                     self._idle_cv.notify_all()
+
+    def _loop_error(self, where: str, shard: int,
+                    exc: BaseException) -> None:
+        """A pump or flush-worker thread survived an exception: the
+        thread must keep serving, so the failure is counted
+        (`pump_errors`), recorded with its text and printed — the
+        taken work is lost to this flush (its ops stay durable; reads
+        answer from the host checkout, counted `reads_from_host`).
+        Filed under the shard the failing bank tagged the exception
+        with (`bank.device_error`), else under the caller's `shard`."""
+        shard = getattr(exc, "dt_shard", shard)
+        self.metrics.bump(shard, "pump_errors")
+        if self.obs is not None:
+            self.obs.recorder.record(
+                "pump_error", where=where, shard=shard,
+                error=f"{exc.__class__.__name__}: {exc}"[:400])
+        import sys
+        import traceback
+        print(f"[dt] {where} (shard {shard}) raised:", file=sys.stderr)
+        traceback.print_exception(exc, file=sys.stderr)
+
+    def join_warmup(self, timeout: Optional[float] = None) -> None:
+        """Wait for the banks' background warm-up and raise if a
+        kernel it was asked for failed to compile."""
+        for bank in self.banks:
+            bank.join_warmup(timeout=timeout)
 
     def _wait_idle(self, timeout: float = 30.0) -> None:
         """Block until every dispatched batch has been flushed."""
@@ -510,7 +543,15 @@ class MergeScheduler:
         epoch this host no longer holds is dropped (`fenced`), never
         merged — its ops are still in the oplog for the new owner.
         The hydration gate runs second (residency recheck: see
-        `_hydration_gate`)."""
+        `_hydration_gate`).
+
+        A device error out of the bank propagates (to the flush worker
+        or pump loop, which count it, or to an inline caller). It ends
+        the spans marked `error=` and takes this batch's accounting
+        with it — `record_flush`, queue waits, read invalidation (cache
+        hygiene only: the read cache is frontier-keyed). Groups of the
+        batch that committed before the raise keep their device state;
+        the rest stay behind their oplogs until their next flush."""
         obs = self.obs
         items = self._fence(shard, items)
         items = self._hydration_gate(shard, items)
@@ -527,21 +568,26 @@ class MergeScheduler:
                            "docs": len(items)})
         bank = self.banks[shard]
         t0 = time.perf_counter()
-        with self._shard_locks[shard]:
-            # one device_sync span per taken batch — the whole bucket
-            # is (at best) ONE device call now, so per-doc spans would
-            # misrepresent the execution shape
-            dspan = NOOP_SPAN if not fspan.sampled else \
-                obs.tracer.start("serve.device_sync",
-                                 parent=fspan.context(),
-                                 attrs={"docs": len(items)})
-            res = bank.sync_docs(
-                items, self._flush_resolve, oplog_lock=self._sync_lock,
-                device_lock=self._device_locks[shard])
-            dspan.end(fused_calls=res["fused_calls"],
-                      fused_docs=res["fused_docs"])
-        dur = time.perf_counter() - t0
-        fspan.end(dur_s=round(dur, 6))
+        # the spans are context managers so a raise out of the bank
+        # (a device error) ends them with `error=<type>`, not never
+        with fspan:
+            with self._shard_locks[shard]:
+                # one device_sync span per taken batch — the whole
+                # bucket is (at best) ONE device call now, so per-doc
+                # spans would misrepresent the execution shape
+                dspan = NOOP_SPAN if not fspan.sampled else \
+                    obs.tracer.start("serve.device_sync",
+                                     parent=fspan.context(),
+                                     attrs={"docs": len(items)})
+                with dspan:
+                    res = bank.sync_docs(
+                        items, self._flush_resolve,
+                        oplog_lock=self._sync_lock,
+                        device_lock=self._device_locks[shard])
+                    dspan.end(fused_calls=res["fused_calls"],
+                              fused_docs=res["fused_docs"])
+            dur = time.perf_counter() - t0
+            fspan.end(dur_s=round(dur, 6))
         self.metrics.record_flush(
             shard, len(items), sum(i.n_ops for i in items), reason,
             dur_s=dur)
@@ -595,12 +641,15 @@ class MergeScheduler:
              class (uniform-shape window ⇒ exactly one dispatch);
           4. per-shard adoption (`bank.adopt_window`): poisoned /
              length-drift rows evict to the host oracle, serial
-             leftovers run the per-doc ladder — the SAME fallback
-             ladder as the per-shard path, one rung higher.
+             leftovers run the per-doc ladder — the SAME data-fault
+             ladder as the per-shard path.
 
-        A mesh replay failure drops its rows to the per-shard fused
-        rung (`_window_mesh_fallback`) before the per-doc/host rungs,
-        so the ladder is strictly widened, never bypassed.
+        A replay that RAISES (compiler, runtime) is counted on the
+        shard of the class's first row, recorded, and re-raised once
+        the window is wound up: its class and the classes after it are
+        not replayed (plans are pure, so those sessions simply stay
+        behind their oplogs until their next flush), while the classes
+        that committed before it are adopted and accounted as usual.
 
         Lock order: shard locks (sorted) → oplog lock (inside
         plan/adopt) → device locks (sorted, deduped); the mesh device
@@ -661,6 +710,7 @@ class MergeScheduler:
             dispatches = mesh_docs = padded_rows = staged_bytes = 0
             failed: List[List[str]] = [[] for _ in entries]
             replayed: List[set] = [set() for _ in entries]
+            err: Optional[BaseException] = None
             for (cap, mi), rows in sorted(classes.items()):
                 sessions = [r[2] for r in rows]
                 plans = [r[3] for r in rows]
@@ -674,54 +724,32 @@ class MergeScheduler:
                             parent=fspan.context(),
                             attrs={"docs": len(rows), "cap": cap,
                                    "max_ins": mi})
-                    ok = None
                     staged = 0
-                    if self.pallas and len(dlocks) <= 1:
-                        # top rung: the Pallas step-kernel replay.
-                        # Single-device windows only — the Pallas
-                        # program is not mesh-sharded, so a window
-                        # spanning devices goes straight to the mesh
-                        # rung. Any failure falls through with the
-                        # rows untouched (commits happen only at the
-                        # adopt_results fence inside a successful
-                        # replay).
-                        from ..tpu import flush_fuse as _ff
-                        try:
+                    # the Pallas step-kernel replay takes single-device
+                    # windows only — its program is not mesh-sharded,
+                    # so a window spanning devices takes the mesh one
+                    rung = "pallas" if self.pallas and len(dlocks) <= 1 \
+                        else "mesh"
+                    try:
+                        if rung == "pallas":
+                            from ..tpu import flush_fuse as _ff
                             ok, device_s = _ff.pallas_fused_replay(
                                 sessions, plans)
-                            dispatches += 1
                             dspan.end(rung="pallas")
-                        except Exception as e:
-                            ok = None
-                            if obs is not None:
-                                obs.recorder.record(
-                                    "pallas_window_fallback",
-                                    docs=len(rows), cap=cap,
-                                    error=f"{e.__class__.__name__}: "
-                                          f"{e}"[:120])
-                    if ok is None:
-                        try:
+                        else:
                             ok, device_s, bp, staged = \
                                 mesh_fused_replay(mesh, sessions, plans)
-                            dispatches += 1
                             mesh_docs += len(rows)
                             padded_rows += bp
                             staged_bytes += staged
                             dspan.end(padded_b=bp, staged_bytes=staged)
-                        except Exception as e:
-                            # mesh rung failed: these rows drop to the
-                            # per-shard fused rung; whatever that can't
-                            # recover falls per-doc/host in adoption
-                            if obs is not None:
-                                obs.recorder.record(
-                                    "mesh_window_fallback",
-                                    docs=len(rows), cap=cap,
-                                    error=f"{e.__class__.__name__}: "
-                                          f"{e}"[:120])
-                            ok, device_s, calls = \
-                                self._window_mesh_fallback(rows)
-                            dispatches += calls
-                            dspan.end(outcome="fallback")
+                    except Exception as e:
+                        dspan.end(error=e.__class__.__name__)
+                        self.banks[rows[0][1]].device_error(
+                            rung, e, docs=len(rows), cap=cap)
+                        err = e
+                        break
+                    dispatches += 1
                 wall = time.perf_counter() - t_cls
                 PROFILER.observe_window(wall, device_s, len(rows),
                                         len(shards),
@@ -755,11 +783,14 @@ class MergeScheduler:
                     for it in items:
                         self.read_invalidate(it.doc_id)
         dur = time.perf_counter() - t0
+        if err is not None:
+            fspan.annotate(error=err.__class__.__name__)
         fspan.end(dur_s=round(dur, 6), dispatches=dispatches)
         self.metrics.record_window(dispatches, n_docs, len(shards),
                                    mesh_docs=mesh_docs,
                                    padded_rows=padded_rows,
-                                   staged_bytes=staged_bytes)
+                                   staged_bytes=staged_bytes,
+                                   shape_classes=len(classes))
         # live telemetry (mirrors _flush_items): queue waits, a flush
         # exemplar off the window span, per-doc attribution
         now_m = time.monotonic()
@@ -775,41 +806,9 @@ class MergeScheduler:
         if obs is not None and fspan.sampled:
             obs.exemplars.note("serve.flush", dur,
                                fspan.context().trace_id)
+        if err is not None:
+            raise err
         return n_docs
-
-    def _window_mesh_fallback(self, rows):
-        """Mesh rung failed for one shape class: re-run its rows
-        through the PR-5 per-shard fused rung, grouped back by shard.
-        Rows a shard's replay can't recover (or whose replay raises
-        too) stay failed and fall to the per-doc/host rungs in
-        adoption. Returns (ok, device_s, dispatches) with `ok` aligned
-        to `rows`."""
-        from ..tpu.flush_fuse import fused_replay
-        ok = [False] * len(rows)
-        device_s = 0.0
-        calls = 0
-        by_shard: Dict[int, List[int]] = {}
-        for idx, (_ei, s, _sess, _plan, _d) in enumerate(rows):
-            by_shard.setdefault(s, []).append(idx)
-        for s, idxs in sorted(by_shard.items()):
-            bank = self.banks[s]
-            sess = [rows[i][2] for i in idxs]
-            plans = [rows[i][3] for i in idxs]
-            try:
-                if bank.device is not None:
-                    import jax
-                    with jax.default_device(bank.device):
-                        oks, ds = fused_replay(sess, plans)
-                else:
-                    oks, ds = fused_replay(sess, plans)
-                calls += 1
-                device_s += ds
-                self.metrics.record_fused(s, len(idxs))
-                for i, good in zip(idxs, oks):
-                    ok[i] = good
-            except Exception:
-                pass    # rows stay failed → host fallback in adoption
-        return ok, device_s, calls
 
     def drain(self) -> int:
         """Flush everything regardless of triggers (shutdown, rebalance,
@@ -860,6 +859,7 @@ class MergeScheduler:
         # not serve (or refresh) its device session for the doc — the
         # durable oplog is the only truth it still holds
         if self.admit is not None and not self.admit(doc_id):
+            self.metrics.bump(shard, "reads_from_host")
             with self._sync_lock:
                 return ol.checkout_tip().snapshot()
         with self._shard_locks[shard]:
@@ -902,8 +902,8 @@ class MergeScheduler:
             while not self._pump_stop.wait(interval):
                 try:
                     self.pump()
-                except Exception:       # pragma: no cover - keep pumping
-                    pass
+                except Exception as e:      # keep pumping, loudly
+                    self._loop_error("pump", 0, e)
 
         self._pump_thread = threading.Thread(target=loop, daemon=True)
         self._pump_thread.start()
@@ -920,6 +920,8 @@ class MergeScheduler:
             self._pump_thread.join(timeout=2)
             self._pump_thread = None
         self._pump_stop = threading.Event()
-        if drain:
-            self.drain()
-        self.stop_workers()
+        try:
+            if drain:
+                self.drain()    # inline: a device error raises here
+        finally:
+            self.stop_workers()

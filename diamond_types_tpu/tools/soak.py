@@ -28,7 +28,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
 import random
 import sys
 import time
@@ -37,8 +36,6 @@ import traceback
 from ..causalgraph.summary import (intersect_with_summary,
                                    summarize_versions)
 
-_REPO_ROOT = os.path.dirname(os.path.dirname(
-    os.path.dirname(os.path.abspath(__file__))))
 from ..encoding.decode import decode_into, load_oplog
 from ..encoding.encode import ENCODE_FULL, ENCODE_PATCH, encode_oplog
 from ..text.crdt import ListCRDT, merge_oplogs
@@ -172,35 +169,7 @@ def main(argv=None) -> int:
     ops_total = 0
     seed = args.seed0
 
-    _bench_mod = []
-
-    def _bench_active() -> bool:
-        # official bench runs must not compete with the soak for CPU
-        # (bench.py bench_is_active; imported lazily so the soak works
-        # from an installed package without the repo-root driver too).
-        # One-time import: this is polled every 5 s for hours, so the
-        # sys.path edit and import scan must not repeat per call.
-        if not _bench_mod:
-            try:
-                if _REPO_ROOT not in sys.path:
-                    sys.path.insert(0, _REPO_ROOT)
-                import bench as _b
-                _bench_mod.append(_b)
-            except Exception:
-                _bench_mod.append(None)
-        if _bench_mod[0] is None:
-            return False
-        try:
-            return _bench_mod[0].bench_is_active()
-        except Exception:
-            return False
-
     while args.count == 0 or done < args.count:
-        if _bench_active():
-            emit({"event": "paused", "why": "bench.py run in flight"})
-            while _bench_active():
-                time.sleep(5)
-            emit({"event": "resumed"})
         try:
             stats = run_seed(seed)
             ops_total += stats["ops"]

@@ -32,7 +32,7 @@ returned length against the host-side projection; any drift evicts the
 session and the bank serves the doc from `oplog.checkout_tip()`.
 
 Everything device-touching imports jax lazily: the serve tier's host
-engine (the HTTP server default) must never pull in a backend.
+engine must never pull in a backend.
 """
 
 from __future__ import annotations
@@ -55,6 +55,13 @@ WARMUP_SHAPE_CLASSES = (1, 2, 4, 8)
 _fused_jit_cache = {}
 from ..analysis.witness import make_lock as _make_lock
 _fused_jit_lock = _make_lock("fused_jit", "leaf")
+
+
+class FenceFailure(RuntimeError):
+    """A replay came back with a poisoned (-1) or drifting length: a
+    DATA fault. The bank answers it by evicting the session to the host
+    oracle. Every other exception out of a device rung is a compiler or
+    runtime failure and propagates."""
 
 
 def make_replay_body(mi: int):
@@ -151,12 +158,13 @@ def make_pallas_replay_body(mi: int, interpret: bool):
 
 def _pallas_fn(b: int, n: int, mi: int, cap: int):
     """Jitted Pallas-rung replay, cache "pallas" — same pow2 shape-class
-    discipline as `_fused_fn`. Off-TPU backends run the kernel
-    interpreted (the pallas_guide.md debugging convention), so the rung
-    stays exercisable on the CPU-simulated mesh."""
+    discipline as `_fused_fn`. Off the TPU the kernel runs interpreted
+    (`runtime.pallas_interpret`), so the rung stays exercisable on the
+    CPU-simulated mesh."""
     import jax
 
-    interpret = jax.default_backend() != "tpu"
+    from .runtime import pallas_interpret
+    interpret = pallas_interpret()
     key = (b, n, mi, cap, interpret)
     with _pallas_jit_lock:
         fn = _pallas_jit_cache.get(key)
@@ -176,8 +184,7 @@ def pallas_fused_replay(sessions: List["FusedDocSession"],
                         ) -> Tuple[List[bool], float]:
     """The ladder's TOP rung: fused bucket replay through the Pallas
     step kernel. Same packing, fences, and commit protocol as
-    `fused_replay`; the scheduler falls back to the mesh/fused rungs on
-    any failure here."""
+    `fused_replay`."""
     import jax.numpy as jnp
 
     b = len(sessions)
@@ -226,9 +233,10 @@ def warmup_fused_cache(flush_docs: int = 8, cap: int = DEFAULT_CAP,
     compile either (cache "mesh").
 
     `xform_classes` pre-compiles the device-transform dispatch
-    (tpu/xform.py, cache "xform") for those run-count classes, and
-    `pallas=True` pre-compiles the Pallas replay rung (cache "pallas")
-    for the same shape classes as the fused rung."""
+    (tpu/xform.py, cache "xform") for those run-count classes — its
+    Pallas variant under `pallas=True`, which also pre-compiles the
+    Pallas replay rung (cache "pallas") for the same shape classes as
+    the fused rung."""
     import jax
     import jax.numpy as jnp
 
@@ -291,7 +299,7 @@ def warmup_fused_cache(flush_docs: int = 8, cap: int = DEFAULT_CAP,
         for b in batches:
             for ncls in xform_classes:
                 n = _pow2(ncls)
-                fn = _xform_fn(b, n)
+                fn = _xform_fn(b, n, pallas)
                 parent = jnp.full((b, n), n, jnp.int32)
                 side = jnp.ones((b, n), jnp.int32)
                 keys = jnp.full((b, n), INT32_MAX, jnp.int32)
@@ -469,8 +477,8 @@ class FusedDocSession:
     def sync(self) -> int:
         """Per-doc path (the fused fallback ladder's last device rung):
         plan, then replay this doc alone at batch size 1. Resyncs on
-        capacity overflow. Raises on a poisoned result (the bank's
-        sync_doc catches, evicts and serves from the host engine)."""
+        capacity overflow. Raises `FenceFailure` on a poisoned result
+        (the bank's sync_doc evicts and serves from the host engine)."""
         plan = self.plan_tail()
         if not plan.fits(self.cap):
             self._materialize(
@@ -481,7 +489,7 @@ class FusedDocSession:
             return 0
         ok, _device_s = fused_replay([self], [plan])
         if not ok[0]:
-            raise RuntimeError(
+            raise FenceFailure(
                 "fused replay poisoned/mismatched length "
                 f"(doc_len {self.doc_len}, plan {plan.new_len})")
         return plan.n_ops

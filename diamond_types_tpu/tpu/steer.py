@@ -150,6 +150,11 @@ class ShapeSteer:
             self._cold_seen.pop(ckey, None)
             return bp0, n0
 
+    def classes(self, cache: str) -> list:
+        """The `(max_ins, cap, b, n)` classes `cache` has dispatched."""
+        with _steer_lock:
+            return sorted(self._warm.get(cache, ()))
+
     def snapshot(self) -> dict:
         with _steer_lock:
             c = dict(self._counts)

@@ -169,16 +169,16 @@ def _xform_single(parent, side, kp, ka, ks, ov, nv, pallas: bool):
     return perm.astype(jnp.int32), pos, new_len, peak
 
 
-def _xform_fn(b: int, n: int):
+def _xform_fn(b: int, n: int, pallas: bool = False):
     """Jitted batched transform for `b` docs x `n` run slots — static
     pow2 shape classes, same O(log^2) cache discipline as `_fused_fn`.
-    DT_TPU_PALLAS=1 routes the position-resolution scans through the
-    gather-free Pallas kernel (batch unrolled: vmap-of-pallas_call would
-    stack an illegal batch grid dim — see merge_kernel._jitted_kernel)."""
+    `pallas` (the serve bank's `pallas=True`, the one selector) routes
+    the position-resolution scans through the gather-free Pallas kernel
+    (batch unrolled: vmap-of-pallas_call would stack an illegal batch
+    grid dim — see merge_kernel._jitted_kernel)."""
     import jax
 
-    pallas = bool(os.environ.get("DT_TPU_PALLAS"))
-    key = (b, n, pallas)
+    key = (b, n, bool(pallas))
     with _xform_jit_lock:
         fn = _xform_jit_cache.get(key)
         from ..obs.devprof import note_jit_lookup
@@ -209,7 +209,8 @@ def xform_shape_class(extracts: Sequence[TailExtract]) -> Tuple[int, int]:
             _pow2(max(max(ex.n for ex in extracts), 1)))
 
 
-def resolve_positions(extracts: Sequence[TailExtract]
+def resolve_positions(extracts: Sequence[TailExtract],
+                      pallas: bool = False
                       ) -> List[Optional[TailPlan]]:
     """Device half: resolve every extract's document order + positions in
     ONE batched dispatch, then assemble TailPlans host-side. Runs outside
@@ -244,7 +245,7 @@ def resolve_positions(extracts: Sequence[TailExtract]
         nv[i, :k] = ex.new_vis
     from ..obs.devprof import note_transfer
     note_transfer(parent.nbytes * 5 + ov.nbytes + nv.nbytes)
-    fn = _xform_fn(bp, n)
+    fn = _xform_fn(bp, n, pallas)
     perm_d, pos_d, len_d, peak_d = fn(*(jnp.asarray(x) for x in
                                         (parent, side, kp, ka, ks, ov, nv)))
     perm_d = np.asarray(perm_d)
@@ -257,7 +258,7 @@ def resolve_positions(extracts: Sequence[TailExtract]
         try:
             plans.append(_assemble_plan(ex, perm_d[i], pos_d[i],
                                         int(len_d[i]), int(peak_d[i])))
-        except Exception:
+        except AssertionError:      # the cross-check, a data fence
             plans.append(None)
     return plans
 
@@ -309,7 +310,8 @@ def _assemble_plan(ex: TailExtract, perm: np.ndarray, pos: np.ndarray,
                     ex.doc_len + peak, ex.frontier, ex.synced_to)
 
 
-def plan_tails_device(sessions: Sequence, oplog_lock=None) -> Tuple[
+def plan_tails_device(sessions: Sequence, oplog_lock=None,
+                      pallas: bool = False) -> Tuple[
         List[TailPlan], dict]:
     """plan_tail_device over a bucket: host extracts under the oplog
     guard, device resolves outside it, per-doc host fallback for guard
@@ -326,7 +328,8 @@ def plan_tails_device(sessions: Sequence, oplog_lock=None) -> Tuple[
     plans: List[Optional[TailPlan]] = [
         h if isinstance(h, TailPlan) else None for h in halves]
     if extracts:
-        resolved = resolve_positions([h for _, h in extracts])
+        resolved = resolve_positions([h for _, h in extracts],
+                                     pallas=pallas)
         for (i, _), plan in zip(extracts, resolved):
             plans[i] = plan
     for i, plan in enumerate(plans):

@@ -150,8 +150,14 @@ def run_scenario(sc: Optional[Scenario], data_dir: Optional[str] = None,
                  incident_opts: Optional[dict] = None,
                  checkpoint_every_s: float = 0.0,
                  resume_dir: Optional[str] = None,
-                 stop_after_ticks: Optional[int] = None) -> dict:
-    """`qos=True` attaches the adaptive-admission controller to every
+                 stop_after_ticks: Optional[int] = None,
+                 engine: str = "host") -> dict:
+    """`engine` is every scenario server's merge-scheduler engine,
+    passed through to `serve()`: "host" (the engine every recorded
+    scorecard ran on) or "device" (the servers share this process's
+    chips). It rides the checkpoint like the other toggles.
+
+    `qos=True` attaches the adaptive-admission controller to every
     server and tags lanes with their class (interactive edits vs bulk
     imports); the scorecard then carries a `qos` block merged across
     the mesh. Default False keeps the static admission path byte-
@@ -188,6 +194,7 @@ def run_scenario(sc: Optional[Scenario], data_dir: Optional[str] = None,
             ck = json.load(f)
         sc = Scenario.from_dict(ck["scenario"])
         qos = bool(ck["qos"])
+        engine = ck.get("engine", engine)
         incidents = bool(ck["incidents"])
         incident_opts = ck.get("incident_opts") or incident_opts
         checkpoint_every_s = float(ck.get("checkpoint_every_s") or 0.0)
@@ -261,6 +268,7 @@ def run_scenario(sc: Optional[Scenario], data_dir: Optional[str] = None,
     def _serve_node(i: int, port: int = 0):
         boots[i] += 1
         httpd = serve(port=port, serve_shards=sc.serve_shards,
+                      engine=engine,
                       data_dir=dirs[i], follower_reads=True,
                       obs_opts=dict(
                           sample_rate=1.0, incidents=incidents,
@@ -461,7 +469,7 @@ def run_scenario(sc: Optional[Scenario], data_dir: Optional[str] = None,
         state = {
             "version": 1,
             "scenario": sc.to_dict(),
-            "qos": qos, "incidents": incidents,
+            "qos": qos, "incidents": incidents, "engine": engine,
             "incident_opts": incident_opts,
             "checkpoint_every_s": checkpoint_every_s,
             "tick": next_tick, "ticks": ticks, "ev_i": ev_i,

@@ -77,7 +77,7 @@ def _mesh(n, tmp_path, lease_ttl_s=5.0, serve_shards=1):
     httpds, addrs = [], []
     for i in range(n):
         httpd = serve(port=0, data_dir=str(tmp_path / f"s{i}"),
-                      serve_shards=serve_shards)
+                      engine="host", serve_shards=serve_shards)
         httpds.append(httpd)
         addrs.append(f"127.0.0.1:{httpd.server_address[1]}")
     nodes = []
@@ -182,7 +182,8 @@ def test_crash_restart_rejoins_and_never_reissues_epoch(tmp_path):
         # reboot on the same port + data dir
         from diamond_types_tpu.tools.server import serve
         httpd = serve(port=int(addrs[0].split(":")[1]),
-                      data_dir=str(tmp_path / "s0"), serve_shards=1)
+                      data_dir=str(tmp_path / "s0"), engine="host",
+                      serve_shards=1)
         httpds[0] = httpd
         node = attach_replication(
             httpd, addrs[0], [addrs[1], addrs[2]], lease_ttl_s=0.5,
@@ -230,7 +231,7 @@ def test_membership_join_leave_moves_ownership(tmp_path):
         # boot a third server and join it through node 0
         from diamond_types_tpu.tools.server import serve
         httpd3 = serve(port=0, data_dir=str(tmp_path / "s2"),
-                       serve_shards=1)
+                       engine="host", serve_shards=1)
         addr3 = f"127.0.0.1:{httpd3.server_address[1]}"
         node3 = attach_replication(
             httpd3, addr3, [], lease_ttl_s=5.0, backoff_base_s=0.01,

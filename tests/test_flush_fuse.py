@@ -331,7 +331,7 @@ def test_scheduler_fused_end_to_end_counters():
                 assert sched.submit(d, n_ops=1)["accepted"]
             sched.pump(force=True)
         m = sched.metrics_json()
-        assert m["version"] == 13
+        assert m["version"] == 14
         assert m["fused"]["device_calls"] >= 1
         assert m["fused"]["occupancy"] > 1
         assert m["fused"]["occupancy_hist"]

@@ -341,7 +341,7 @@ def _serve_one(**obs_opts):
     from diamond_types_tpu.tools.server import serve
     opts = {"sample_rate": 0.0}
     opts.update(obs_opts)
-    httpd = serve(port=0, serve_shards=2, obs_opts=opts)
+    httpd = serve(port=0, engine="host", serve_shards=2, obs_opts=opts)
     addr = f"127.0.0.1:{httpd.server_address[1]}"
     threading.Thread(target=httpd.serve_forever, daemon=True).start()
     return httpd, addr
@@ -460,7 +460,7 @@ def _serve_pair():
     for _ in range(2):
         # follower_reads attaches read/follower.py's FollowerIndex —
         # the advert_usable stamp rides its note_advert
-        httpd = serve(port=0, serve_shards=2, follower_reads=True,
+        httpd = serve(port=0, engine="host", serve_shards=2, follower_reads=True,
                       obs_opts={"sample_rate": 1.0})
         httpds.append(httpd)
         addrs.append(f"127.0.0.1:{httpd.server_address[1]}")

@@ -293,6 +293,47 @@ def test_one_dispatch_per_window_vs_per_shard_control():
     assert wc["mesh_docs"] == 0
 
 
+def test_mesh_commit_keeps_each_session_on_its_banks_device():
+    """Placed shards: after mesh windows every session's state — docs
+    row and length — sits on its own bank's device and nowhere else,
+    window after window (a row slice of the sharded result comes back
+    replicated over the mesh; the commit cuts it from its shard and
+    sends it home), and a mixed window takes one program per class."""
+    ols = {}
+    sched = _mk_sched(ols, 4, mesh_window=True, place_on_devices=True)
+    assert len({b.device for b in sched.banks}) == 4
+    rng = random.Random(11)
+    docs = [f"place-{i}" for i in range(12)]
+    for d in docs:
+        ols[d] = _mk_oplog(d)
+        # two capacity classes, so windows are mixed
+        ols[d].add_insert(ols[d].get_or_create_agent_id("a"), 0,
+                          "y" * (900 if d.endswith(("0", "5")) else 30))
+    for rnd in range(4):
+        for d in docs:
+            _random_edits(ols[d], rng, 2)
+            assert sched.submit(d, n_ops=2)["accepted"]
+        sched.pump(force=True)
+        for bank in sched.banks:
+            for sess in bank.sessions.values():
+                assert sess.docs.devices() == {bank.device}
+                assert sess.lens.devices() == {bank.device}
+    for d in docs:
+        assert sched.text(d) == ols[d].checkout_tip().snapshot()
+    m = sched.metrics_json()
+    w = m["window"]
+    assert len({s.cap for b in sched.banks
+                for s in b.sessions.values()}) == 2
+    assert w["mesh_docs"] == 3 * len(docs)
+    assert w["dispatches"] == w["shape_classes"] == 2 * w["device_windows"]
+    assert m["totals"]["reads_from_host"] == 0
+    assert m["totals"]["device_errors"] == 0
+    # the bank's slot accounting is what its chip holds: one copy each
+    for bank in sched.banks:
+        assert bank.footprint_slots() == sum(
+            s.cap for s in bank.sessions.values())
+
+
 # ---- warmup --------------------------------------------------------------
 
 def test_warmup_precompiles_mesh_shape_classes():

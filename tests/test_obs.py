@@ -298,7 +298,7 @@ def _serve_pair(sample_rate=1.0):
     from diamond_types_tpu.tools.server import serve
     httpds, addrs = [], []
     for _ in range(2):
-        httpd = serve(port=0, serve_shards=2,
+        httpd = serve(port=0, engine="host", serve_shards=2,
                       obs_opts={"sample_rate": sample_rate})
         httpds.append(httpd)
         addrs.append(f"127.0.0.1:{httpd.server_address[1]}")
@@ -403,7 +403,7 @@ def test_metrics_endpoint_formats_and_debug_events():
     no-store; dt_flush_latency_seconds shows non-zero counts after
     traffic; /debug/events dumps the flight-recorder ring."""
     from diamond_types_tpu.tools.server import serve
-    httpd = serve(port=0, serve_shards=2,
+    httpd = serve(port=0, engine="host", serve_shards=2,
                   obs_opts={"sample_rate": 1.0})
     addr = f"127.0.0.1:{httpd.server_address[1]}"
     threading.Thread(target=httpd.serve_forever, daemon=True).start()
@@ -419,7 +419,7 @@ def test_metrics_endpoint_formats_and_debug_events():
             assert r.headers["Content-Type"].startswith(
                 "application/json")
             doc = json.loads(r.read())
-        assert doc["serve"]["version"] == 13
+        assert doc["serve"]["version"] == 14
         assert doc["serve"]["latencies"]["flush"]["count"] >= 1
         assert doc["obs"]["trace"]["started"] >= 1
         assert any(row["count"] >= 1
@@ -446,7 +446,7 @@ def test_unsampled_requests_skip_span_buffer():
     """At sample_rate=0 the server's request path must produce zero
     buffered spans (histograms still record — they are always on)."""
     from diamond_types_tpu.tools.server import serve
-    httpd = serve(port=0, serve_shards=2,
+    httpd = serve(port=0, engine="host", serve_shards=2,
                   obs_opts={"sample_rate": 0.0})
     addr = f"127.0.0.1:{httpd.server_address[1]}"
     threading.Thread(target=httpd.serve_forever, daemon=True).start()

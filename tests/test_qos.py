@@ -459,7 +459,7 @@ def _post(base, doc, body=None, headers=None):
 
 def test_server_shed_gate_and_debug_endpoint():
     from diamond_types_tpu.tools.server import serve
-    srv = serve(port=0, data_dir=None, serve_shards=2, qos=True)
+    srv = serve(port=0, data_dir=None, engine="host", serve_shards=2, qos=True)
     port = srv.server_address[1]
     base = f"http://127.0.0.1:{port}"
     threading.Thread(target=srv.serve_forever, daemon=True).start()
@@ -509,7 +509,7 @@ def test_server_shed_gate_and_debug_endpoint():
 
 def test_server_qos_off_has_no_block():
     from diamond_types_tpu.tools.server import serve
-    srv = serve(port=0, data_dir=None, serve_shards=1)
+    srv = serve(port=0, data_dir=None, engine="host", serve_shards=1)
     port = srv.server_address[1]
     base = f"http://127.0.0.1:{port}"
     threading.Thread(target=srv.serve_forever, daemon=True).start()

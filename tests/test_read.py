@@ -256,7 +256,7 @@ def _mesh2(faults=None, read_opts=None):
     from diamond_types_tpu.tools.server import serve
     httpds, addrs, nodes = [], [], []
     for _ in range(2):
-        httpd = serve(port=0, serve_shards=1)
+        httpd = serve(port=0, engine="host", serve_shards=1)
         httpds.append(httpd)
         addrs.append(f"127.0.0.1:{httpd.server_address[1]}")
     for i, httpd in enumerate(httpds):

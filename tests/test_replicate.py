@@ -38,7 +38,7 @@ def _mesh(n, tmp_path=None, serve_shards=2, faults=None,
     for i in range(n):
         data_dir = str(tmp_path / f"s{i}") if tmp_path else None
         httpd = serve(port=0, data_dir=data_dir,
-                      serve_shards=serve_shards)
+                      engine="host", serve_shards=serve_shards)
         httpds.append(httpd)
         addrs.append(f"127.0.0.1:{httpd.server_address[1]}")
     nodes = []
@@ -450,7 +450,7 @@ def test_two_server_smoke(tmp_path):
             # v3: histogram latencies + derived v2 keys
             assert "handoff" in m["replication"]["latencies"]
             assert m["replication"]["handoffs"]["latency_s_total"] >= 0
-            assert m["serve"]["version"] == 13
+            assert m["serve"]["version"] == 14
             assert m["serve"]["uptime_s"] >= 0
             assert "denied" in m["serve"]["totals"]
             assert "fenced" in m["serve"]["totals"]
@@ -738,7 +738,7 @@ def test_mixed_version_mesh_converges_on_json(tmp_path):
     httpds, addrs = [], []
     for i in range(2):
         httpd = serve(port=0, data_dir=str(tmp_path / f"s{i}"),
-                      serve_shards=2)
+                      engine="host", serve_shards=2)
         httpds.append(httpd)
         addrs.append(f"127.0.0.1:{httpd.server_address[1]}")
     nodes = []

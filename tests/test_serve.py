@@ -216,14 +216,17 @@ def test_bank_slot_budget_eviction_device():
     assert bank.text("a", ols["a"]) == "hello"
 
 
-def test_bank_host_fallback_on_device_failure(monkeypatch):
+def test_bank_host_fallback_on_fence_failure(monkeypatch):
+    """A DATA fault (poisoned / drifting replay length) evicts the
+    session to the host oracle and is counted as a host fallback."""
+    from diamond_types_tpu.tpu.flush_fuse import FenceFailure
     m = ServeMetrics(1, flush_docs=4, max_pending=16)
     bank = SessionBank(0, engine="device", metrics=m)
     ol = _mk_oplog("a")
 
     class Boom:
         def sync(self):
-            raise RuntimeError("worker crashed")
+            raise FenceFailure("poisoned length")
 
         def footprint_slots(self):
             return 0
@@ -232,8 +235,71 @@ def test_bank_host_fallback_on_device_failure(monkeypatch):
     r = bank.sync_doc("a", ol)
     assert r["engine"] == "host" and "error" in r
     assert m.shard[0]["host_fallbacks"] == 1
+    assert m.shard[0]["device_errors"] == 0
     assert bank.sessions == {}       # broken session evicted
     assert bank.text("a", ol) == "hello"
+    assert m.shard[0]["reads_from_host"] == 1
+
+
+def test_bank_device_failure_is_not_a_fallback(monkeypatch):
+    """A compiler or runtime failure is never treated as a data fault:
+    it is counted, recorded with its text, and raised."""
+    from diamond_types_tpu.obs.recorder import FlightRecorder
+    m = ServeMetrics(1, flush_docs=4, max_pending=16)
+    bank = SessionBank(0, engine="device", metrics=m)
+    bank.recorder = FlightRecorder()
+    ol = _mk_oplog("a")
+
+    class Boom:
+        def sync(self):
+            raise RuntimeError("Mosaic failed to compile")
+
+        def footprint_slots(self):
+            return 0
+
+    monkeypatch.setattr(bank, "_build", lambda doc_id, oplog: Boom())
+    with pytest.raises(RuntimeError, match="Mosaic"):
+        bank.sync_doc("a", ol)
+    assert m.shard[0]["host_fallbacks"] == 0
+    assert m.shard[0]["device_errors"] == 1
+    ev = [e for e in bank.recorder.dump() if e["kind"] == "device_error"]
+    assert ev and ev[0]["rung"] == "per_doc"
+    assert "Mosaic failed to compile" in ev[0]["error"]
+
+    def no_build(doc_id, oplog):
+        raise RuntimeError("no device memory")
+
+    bank.sessions.clear()
+    monkeypatch.setattr(bank, "_build", no_build)
+    with pytest.raises(RuntimeError, match="no device memory"):
+        bank.sync_doc("a", ol)
+    assert m.shard[0]["device_errors"] == 2
+    assert [e["rung"] for e in bank.recorder.dump()
+            if e["kind"] == "device_error"] == ["per_doc", "build"]
+
+
+def test_bank_reads_counted_by_source():
+    """bank.text() says where it answered from: the resident device
+    session only when it is caught up with the oplog."""
+    m = ServeMetrics(1, flush_docs=4, max_pending=16)
+    bank = SessionBank(0, engine="device", metrics=m, fused=True)
+    ol = _mk_oplog("a")
+    assert bank.text("a", ol) == "hello"             # no session yet
+    assert (m.shard[0]["reads_from_host"],
+            m.shard[0]["reads_from_device"]) == (1, 0)
+    bank.sync_doc("a", ol)
+    assert bank.text("a", ol) == "hello"             # resident, synced
+    assert (m.shard[0]["reads_from_host"],
+            m.shard[0]["reads_from_device"]) == (1, 1)
+    a = ol.get_or_create_agent_id("alice")
+    ol.add_insert(a, 5, "!")
+    assert bank.text("a", ol) == "hello!"            # session behind
+    assert (m.shard[0]["reads_from_host"],
+            m.shard[0]["reads_from_device"]) == (2, 1)
+    host = SessionBank(0, engine="host", metrics=m)
+    host.sync_doc("a", ol)
+    assert host.text("a", ol) == "hello!"
+    assert m.shard[0]["reads_from_host"] == 3
 
 
 # ---- scheduler (host engine) ----------------------------------------------
@@ -307,7 +373,7 @@ def test_serve_bench_concurrent_mode_host():
 
 def test_docstore_scheduler_integration(tmp_path):
     from diamond_types_tpu.tools.server import serve
-    httpd = serve(port=0, data_dir=str(tmp_path), serve_shards=2)
+    httpd = serve(port=0, data_dir=str(tmp_path), engine="host", serve_shards=2)
     port = httpd.server_address[1]
     t = threading.Thread(target=httpd.serve_forever, daemon=True)
     t.start()

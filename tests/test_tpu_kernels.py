@@ -187,8 +187,8 @@ def test_pallas_replay_matches_xla_path():
     args = (jnp.asarray(np.tile(pos, (b, 1))), jnp.asarray(np.tile(dl, (b, 1))),
             jnp.asarray(np.tile(il, (b, 1))),
             jnp.asarray(np.tile(chars, (b, 1, 1))))
-    ref_docs, ref_lens = replay_batch(*args, cap=64)
-    docs, lens = replay_batch_pallas(*args, cap=64, interpret=True)
+    ref_docs, ref_lens = replay_batch(*args, cap=128)
+    docs, lens = replay_batch_pallas(*args, cap=128, interpret=True)
     assert np.array_equal(np.asarray(docs), np.asarray(ref_docs))
     assert np.array_equal(np.asarray(lens), np.asarray(ref_lens))
 

@@ -41,7 +41,7 @@ def _mesh(n, faults=None, **opts):
     opts.setdefault("lease_ttl_s", 30.0)
     httpds, addrs = [], []
     for _ in range(n):
-        httpd = serve(port=0, serve_shards=1)
+        httpd = serve(port=0, engine="host", serve_shards=1)
         httpds.append(httpd)
         addrs.append(f"127.0.0.1:{httpd.server_address[1]}")
     nodes = []

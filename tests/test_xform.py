@@ -17,6 +17,7 @@ import random
 import numpy as np
 import pytest
 
+from diamond_types_tpu.obs import Observability
 from diamond_types_tpu.serve.metrics import ServeMetrics
 from diamond_types_tpu.serve.scheduler import MergeScheduler
 from diamond_types_tpu.text.oplog import OpLog
@@ -159,12 +160,15 @@ def test_per_doc_poison_isolation_on_device_plan_rung():
 
 # ---- the fallback ladder under injected faults ----------------------------
 
-def test_bank_pallas_rung_falls_back_to_fused(monkeypatch):
-    """Injected pallas_fused_replay failure: the bank's `_replay_group`
-    drops one rung to the fused replay, bumps `pallas_fallbacks`, and
-    parity holds — nothing is lost, nothing is bypassed."""
+def test_bank_pallas_rung_failure_propagates(monkeypatch):
+    """Injected pallas_fused_replay failure: the rung that was asked for
+    is never answered by the XLA kernel under its name. The exception is
+    counted (`device_errors`), recorded with its text and raised; the
+    docs stay byte-correct through the host oracle, and the read says
+    so (`reads_from_host`)."""
     ols = {}
     sched = _mk_sched(ols, 1, device_plan=True, pallas=True)
+    sched.attach_obs(Observability())
     assert sched.banks[0].pallas
     rng = random.Random(31)
     docs = [f"d{i}" for i in range(4)]
@@ -178,23 +182,37 @@ def test_bank_pallas_rung_falls_back_to_fused(monkeypatch):
             def boom(sessions, plans):
                 raise RuntimeError("injected pallas failure")
             monkeypatch.setattr(ff, "pallas_fused_replay", boom)
-        sched.pump(force=True)
+            with pytest.raises(RuntimeError, match="injected pallas"):
+                sched.pump(force=True)
+        else:
+            sched.pump(force=True)
     monkeypatch.undo()
     m = sched.metrics_json()
-    assert m["totals"]["pallas_fallbacks"] >= 1
+    assert m["totals"]["device_errors"] == 1
     assert m["totals"]["host_fallbacks"] == 0
+    ev = [e for e in sched.obs.recorder.dump()
+          if e["kind"] == "device_error"]
+    assert ev[0]["rung"] == "pallas"
+    assert "injected pallas failure" in ev[0]["error"]
     for d in docs:
         assert sched.text(d) == ols[d].checkout_tip().snapshot()
+    m = sched.metrics_json()
+    assert m["totals"]["reads_from_host"] == len(docs)
+    assert m["totals"]["reads_from_device"] == 0
 
 
-def test_window_ladder_pallas_then_mesh_rungs_fail(monkeypatch):
-    """Mesh flush window with BOTH top rungs failing (pallas raise,
-    mesh raise): the window completes through the per-shard fused
-    fallback with byte parity — the ladder never bypasses a fence."""
+@pytest.mark.parametrize("rung", ["pallas", "mesh"])
+def test_window_rung_failure_propagates(monkeypatch, rung):
+    """Mesh flush window whose replay raises (the Pallas program on a
+    one-device window, the mesh program otherwise): counted on the
+    failing class's shard, recorded, raised once the window is wound
+    up — no quieter rung replays the window, and the host oracle keeps
+    every doc byte-correct."""
     from diamond_types_tpu.parallel import mesh as pm
     ols = {}
     sched = _mk_sched(ols, 1, mesh_window=True, device_plan=True,
-                      pallas=True)
+                      pallas=(rung == "pallas"))
+    sched.attach_obs(Observability())
     rng = random.Random(37)
     docs = [f"d{i}" for i in range(4)]
     for rnd in range(3):
@@ -209,10 +227,19 @@ def test_window_ladder_pallas_then_mesh_rungs_fail(monkeypatch):
             # both call-time imports re-resolve these module attrs
             monkeypatch.setattr(ff, "pallas_fused_replay", boom)
             monkeypatch.setattr(pm, "mesh_fused_replay", boom)
-        sched.pump(force=True)
+            with pytest.raises(RuntimeError, match="injected rung"):
+                sched.pump(force=True)
+        else:
+            sched.pump(force=True)
     monkeypatch.undo()
     m = sched.metrics_json()
-    assert m["window"]["windows"] >= 3
+    # the failed window is accounted, with no dispatch to its name
+    assert m["window"]["windows"] == 3
+    assert m["window"]["device_windows"] == 1
+    assert m["totals"]["device_errors"] == 1
+    ev = [e for e in sched.obs.recorder.dump()
+          if e["kind"] == "device_error"]
+    assert [e["rung"] for e in ev] == [rung]
     for d in docs:
         assert sched.text(d) == ols[d].checkout_tip().snapshot()
 
@@ -231,7 +258,7 @@ def test_device_plan_guard_trip_host_fallback(monkeypatch):
         b = ol.get_or_create_agent_id("b")
         ol.add_insert_at(b, [], 0, "W")
     monkeypatch.setattr(xfm, "resolve_positions",
-                        lambda exts: [None] * len(exts))
+                        lambda exts, pallas=False: [None] * len(exts))
     plans, stats = xfm.plan_tails_device(sess)
     monkeypatch.undo()
     assert stats["fallbacks"] == 3 and stats["device_docs"] == 0
@@ -317,9 +344,14 @@ def test_pallas_fused_replay_parity():
 
 @pytest.mark.pallas
 def test_pallas_xform_end_to_end(monkeypatch):
-    """DT_TPU_PALLAS=1 routes the transform's position scans through the
+    """`pallas=True` routes the transform's position scans through the
     Pallas kernel; the device-planned replay stays byte-identical."""
-    monkeypatch.setenv("DT_TPU_PALLAS", "1")
+    from diamond_types_tpu.tpu import pallas_kernels as pk
+    calls = []
+    real = pk.xform_positions_pallas
+    monkeypatch.setattr(pk, "xform_positions_pallas",
+                        lambda *a, **k: calls.append(1) or real(*a, **k))
+    xfm._xform_jit_cache.clear()
     ol = _mk_oplog("pe")
     a = ol.get_or_create_agent_id("a")
     ol.add_insert(a, 0, "root ")
@@ -328,8 +360,8 @@ def test_pallas_xform_end_to_end(monkeypatch):
     for k in range(5):
         ag = ol.get_or_create_agent_id(f"c{k}")
         ol.add_insert_at(ag, base, 0, f"<{k}>")
-    plans, stats = xfm.plan_tails_device([sess])
-    assert stats["device_docs"] == 1
+    plans, stats = xfm.plan_tails_device([sess], pallas=True)
+    assert stats["device_docs"] == 1 and calls
     ok, _dev = ff.fused_replay([sess], plans)
     assert all(ok)
     assert sess.text() == ol.checkout_tip().snapshot()
@@ -341,14 +373,15 @@ def test_metrics_transform_block_and_version():
     m = ServeMetrics(2, 4, 64)
     m.record_transform(0, device_docs=3, host_docs=1, fallbacks=1,
                        batches=1)
-    m.bump(0, "pallas_fallbacks")
+    m.bump(0, "device_errors")
     s = m.snapshot()
-    assert s["version"] == 13
+    assert s["version"] == 14
     t = s["transform"]
     assert t["device_docs"] == 3 and t["host_docs"] == 1
     assert t["fallbacks"] == 1 and t["batches"] == 1
     assert t["device_ratio"] == 0.6          # 3 / (3 + 1 + 1)
-    assert s["totals"]["pallas_fallbacks"] == 1
+    assert s["totals"]["device_errors"] == 1
+    assert "pallas_fallbacks" not in s["totals"]
 
 
 def test_prom_zero_fills_xform_and_pallas_jit_families():
