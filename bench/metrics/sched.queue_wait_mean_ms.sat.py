@@ -1,0 +1,3 @@
+"""The saturated cell's `sched.queue_wait_mean_ms` (submit -> the end of
+the flush): one reader for both cells, in bench/phases.py."""
+from bench.phases import queue_wait_mean_ms as read  # noqa: F401

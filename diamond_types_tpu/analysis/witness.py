@@ -20,11 +20,19 @@ default); `witness_enable()` turns on recording:
 Reentrant re-acquisition of the SAME lock object (RLocks) records
 nothing. The witness is process-global on purpose: deadlocks are a
 process-level property, and the soaks boot many nodes in one process.
+
+The witness records order, not time. A `ClockedLock`
+(`make_lock(..., clocked=True)`: the store's oplog guard, and no
+other) can be given a clock (`attach_clock(obs.phases)`, by `serve()`)
+and then also reports how long each acquisition waited and how long it
+held, charged to the phase open on the acquiring thread
+(obs/phases.py). Every other lock runs the code it always ran.
 """
 
 from __future__ import annotations
 
 import threading
+import time
 from typing import Dict, List, Optional, Tuple
 
 # module-level switch: read unlocked on the acquire fast path (a stale
@@ -132,11 +140,62 @@ class WitnessLock:
         stack.append(self)
 
 
+class ClockedLock(WitnessLock):
+    """A non-reentrant WitnessLock that can time its waits and holds
+    into a clock (obs.phases.PhaseTable). Without one attached it
+    pays one attribute check per acquire and release and allocates
+    nothing."""
+
+    __slots__ = ("clock", "_held")
+
+    def __init__(self, name: str, order_class: str,
+                 rank: Optional[int] = None) -> None:
+        super().__init__(name, order_class, rank=rank)
+        self.clock = None       # attach_clock
+        self._held = None       # the clock's note on the acquisition
+
+    def attach_clock(self, clock) -> None:
+        self.clock = clock
+
+    def acquire(self, blocking: bool = True, timeout: float = -1) -> bool:
+        clock = self.clock
+        if clock is None:
+            return WitnessLock.acquire(self, blocking, timeout)
+        got = clock.acquire(self, blocking, timeout)
+        if got and _enabled:
+            self._record_acquire()
+        elif got:
+            _held().append(self)
+        return got
+
+    def release(self) -> None:
+        clock = self.clock
+        if clock is None:
+            return WitnessLock.release(self)
+        stack = _held()
+        for i in range(len(stack) - 1, -1, -1):
+            if stack[i] is self:
+                del stack[i]
+                break
+        # read before letting go: the next holder overwrites the note
+        held, self._held = self._held, None
+        now = time.perf_counter()
+        self._inner.release()
+        if held is not None:
+            clock.released(self.name, held, now)
+
+
 def make_lock(name: str, order_class: str, rank: Optional[int] = None,
-              reentrant: bool = False) -> WitnessLock:
+              reentrant: bool = False, clocked: bool = False) -> WitnessLock:
     """Construct a witness-instrumented lock. Always returns the
     wrapper (near-zero cost disabled) so `witness_enable()` works on
-    locks constructed before the switch flipped."""
+    locks constructed before the switch flipped. `clocked` makes it a
+    `ClockedLock` (non-reentrant: a hold is one acquire to one
+    release)."""
+    if clocked:
+        if reentrant:
+            raise ValueError(f"{name}: a reentrant lock takes no clock")
+        return ClockedLock(name, order_class, rank=rank)
     return WitnessLock(name, order_class, rank=rank,
                        reentrant=reentrant)
 

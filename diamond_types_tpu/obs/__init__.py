@@ -4,6 +4,9 @@ One `Observability` bundle per server process ties together:
 
   trace.py      sampled spans with X-DT-Trace cross-host propagation
   hist.py       log-bucketed latency histograms (p50/p90/p99)
+  phases.py     always-on phase clocks: the parts of an edit, an
+                autosave pass and a replay, and who waits for and
+                holds the clocked locks (`snapshot()["phases"]`)
   recorder.py   flight recorder — bounded ring of structured events
   prom.py       Prometheus/OpenMetrics exposition of the /metrics JSON
   devprof.py    wall-vs-device flush timing, jit-cache hits, transfers
@@ -34,6 +37,7 @@ from .hist import BOUNDS, Histogram, HistogramSet
 from .incident import INCIDENT_KINDS, AnomalyDetector, IncidentStore
 from .journey import STAGES as JOURNEY_STAGES
 from .journey import OpJourney
+from .phases import NOOP_PHASE, PhaseTable, phase
 from .prom import CONTENT_TYPE, OPENMETRICS_CONTENT_TYPE, render_metrics
 from .recorder import FlightRecorder
 from .scorecard import (SCORECARD_VERSION, build_scorecard,
@@ -48,6 +52,7 @@ __all__ = [
     "Observability", "Tracer", "Span", "SpanContext", "NOOP_SPAN",
     "TRACE_HEADER", "format_context", "parse_header",
     "Histogram", "HistogramSet", "BOUNDS",
+    "PhaseTable", "NOOP_PHASE", "phase",
     "FlightRecorder",
     "CONTENT_TYPE", "OPENMETRICS_CONTENT_TYPE", "render_metrics",
     "PROFILER", "DeviceProfiler", "note_jit_lookup", "note_transfer",
@@ -87,6 +92,9 @@ class Observability:
         self.recorder = FlightRecorder(capacity=recorder_capacity,
                                        enabled=enabled)
         self.hist = HistogramSet()
+        # phase clocks: counters like the histograms, so always on
+        self.phases = PhaseTable(tracer=self.tracer,
+                                 recorder=self.recorder)
         # live telemetry tier: windowed time-series + SLO burn rates +
         # exemplars + hot-key attribution. `telemetry=False` keeps the
         # cumulative tier while turning every live-tier write into a
@@ -128,6 +136,7 @@ class Observability:
         out = {"trace": self.tracer.stats(),
                "recorder": self.recorder.stats(),
                "http": self.hist.snapshot(),
+               "phases": self.phases.snapshot(),
                "devprof": PROFILER.snapshot(),
                "timeseries": self.ts.snapshot(),
                "slo": self.slo.snapshot(),

@@ -32,6 +32,12 @@ Naming scheme:
                                       antientropy_round,
                                       rebalance_drain)
   dt_http_request_seconds{endpoint,method}
+  dt_phase_seconds_total{phase} /     phase clocks (obs/phases.py):
+  dt_phase_total{phase}               seconds inside and closes of each
+                                      named phase since boot
+  dt_lock_wait_seconds_total{lock,site} /  clocked locks: seconds waited
+  dt_lock_hold_seconds_total{lock,site}    for and held, by the phase
+                                      open at the acquisition
   dt_trace_* / dt_recorder_* / dt_devprof_*
   dt_slo_*{objective}                 burn-rate gauges + alert state
   dt_hot_*{dim,kind[,key]}            top-K attribution (bounded: the
@@ -377,6 +383,20 @@ def _render_obs(b: _Builder, obs: dict) -> None:
         for entry in series:
             b.histogram(f"dt_{name}_seconds", entry,
                         labels=entry.get("labels") or {})
+    ph = obs.get("phases") or {}
+    for name, row in sorted((ph.get("phases") or {}).items()):
+        lb = {"phase": name}
+        b.add("dt_phase_seconds_total", "counter",
+              row.get("sum_s", 0.0), labels=lb)
+        b.add("dt_phase_total", "counter", row.get("count", 0),
+              labels=lb)
+    for lock, sites in sorted((ph.get("locks") or {}).items()):
+        for site, cell in sorted(sites.items()):
+            lb = {"lock": lock, "site": site}
+            b.add("dt_lock_wait_seconds_total", "counter",
+                  cell.get("wait_s", 0.0), labels=lb)
+            b.add("dt_lock_hold_seconds_total", "counter",
+                  cell.get("hold_s", 0.0), labels=lb)
     tr = obs.get("trace") or {}
     for k in ("started", "sampled_out", "finished"):
         if k in tr:

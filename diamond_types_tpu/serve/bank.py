@@ -62,6 +62,7 @@ from collections import OrderedDict
 from typing import Dict, List, Optional
 
 from ..obs.devprof import PROFILER
+from ..obs.phases import phase
 from ..tpu.flush_fuse import FenceFailure
 from ..tpu.runtime import first_touch
 from .metrics import ServeMetrics
@@ -403,12 +404,15 @@ class SessionBank:
         olock = oplog_lock if oplog_lock is not None \
             else contextlib.nullcontext()
         # resolve first, outside every lock (non-reentrant store lock)
-        ols = {it.doc_id: resolve(it.doc_id) for it in items}
+        with phase("bank.resolve"):
+            ols = {it.doc_id: resolve(it.doc_id) for it in items}
         serial = list(items)
         groups: List[tuple] = []     # (sessions, plans, doc_ids)
         if self.fused and self.engine == "device":
-            serial, groups = self._plan_fused(items, ols, olock,
-                                              min_fuse=min_fuse)
+            # session builds and tail plans, under the oplog guard
+            with phase("bank.plan"):
+                serial, groups = self._plan_fused(items, ols, olock,
+                                                  min_fuse=min_fuse)
         self._journey_stamp(items, "planned")
         return {"items": items, "ols": ols, "serial": serial,
                 "groups": groups}
@@ -447,7 +451,7 @@ class SessionBank:
         for _sessions, _plans, doc_ids in win["groups"]:
             for _d in doc_ids:
                 self._bump("syncs")
-        with olock:
+        with phase("adopt"), olock:
             for d in failed:
                 # poisoned (-1) or length-drift result: the session's
                 # device state is untrusted — evict it and serve the

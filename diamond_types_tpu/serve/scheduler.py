@@ -56,6 +56,7 @@ import time
 from typing import Callable, Dict, List, Optional
 
 from ..analysis.witness import make_lock
+from ..obs.phases import NOOP_PHASE
 from ..obs.trace import NOOP_SPAN
 from ..qos.classes import QOS_PRIORITY
 from .admission import AdmissionQueue, Backpressure
@@ -207,6 +208,9 @@ class MergeScheduler:
         violations, fenced flushes) into the flight recorder."""
         self.obs = obs
         self.metrics.recorder = obs.recorder
+        # the queue-wait histogram is exported under a phase's name
+        obs.phases.adopt("sched.queue_wait",
+                         self.metrics.queue_wait_latency)
         # live-telemetry tier: counters/latencies double-write into the
         # windowed TimeSeries (rate()/quantile() "now" queries + SLO
         # burn rates); per-doc/agent usage feeds the top-K sketch
@@ -568,9 +572,13 @@ class MergeScheduler:
                            "docs": len(items)})
         bank = self.banks[shard]
         t0 = time.perf_counter()
+        # the root the bank's and the replay's phases hang under
+        # (obs/phases.py: they find it on this thread)
+        ph = obs.phases.phase("sched.flush", span=fspan) \
+            if obs is not None else NOOP_PHASE
         # the spans are context managers so a raise out of the bank
         # (a device error) ends them with `error=<type>`, not never
-        with fspan:
+        with fspan, ph:
             with self._shard_locks[shard]:
                 # one device_sync span per taken batch — the whole
                 # bucket is (at best) ONE device call now, so per-doc
@@ -683,6 +691,9 @@ class MergeScheduler:
                     attrs={"shards": len(shards), "docs": n_docs})
         t0 = time.perf_counter()
         with contextlib.ExitStack() as sstack:
+            if obs is not None:
+                sstack.enter_context(
+                    obs.phases.phase("sched.flush", span=fspan))
             for s in shards:
                 sstack.enter_context(self._shard_locks[s])
             wins = [self.banks[s].plan_window(
@@ -888,6 +899,9 @@ class MergeScheduler:
     def metrics_json(self) -> dict:
         snap = self.metrics.snapshot()
         snap["router_counts"] = self.router.counts()
+        if self.obs is not None:
+            # beside, not inside, the ServeMetrics schema (version 14)
+            snap["phases"] = self.obs.phases.snapshot()
         return snap
 
     # ---- background pump -------------------------------------------------

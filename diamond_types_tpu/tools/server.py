@@ -132,6 +132,7 @@ from typing import Dict, Optional
 from ..causalgraph.summary import intersect_with_summary, summarize_versions
 from ..encoding.decode import decode_into, load_oplog
 from ..encoding.encode import ENCODE_FULL, ENCODE_PATCH, encode_oplog
+from ..obs.phases import NOOP_PHASE
 from ..obs.trace import TRACE_HEADER, parse_header
 from ..text.oplog import OpLog
 from ..wire.frames import (FRAME_DOCS, FRAME_OPS, FRAME_PATCH,
@@ -145,6 +146,8 @@ from ..wire.snapshot import build_snapshot
 # Doc ids are filenames (DocStore writes {data_dir}/{id}.dt) and are
 # interpolated into the served pages: restrict to a safe charset.
 _DOC_ID_RE = re.compile(r"^[A-Za-z0-9_.-]{1,64}$")
+# document POSTs that get a root phase of their own name (`http.edit`)
+_POST_ACTIONS = ("edit", "push", "pull", "changes", "ops", "history", "at")
 
 
 class DocStore:
@@ -184,7 +187,9 @@ class DocStore:
         # always-local behavior.
         self.reads = None
         from ..analysis.witness import make_lock
-        self.lock = make_lock("store.oplog", "oplog")
+        # clocked: with a bundle attached, who waits for it and who
+        # holds it is timed by phase (obs/phases.py)
+        self.lock = make_lock("store.oplog", "oplog", clocked=True)
         # serializes flush passes; deliberately OUTER to the oplog
         # guard (its own `io` rung in the canonical lock order)
         self.io_lock = make_lock("store.io", "io")
@@ -294,6 +299,16 @@ class DocStore:
     def flush(self, force: bool = False) -> None:
         if self.data_dir is None:
             return
+        obs = self.obs
+        ph = obs.phases.phase("autosave.pass") if obs is not None \
+            else NOOP_PHASE
+        with ph:
+            self._flush_pass(force, ph)
+
+    def _flush_pass(self, force: bool, ph) -> None:
+        """One autosave pass; `ph` is its `autosave.pass` phase: the
+        encode under the store lock (the wait for it included) and the
+        file loop are its two steps."""
         os.makedirs(self.data_dir, exist_ok=True)
         now = time.monotonic()
         # io_lock serializes whole flush passes: without it, a flusher
@@ -305,6 +320,7 @@ class DocStore:
             # under it; an encode racing a mutation could crash or persist
             # a torn snapshot); only the disk write happens outside it.
             blobs = []
+            ph.step("autosave.encode")
             with self.lock:
                 due = [d for d, t in self.dirty.items()
                        if force or now - t >= self.save_interval]
@@ -333,6 +349,8 @@ class DocStore:
             # and silently drop the remaining docs' (already-cleared)
             # dirty flags — an idle doc's edits would otherwise never be
             # persisted again.
+            ph.step("autosave.write")
+            ph.count("docs", len(blobs))
             for doc_id, blob in blobs:
                 path = self._path(doc_id)
                 tmp = path + ".tmp"
@@ -687,11 +705,42 @@ class SyncHandler(BaseHTTPRequestHandler):
             return span.context()
         return None
 
+    def _doc_phase(self, obs, method: str):
+        """The root phase of a document request (`http.edit`,
+        `http.get`, `http.<action>`), NOOP_PHASE for any other path or
+        with no bundle; also closes `http.accept_wait`, which ends at
+        the handler's first line. A root that takes SLOW_REQUEST_S or
+        longer writes one `slow_request` event with its parts — but for
+        the `changes` long-poll, whose wait is the request."""
+        if obs is None:
+            return NOOP_PHASE
+        at = getattr(self.server, "accepted_at", None)
+        t_acc = at.pop(self.request, None) if at is not None else None
+        wait = None if t_acc is None else time.perf_counter() - t_acc
+        doc_id, action = self._route()
+        if doc_id is None:
+            if wait is not None:
+                obs.phases.observe("http.accept_wait", wait)
+            return NOOP_PHASE
+        if method == "GET":
+            name = "http.get"
+        else:
+            name = "http." + (action if action in _POST_ACTIONS
+                              else "other")
+        slow = None if action == "changes" else {
+            "doc": doc_id, "accept_wait_ms": round((wait or 0.0) * 1e3, 3)}
+        ph = obs.phases.phase(name, slow=slow)
+        if wait is not None:
+            ph.note("http.accept_wait", wait)   # written with the root
+        return ph
+
     def do_GET(self):
         obs = self.store.obs
         t0 = time.monotonic()
+        self._phase = self._doc_phase(obs, "GET")
         try:
-            self._do_get()
+            with self._phase:
+                self._do_get()
         finally:
             if obs is not None:
                 obs.hist.observe("http_request", time.monotonic() - t0,
@@ -884,11 +933,14 @@ class SyncHandler(BaseHTTPRequestHandler):
             # routed BEFORE store.get: 404ing a doc that was never
             # materialized here must not mint an empty oplog for it
             return self._doc_snapshot(doc_id, no_store)
+        ph = self._phase
+        ph.step("get.checkout")
         ol = self.store.get(doc_id)
         if action == "":
             with self.store.lock:
                 text = ol.checkout_tip().snapshot()
                 frontier = ol.cg.local_to_remote_frontier(ol.version)
+            ph.step("get.respond")
             return self._send(200, text.encode("utf8"),
                               "text/plain; charset=utf-8",
                               extra={**no_store,
@@ -1028,25 +1080,32 @@ class SyncHandler(BaseHTTPRequestHandler):
         from ..encoding.decode import ParseError
         obs = self.store.obs
         t0 = time.monotonic()
-        if obs is not None:
-            # Root (or continued) span for this request: an X-DT-Trace
-            # header from a proxying peer or traced client stitches this
-            # hop into the caller's trace; otherwise head-sampling here
-            # decides for every downstream span (admit, flush, proxy).
-            self._span = obs.tracer.start(
-                "http." + self._endpoint_label(),
-                parent=parse_header(self.headers.get(TRACE_HEADER)),
-                attrs={"path": self.path.split("?", 1)[0]})
+        ph = self._phase = self._doc_phase(obs, "POST")
         try:
-            self._do_post()
-        except (ValueError, KeyError, TypeError, AttributeError,
-                ParseError) as e:
-            try:
-                self._send(400, json.dumps(
-                    {"error": f"bad request: {e.__class__.__name__}"})
-                    .encode("utf8"))
-            except OSError:
-                pass  # client already gone
+            with ph:
+                if obs is not None:
+                    # Root (or continued) span for this request: an
+                    # X-DT-Trace header from a proxying peer or traced
+                    # client stitches this hop into the caller's trace;
+                    # otherwise head-sampling here decides for every
+                    # downstream span (admit, flush, proxy) and for the
+                    # request's phases, which become its children.
+                    self._span = obs.tracer.start(
+                        "http." + self._endpoint_label(),
+                        parent=parse_header(self.headers.get(TRACE_HEADER)),
+                        attrs={"path": self.path.split("?", 1)[0]})
+                    ph.trace(self._span)
+                try:
+                    self._do_post()
+                except (ValueError, KeyError, TypeError, AttributeError,
+                        ParseError) as e:
+                    try:
+                        self._send(400, json.dumps(
+                            {"error":
+                             f"bad request: {e.__class__.__name__}"})
+                            .encode("utf8"))
+                    except OSError:
+                        pass  # client already gone
         finally:
             if obs is not None:
                 span = getattr(self, "_span", None)
@@ -1073,6 +1132,9 @@ class SyncHandler(BaseHTTPRequestHandler):
         doc_id, action = self._route()
         if doc_id is None:
             return self._send(404, b"{}")
+        ph = self._phase
+        if action == "edit":
+            ph.step("edit.parse")
         n = int(self.headers.get("Content-Length", 0))
         body = self.rfile.read(n)
         obs = self.store.obs
@@ -1281,6 +1343,7 @@ class SyncHandler(BaseHTTPRequestHandler):
             if obs is not None:
                 obs.attrib.note("ops", agent=req["agent"], n=len(ops))
                 obs.attrib.note("bytes", agent=req["agent"], n=float(n))
+            ph.step("edit.checkout")
             with self.store.lock:
                 frontier = list(ol.cg.remote_to_local_frontier(
                     req.get("version") or []))
@@ -1288,6 +1351,7 @@ class SyncHandler(BaseHTTPRequestHandler):
                 # client's version before touching the oplog: a rejected op
                 # must not leave earlier batch ops half-applied.
                 blen = len(ol.checkout(frontier))
+                ph.step("edit.apply")
                 for op in ops:
                     if op[0] == "ins":
                         _k, pos, text = op
@@ -1310,10 +1374,12 @@ class SyncHandler(BaseHTTPRequestHandler):
                                               op[2], None)
                     frontier = [lv]
                 out = ol.cg.local_to_remote_frontier(frontier)
+            ph.step("edit.publish")
             self.store.mark_dirty(doc_id)
             self.store.notify(doc_id)
             if self.store.reads is not None:
                 self.store.reads.on_local_mutation(doc_id)
+            ph.step("edit.submit")
             tctx = self._trace_ctx()
             if obs is not None and tctx is not None:
                 # journey identity = the edit's (agent, last seq): the
@@ -1324,6 +1390,7 @@ class SyncHandler(BaseHTTPRequestHandler):
                                   trace=tctx.trace_id)
             self.store.submit_merge(doc_id, len(ops), trace=tctx,
                                     qos=qos_cls)
+            ph.step("edit.respond")
             return self._send(200, json.dumps({"version": out})
                               .encode("utf8"))
         if action == "changes":
@@ -1447,6 +1514,23 @@ class SyncHandler(BaseHTTPRequestHandler):
 class _Server(ThreadingHTTPServer):
     store: DocStore = None
 
+    def __init__(self, *a, **kw) -> None:
+        super().__init__(*a, **kw)
+        # connection -> when accept() returned it: `http.accept_wait`
+        # runs from there to the handler's first line (thread start,
+        # request line, headers)
+        self.accepted_at: dict = {}
+
+    def process_request(self, request, client_address):
+        store = self.store
+        if store is not None and store.obs is not None:
+            self.accepted_at[request] = time.perf_counter()
+        super().process_request(request, client_address)
+
+    def shutdown_request(self, request):
+        self.accepted_at.pop(request, None)   # never reached a handler
+        super().shutdown_request(request)
+
     def server_close(self):
         """Clean shutdown. The final durable flush is the guarantee (an
         edit acknowledged before it reads back after a restart), and
@@ -1515,6 +1599,9 @@ def serve(port: int = 8008, data_dir: Optional[str] = None,
         # explain; callers may still override with their own dir
         oo.setdefault("incident_dir", data_dir)
     store.obs = Observability(**oo)
+    # who waits for the oplog guard and who holds it, by phase: the one
+    # clocked lock (the scheduler's own are read by no metric)
+    store.lock.attach_clock(store.obs.phases)
     if serve_shards:
         from ..serve.scheduler import MergeScheduler
         so = dict(sched_opts or {})

@@ -44,6 +44,7 @@ from typing import List, Sequence, Tuple
 
 import numpy as np
 
+from ..obs.phases import phase
 from .merge_kernel import _pow2
 
 DEFAULT_CAP = 1 << 10
@@ -80,23 +81,29 @@ def make_replay_body(mi: int):
 
     from .batch import _apply_ops_batched
 
-    def run(docs, lens, pos, dlen, ilen, chars):
-        bad = (dlen > mi) | (ilen > mi)
-        dlen = jnp.where(bad, 0, dlen)
-        ilen = jnp.where(bad, 0, ilen)
-        bad_doc = jnp.any(bad, axis=1)
+    # the function's name is the jitted module's in a profiler trace
+    # (`jit_dt_fused_replay`), the scopes name its device operations:
+    # metadata only, the operations and shapes are what they were
+    def dt_fused_replay(docs, lens, pos, dlen, ilen, chars):
+        with jax.named_scope("dt.replay.sanitize"):
+            bad = (dlen > mi) | (ilen > mi)
+            dlen = jnp.where(bad, 0, dlen)
+            ilen = jnp.where(bad, 0, ilen)
+            bad_doc = jnp.any(bad, axis=1)
 
         def step(carry, op):
             d, l, p, dl, il, c = carry + op
-            d, l = _apply_ops_batched(d, l, p, dl, il, c)
+            with jax.named_scope("dt.replay.apply"):
+                d, l = _apply_ops_batched(d, l, p, dl, il, c)
             return (d, l), None
 
-        ops = (jnp.swapaxes(pos, 0, 1), jnp.swapaxes(dlen, 0, 1),
-               jnp.swapaxes(ilen, 0, 1), jnp.swapaxes(chars, 0, 1))
-        (docs, lens), _ = jax.lax.scan(step, (docs, lens), ops)
-        return docs, jnp.where(bad_doc, -1, lens)
+        with jax.named_scope("dt.replay.scan"):
+            ops = (jnp.swapaxes(pos, 0, 1), jnp.swapaxes(dlen, 0, 1),
+                   jnp.swapaxes(ilen, 0, 1), jnp.swapaxes(chars, 0, 1))
+            (docs, lens), _ = jax.lax.scan(step, (docs, lens), ops)
+            return docs, jnp.where(bad_doc, -1, lens)
 
-    return run
+    return dt_fused_replay
 
 
 def _fused_fn(b: int, n: int, mi: int, cap: int):
@@ -400,17 +407,27 @@ class FusedDocSession:
         Concurrent/merged histories come back pre-transformed by the
         host oracle; `pos is None` rows (deletes that already
         happened) are no-ops and are skipped."""
+        with phase("plan.tail") as ph:
+            return self._plan_tail(ph)
+
+    def _plan_tail(self, ph) -> TailPlan:
+        """`plan_tail` under its `plan.tail` phase `ph`; the steps are
+        the transform walk, the row loop and the numpy fill."""
         ol = self.oplog
         if self.synced_to >= len(ol):
             return _empty_plan(self.frontier, self.synced_to,
                                self.doc_len, self.max_ins)
         mi = self.max_ins
+        ph.step("plan.xf")
         xf = ol.get_xf_operations_full(list(self.frontier), ol.version)
+        ph.step("plan.rows")
         rows: List[Tuple[int, int, int, str]] = []
         cur = self.doc_len
         peak = cur
         from ..text.op import INS
-        for _lv, op, pos in xf:
+        # the walk is lazy and stays so: the seconds inside its next()
+        # leave `plan.rows` for `plan.xf` (which so closes twice a plan)
+        for _lv, op, pos in ph.timed(xf, "plan.xf"):
             if pos is None:
                 continue
             if op.kind == INS:
@@ -432,6 +449,7 @@ class FusedDocSession:
                     d -= k
                 cur -= len(op)
         k = len(rows)
+        ph.step("plan.pack")
         frontier = tuple(int(x) for x in xf.next_frontier)
         if k == 0:
             plan = _empty_plan(frontier, len(ol), self.doc_len, mi)
@@ -568,12 +586,21 @@ def fused_replay(sessions: List[FusedDocSession],
     disagrees with the host-side projection is NOT committed — the
     caller evicts it and serves the doc from the host engine.
     Successful sessions have their result rows committed."""
+    with phase("replay") as ph:
+        return _fused_replay(sessions, plans, ph)
+
+
+def _fused_replay(sessions, plans, ph) -> Tuple[List[bool], float]:
+    """`fused_replay` under its `replay` phase `ph`; the steps are the
+    host pack, the two stacks, dispatch (jit lookup, plan upload, the
+    call), the length fence and adoption."""
     import jax.numpy as jnp
 
     b = len(sessions)
     assert b == len(plans) and b >= 1
     cap = sessions[0].cap
     mi = sessions[0].max_ins
+    ph.step("replay.pack")
     from .steer import STEER
     n0 = _pow2(max(max(p.n_ops for p in plans), 1))
     bp0 = _pow2(b) if b > 1 else 1
@@ -582,18 +609,22 @@ def fused_replay(sessions: List[FusedDocSession],
     from ..obs.devprof import note_transfer
     note_transfer(pos.nbytes + dlen.nbytes + ilen.nbytes + chars.nbytes,
                   rung="fused", purpose="plan")
+    ph.step("replay.stack")
     docs = jnp.stack([s.docs for s in sessions]
                      + [sessions[0].docs] * (bp - b))
     lens = jnp.stack([s.lens for s in sessions]
                      + [sessions[0].lens] * (bp - b))
+    ph.step("replay.dispatch")
     fn = _fused_fn(bp, n, mi, cap)
     out_docs, out_lens = fn(docs, lens, jnp.asarray(pos),
                             jnp.asarray(dlen), jnp.asarray(ilen),
                             jnp.asarray(chars))
     # the length fetch is the completion fence AND the parity
     # cross-check: poison (-1) or host-projection drift fails the doc
+    ph.step("replay.fence")
     t_fence = time.perf_counter()
     got = np.asarray(out_lens)
     device_s = time.perf_counter() - t_fence
+    ph.step("replay.adopt")
     return adopt_results(sessions, plans, out_docs, out_lens, got), \
         device_s
