@@ -16,15 +16,20 @@ from ..core.span import Span
 from ..listmerge.transform import TransformedOps
 from .op import DEL, INS, OpRun, OpStore
 
+# (frontier, length) pairs an oplog remembers, oldest out first: one for
+# each writer of a document who pushes from their own head
+LEN_MEMO_SIZE = 8
+
 
 class OpLog:
-    __slots__ = ("cg", "ops", "doc_id", "_native_ctx")
+    __slots__ = ("cg", "ops", "doc_id", "_native_ctx", "_len_memo")
 
     def __init__(self) -> None:
         self.cg = CausalGraph()
         self.ops = OpStore()
         self.doc_id: Optional[str] = None
         self._native_ctx = None
+        self._len_memo: dict = {}   # sorted LV tuple -> length, by age
 
     def __len__(self) -> int:
         return len(self.cg)
@@ -269,6 +274,33 @@ class OpLog:
 
     def checkout_tip(self):
         return self.checkout(self.version)
+
+    # --- length at a version -------------------------------------------------
+    # The log only appends and LVs are never renumbered, so a frontier's
+    # causal cone, and with it the document's length there, never changes:
+    # an entry is never invalidated and is exact for the object's life.
+
+    def length_known(self, frontier: Sequence[int]) -> bool:
+        return tuple(sorted(frontier)) in self._len_memo
+
+    def length_at(self, frontier: Sequence[int]) -> int:
+        """The document's length at `frontier`: remembered, else a full
+        checkout, which is then remembered."""
+        n = self._len_memo.get(tuple(sorted(frontier)))
+        if n is None:
+            n = len(self.checkout(frontier))
+            self.remember_length(frontier, n)
+        return n
+
+    def remember_length(self, frontier: Sequence[int], n: int) -> None:
+        """`n` must be exact: what a checkout at `frontier` returned, or
+        exact arithmetic on such a number (the parents' length plus an
+        appended op's own change)."""
+        memo = self._len_memo
+        key = tuple(sorted(frontier))
+        if key not in memo and len(memo) >= LEN_MEMO_SIZE:
+            del memo[next(iter(memo))]
+        memo[key] = n
 
     # --- misc ---------------------------------------------------------------
 

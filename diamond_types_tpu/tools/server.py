@@ -459,17 +459,18 @@ def _crdt_next_seq(aa, agent: int) -> int:
     return nxt
 
 
-def _crdt_apply_op(ol: OpLog, op: dict, cache: Optional[dict] = None) -> None:
+def _crdt_apply_op(ol: OpLog, op: dict) -> None:
     """Fold one browser-CRDT op (original position + explicit parents)
     into the oplog; idempotent on (agent, seq) replays. Validation runs
     BEFORE any mutation: a bad op must not leave a half-appended log.
 
-    `cache` (shared across one batch) carries (frontier, doc-length) from
-    the previous op: client batches are almost always a linear chain
-    (each op's parents = the previous op's result), so only the first op
-    pays a full checkout — without it a reconnect pushing hundreds of
-    queued ops would run O(ops x history) Branch merges under
-    store.lock, stalling every other endpoint."""
+    The document's length at the op's parents comes from the oplog's
+    memo (`OpLog.length_at`) and the length after the op is remembered
+    for the version it makes: client batches are almost always a linear
+    chain (each op's parents = the previous op's result), so only a
+    version nothing remembers pays a full checkout — without it a
+    reconnect pushing hundreds of queued ops would run O(ops x history)
+    Branch merges under store.lock, stalling every other endpoint."""
     from operator import index as _ix
     name = op["agent"]
     if not _agent_name_ok(name):
@@ -497,10 +498,7 @@ def _crdt_apply_op(ol: OpLog, op: dict, cache: Optional[dict] = None) -> None:
     # Positions are only meaningful against the document AT THE OP'S
     # PARENTS: an out-of-range op accepted here is persisted and poisons
     # every future merge on every peer, so length-check before mutating.
-    if cache is not None and cache.get("frontier") == tuple(frontier):
-        blen = cache["blen"]
-    else:
-        blen = len(ol.checkout(frontier))
+    blen = ol.length_at(frontier)
     if op.get("kind") == "ins":
         pos = _ix(op["pos"])
         content = op.get("content")
@@ -528,9 +526,7 @@ def _crdt_apply_op(ol: OpLog, op: dict, cache: Optional[dict] = None) -> None:
         blen -= n
     else:
         raise ValueError("bad crdt op kind")
-    if cache is not None:
-        cache["frontier"] = (lv,)
-        cache["blen"] = blen
+    ol.remember_length([lv], blen)
 
 
 def _crdt_ops_since(ol: OpLog, have: dict) -> list:
@@ -1349,8 +1345,13 @@ class SyncHandler(BaseHTTPRequestHandler):
                     req.get("version") or []))
                 # Validate the WHOLE batch against the doc length at the
                 # client's version before touching the oplog: a rejected op
-                # must not leave earlier batch ops half-applied.
-                blen = len(ol.checkout(frontier))
+                # must not leave earlier batch ops half-applied. A writer
+                # who pushes from the version their last push returned
+                # finds that length remembered; any other version pays a
+                # full checkout here, once.
+                ph.count("len_hit" if ol.length_known(frontier)
+                         else "len_miss")
+                blen = ol.length_at(frontier)
                 ph.step("edit.apply")
                 for op in ops:
                     if op[0] == "ins":
@@ -1373,6 +1374,7 @@ class SyncHandler(BaseHTTPRequestHandler):
                         lv = ol.add_delete_at(agent, frontier, op[1],
                                               op[2], None)
                     frontier = [lv]
+                ol.remember_length(frontier, blen)
                 out = ol.cg.local_to_remote_frontier(frontier)
             ph.step("edit.publish")
             self.store.mark_dirty(doc_id)
@@ -1437,10 +1439,9 @@ class SyncHandler(BaseHTTPRequestHandler):
             applied = 0
             try:
                 with self.store.lock:
-                    cache = {}   # (frontier, blen) carried across the batch
                     for op in req.get("push") or []:
                         try:
-                            _crdt_apply_op(ol, op, cache)
+                            _crdt_apply_op(ol, op)
                         except AssertionError as e:
                             # engine invariant tripped mid-apply (e.g. a doc
                             # poisoned before op validation existed): a
@@ -1513,6 +1514,12 @@ class SyncHandler(BaseHTTPRequestHandler):
 
 class _Server(ThreadingHTTPServer):
     store: DocStore = None
+    # The listen queue. The stdlib's 5 overflows when a few dozen
+    # clients, each on a connection a request, reconnect while the accept
+    # loop waits for the interpreter: a connection that falls out waits
+    # for TCP's retransmission timers (seconds, then tens of seconds),
+    # outlives its client's time-out and leaves a push with no answer.
+    request_queue_size = 128
 
     def __init__(self, *a, **kw) -> None:
         super().__init__(*a, **kw)

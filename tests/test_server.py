@@ -775,3 +775,233 @@ def test_crdt_peer_astral_unit_ops():
     finally:
         srv.shutdown()
         srv.server_close()
+
+
+# ---- the length at a writer's version: remembered, not checked out ----------
+
+def _checkouts(monkeypatch):
+    """Every full checkout an oplog pays from here on, by frontier."""
+    from diamond_types_tpu.text.oplog import OpLog
+    calls = []
+    real = OpLog.checkout
+
+    def checkout(ol, frontier):
+        calls.append(tuple(frontier))
+        return real(ol, frontier)
+
+    monkeypatch.setattr(OpLog, "checkout", checkout)
+    return calls
+
+
+def _edit_counts(srv):
+    """`len_hit` / `len_miss` of the `http.edit` row. A request's rows
+    are written when its root closes, after the response."""
+    import time
+    table = srv.RequestHandlerClass.store.obs.phases
+    deadline = time.monotonic() + 5.0
+    seen = None
+    while time.monotonic() < deadline:
+        row = table.snapshot()["phases"].get("http.edit") or {}
+        now = (row.get("count", 0), dict(row.get("counts") or {}))
+        if now == seen:
+            return now[1]
+        seen = now
+        time.sleep(0.05)
+    return seen[1]
+
+
+def test_edit_a_writers_second_push_finds_the_length_remembered(monkeypatch):
+    srv, base = _boot_server()
+    try:
+        calls = _checkouts(monkeypatch)
+        w1 = DumbClient(base, "memo", "web-one")     # /state: a checkout
+        del calls[:]
+        w1.edit([{"kind": "ins", "pos": 0, "text": "hello \U0001F600"}])
+        assert calls == [()]                # the empty version: a miss
+        w1.edit([{"kind": "ins", "pos": 7, "text": "!"},
+                 {"kind": "del", "start": 0, "end": 1}])
+        w1.edit([{"kind": "ins", "pos": 7, "text": "?"}])
+        assert calls == [()]                # from their own head: hits
+        assert _edit_counts(srv) == {"len_miss": 1, "len_hit": 2}
+        # a second writer starts at the tip the first one left: a hit;
+        # then both type from their own heads and never pay a checkout
+        w2 = DumbClient(base, "memo", "web-two")
+        del calls[:]
+        w2.edit([{"kind": "ins", "pos": 0, "text": ">"}])
+        w1.edit([{"kind": "ins", "pos": 8, "text": "<"}])
+        w2.edit([{"kind": "del", "start": 0, "end": 1}])
+        assert calls == []
+        assert _edit_counts(srv) == {"len_miss": 1, "len_hit": 5}
+        # pulling the peer's edits leaves two heads nothing remembers:
+        # one checkout at exactly that version, and hits again after it
+        w1.sync()
+        assert len(w1.version) == 2
+        del calls[:]
+        w1.edit([{"kind": "ins", "pos": len(w1.text), "text": "."}])
+        assert len(calls) == 1 and len(calls[0]) == 2
+        w1.edit([{"kind": "ins", "pos": len(w1.text), "text": "."}])
+        assert len(calls) == 1
+        assert _edit_counts(srv) == {"len_miss": 2, "len_hit": 6}
+        w2.sync()
+        w1.sync()
+        assert w1.text == w2.text == "ello \U0001F600!?<.."
+        ol = srv.RequestHandlerClass.store.get("memo")
+        assert ol.checkout_tip().snapshot() == w1.text
+    finally:
+        srv.shutdown()
+        srv.server_close()
+
+
+def test_edit_a_refused_batch_leaves_oplog_memo_and_next_answer(monkeypatch):
+    import json
+    import urllib.error
+    import urllib.request
+    srv, base = _boot_server()
+    try:
+        w = DumbClient(base, "refuse", "web-one")
+        w.edit([{"kind": "ins", "pos": 0, "text": "hello"}])
+        ol = srv.RequestHandlerClass.store.get("refuse")
+        n_ops, memo = len(ol), dict(ol._len_memo)
+        calls = _checkouts(monkeypatch)
+        bad = [
+            [{"kind": "ins", "pos": 6, "text": "x"}],            # past end
+            [{"kind": "ins", "pos": 5, "text": "ok"},            # the second
+             {"kind": "del", "start": 3, "end": 8}],             # op is bad
+            [{"kind": "ins", "pos": 0, "text": ""}],             # no text
+            [{"kind": "ins", "pos": 0, "text": "\ud800"}],       # surrogate
+            [{"kind": "ins", "pos": 0, "text": 7}],
+            [{"kind": "del", "start": 2, "end": 2}],
+        ]
+        for ops in bad:
+            body = json.dumps({"agent": "web-one", "version": w.version,
+                               "ops": ops}).encode("utf8", "surrogatepass")
+            try:
+                urllib.request.urlopen(urllib.request.Request(
+                    f"{base}/doc/refuse/edit", data=body))
+                raise AssertionError(f"accepted {ops}")
+            except urllib.error.HTTPError as e:
+                assert e.code == 400, ops
+                assert json.loads(e.read()) == {"error": "bad op"}, ops
+        assert len(ol) == n_ops and ol._len_memo == memo and calls == []
+        # the length the refusals were held against is the next push's
+        # too: position 5 is the end, 6 is still past it
+        w.edit([{"kind": "ins", "pos": 5, "text": "!"},
+                {"kind": "del", "start": 0, "end": 6}])
+        assert calls == []
+        assert ol.checkout_tip().snapshot() == ""
+        del calls[:]
+        assert _edit_counts(srv) == {"len_miss": 1,
+                                     "len_hit": len(bad) + 1}
+        # a refusal at a version nothing remembers pays its checkout
+        # and remembers that version alone
+        head = ol.cg.local_to_remote_frontier([2])
+        body = json.dumps({"agent": "web-two", "version": head,
+                           "ops": [{"kind": "ins", "pos": 4, "text": "x"}]})
+        try:
+            urllib.request.urlopen(urllib.request.Request(
+                f"{base}/doc/refuse/edit", data=body.encode("utf8")))
+            raise AssertionError("accepted an insert past the end")
+        except urllib.error.HTTPError as e:
+            assert e.code == 400
+        assert calls == [(2,)] and ol.length_at([2]) == 3
+        assert len(ol._len_memo) == len(memo) + 2
+        assert ol.cg.agent_assignment.try_get_agent("web-two") is None
+    finally:
+        srv.shutdown()
+        srv.server_close()
+
+
+def test_crdt_ops_batches_pay_one_checkout_a_version_nothing_remembers(
+        monkeypatch):
+    """The per-request cache is gone: the oplog's own memo carries the
+    length from one op of a chain to the next, and from one request to
+    the next."""
+    import json
+    import urllib.error
+    import urllib.request
+    srv, base = _boot_server()
+    try:
+        calls = _checkouts(monkeypatch)
+        a = _CrdtPeer(base, "chain", "anna")
+        a.edit_ins(0, "hello \U0001F600 world")     # 13 unit ops, one chain
+        a.edit_del(0, 6)
+        a.sync()
+        assert calls == [()]
+        a.edit_ins(7, "!!")                 # continues the chain: none
+        a.sync()
+        assert calls == [()]
+        ol = srv.RequestHandlerClass.store.get("chain")
+        assert ol.checkout_tip().snapshot() == "\U0001F600 world!!"
+        del calls[:]
+        # a batch that is not one chain: each op from a version of its own
+        b = _CrdtPeer(base, "chain", "bert")
+        mid = [["anna", 4]]                 # "hello": never asked before
+        b.pending = [
+            {"agent": "bert", "seq": 0, "parents": mid, "kind": "ins",
+             "pos": 5, "content": "A"},
+            {"agent": "bert", "seq": 1, "parents": a.frontier,
+             "kind": "ins", "pos": 0, "content": "B"},
+            {"agent": "bert", "seq": 2, "parents": [["bert", 0]],
+             "kind": "del", "pos": 0, "len": 6},
+        ]
+        b.sync()
+        assert calls == [(4,)]              # the tip and bert's own: known
+        assert ol.length_at(ol.cg.remote_to_local_frontier(
+            [("bert", 7)])) == 0
+        text = ol.checkout_tip().snapshot()
+        assert sorted(text) == sorted("B\U0001F600 world!!")
+        # a bad op in the middle: the ops before it stay, the length it
+        # was refused against is exact, and the next batch goes on
+        del calls[:]
+        n_ops = len(ol)
+        tip = ol.cg.local_to_remote_frontier(ol.version)
+        c = _CrdtPeer(base, "chain", "cara")
+        c.pending = [
+            {"agent": "cara", "seq": 0, "parents": tip, "kind": "ins",
+             "pos": len(text), "content": "."},
+            {"agent": "cara", "seq": 1, "parents": [["cara", 0]],
+             "kind": "ins", "pos": len(text) + 2, "content": "x"},
+        ]
+        try:
+            c.sync()
+            raise AssertionError("accepted an insert past the end")
+        except urllib.error.HTTPError as e:
+            assert e.code == 400
+        assert len(ol) == n_ops + 1
+        c.pending = [
+            {"agent": "cara", "seq": 1, "parents": [["cara", 0]],
+             "kind": "ins", "pos": len(text) + 1, "content": "x"}]
+        c.sync()
+        assert len(calls) == 1 and len(calls[0]) == len(tip) == 2
+        assert ol.checkout_tip().snapshot() == text + ".x"
+    finally:
+        srv.shutdown()
+        srv.server_close()
+
+
+def test_the_listen_queue_holds_a_burst_of_connections():
+    """More clients than the stdlib's backlog of 5 connect before the
+    accept loop runs: none may fall out of the listen queue, where it
+    would wait a second or more for TCP to retransmit."""
+    import socket
+    from diamond_types_tpu.tools.server import _Server
+    assert _Server.request_queue_size >= 64
+    srv = serve(port=0, data_dir=None)      # listening, nobody accepts yet
+    conns = []
+    try:
+        for _ in range(40):
+            c = socket.create_connection(srv.server_address, timeout=0.9)
+            c.sendall(b"GET /doc/burst/state HTTP/1.0\r\n\r\n")
+            conns.append(c)
+    finally:
+        # `shutdown()` waits for a loop that has run
+        threading.Thread(target=srv.serve_forever, daemon=True).start()
+        try:
+            for c in conns:
+                c.settimeout(10)
+                assert c.recv(64).startswith(b"HTTP/1.0 200")
+        finally:
+            for c in conns:
+                c.close()
+            srv.shutdown()
+            srv.server_close()
