@@ -27,6 +27,7 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..obs.phases import phase
 from ..tpu.batch import replay_batch
 from ..tpu.runtime import devices
 
@@ -247,6 +248,21 @@ def mesh_fused_replay(mesh: Mesh, sessions, plans):
     from a host tracker-walk plan — the mesh rung consumes either
     unchanged, and a transform fallback upstream simply arrives as a
     host plan."""
+    with phase("mesh.replay") as ph:
+        return _mesh_fused_replay(mesh, sessions, plans, ph)
+
+
+def _mesh_fused_replay(mesh: Mesh, sessions, plans, ph):
+    """`mesh_fused_replay` under its `mesh.replay` phase `ph`. The
+    steps are the host pack, staging (arena hand-back, device-side
+    gather or the control arm's host staging), dispatch (jit lookup,
+    plan upload, the call), the length fence and adoption (rows back
+    to their chips, `adopt_results`, the arena). The row's own counts
+    say what the window moved: `rows`, `rows_off_home` (a row whose
+    session's chip is not the mesh device whose slice replays it),
+    `ici_bytes` (such a row and its length cross the interconnect on
+    the way back, and on the way in too when it was gathered),
+    `arena_hits` / `arena_misses`, and by capacity class `cap.<cap>.dispatches` / `.docs` / `.padded_rows`."""
     import time
 
     import jax.numpy as jnp
@@ -262,6 +278,7 @@ def mesh_fused_replay(mesh: Mesh, sessions, plans):
     cap = sessions[0].cap
     mi = sessions[0].max_ins
     ndev = int(mesh.devices.size)
+    ph.step("mesh.pack")
     n0 = _pow2(max(max(p.n_ops for p in plans), 1))
     bp0 = pad_batch_count(b, ndev)
     # warm mesh classes are mesh-legal by construction; multiple=ndev
@@ -272,19 +289,30 @@ def mesh_fused_replay(mesh: Mesh, sessions, plans):
     note_transfer(plan_bytes, rung="mesh", purpose="plan")
     staged_bytes = plan_bytes
     sh = NamedSharding(mesh, P(mesh.axis_names[0]))
-    fn = mesh_flush_fn(mesh, bp, n, mi, cap)
+    ph.step("mesh.stage")
+    # where each session lives, and the mesh device whose slice
+    # replays its row (rows are batched in class order)
+    homes = [_home(s.docs) for s in sessions]
+    mesh_devs = list(mesh.devices.flat)
+    per = bp // ndev
+    off_home = sum(h is not None and h != mesh_devs[i // per]
+                   for i, h in enumerate(homes))
+    crossings = off_home            # every such row goes back home
     reuse = _arena.acquire(mesh, cap, mi, sessions, bp) \
         if _arena.DEVICE_STAGE.enabled else None
     if reuse is not None:
         # donated-buffer fast path: window k's outputs are window
         # k+1's inputs, already sharded over this mesh — no staging
         docs_d, lens_d = reuse
+        ph.count("arena_hits")
     elif _arena.DEVICE_STAGE.enabled:
         # device-side gather: resident rows never visit host numpy
         docs_d = _gather_rows(sh, [s.docs for s in sessions], bp,
                               (cap,), 0)
         lens_d = _gather_rows(sh, [jnp.asarray(s.lens, jnp.int32)
                                    for s in sessions], bp, (), -1)
+        ph.count("arena_misses")
+        crossings += off_home       # and came over chip-to-chip
     else:
         # control arm: legacy host staging — every resident byte
         # round-trips through numpy and is accounted as staged
@@ -298,22 +326,31 @@ def mesh_fused_replay(mesh: Mesh, sessions, plans):
         staged_bytes += docs_h.nbytes + lens_h.nbytes
         docs_d = jax.device_put(jnp.asarray(docs_h), sh)
         lens_d = jax.device_put(jnp.asarray(lens_h), sh)
+    ph.step("mesh.dispatch")
+    fn = mesh_flush_fn(mesh, bp, n, mi, cap)
     out_docs, out_lens = fn(docs_d, lens_d,
                             *(jax.device_put(jnp.asarray(x), sh)
                               for x in (pos, dlen, ilen, chars)))
     # the length fetch is the completion fence + parity cross-check
+    ph.step("mesh.fence")
     t_fence = time.perf_counter()
     got = np.asarray(out_lens)
     device_s = time.perf_counter() - t_fence
+    ph.step("mesh.adopt")
     # each committed row goes back to the chip its session lives on
     # (its bank's): a plain `out_docs[i]` of the sharded result comes
     # back replicated over the whole mesh — one copy per chip
-    homes = [_home(s.docs) for s in sessions]
     ok = adopt_results(sessions, plans, _rows_at(out_docs, homes),
                        _rows_at(out_lens, homes), got)
     if _arena.DEVICE_STAGE.enabled:
         _arena.adopt(mesh, cap, mi, out_docs, out_lens, sessions,
                      ok, bp)
+    ph.count("rows", b)
+    ph.count("rows_off_home", off_home)
+    ph.count("ici_bytes", crossings * (4 * cap + 4))
+    ph.count(f"cap.{cap}.dispatches")
+    ph.count(f"cap.{cap}.docs", b)
+    ph.count(f"cap.{cap}.padded_rows", bp)
     return ok, device_s, bp, staged_bytes
 
 

@@ -83,6 +83,7 @@ class MergeScheduler:
                  flush_workers: bool = True,
                  warmup: bool = False,
                  mesh_window: bool = False,
+                 mesh_window_rows: Optional[int] = None,
                  device_plan: bool = False,
                  pallas: bool = False) -> None:
         """`resolve(doc_id) -> OpLog` is the document authority —
@@ -102,8 +103,11 @@ class MergeScheduler:
         device dispatches per window), `pump()` assembles EVERY due
         shard's fusable tails into one mesh-sharded super-batch and
         issues a single `shard_map` program over the `docs` axis —
-        see `_flush_window`. `device_plan=True` (fused device engine
-        only) plans tails through the device transform
+        see `_flush_window`; `mesh_window_rows` is the most rows of one
+        class that go out in one such program (default `n_shards x
+        flush_docs`, one bucket a shard: a deployment states it when
+        its warm-up is built around it). `device_plan=True` (fused
+        device engine only) plans tails through the device transform
         (tpu/xform.plan_tails_device) instead of the host tracker walk;
         `pallas=True` replays through the Pallas step kernel where one
         device holds the window. The ladder below a replay (per-doc →
@@ -131,6 +135,12 @@ class MergeScheduler:
         # mesh flush windows ride on fused sessions (the super-batch is
         # assembled from FusedDocSession plan rows)
         self.mesh_window = bool(mesh_window) and self.fused
+        if mesh_window_rows is None:
+            mesh_window_rows = n_shards * flush_docs
+        if int(mesh_window_rows) < 1:
+            raise ValueError(
+                f"mesh_window_rows={mesh_window_rows!r}: at least 1")
+        self.mesh_window_rows = int(mesh_window_rows)
         self.device_plan = bool(device_plan) and self.fused
         self.pallas = bool(pallas) and self.fused
         self._mesh = None          # lazy: first window / warmup builds
@@ -372,11 +382,25 @@ class MergeScheduler:
                 items = self.queue.take(shard, bucket)
                 if items:
                     taken.append((shard, reason, items))
+            window = bool(taken) and self.mesh_window
+            if window:
+                # the window runs on THIS thread and its items have
+                # left the queue: count it in flight before the lock
+                # is let go, or a drain() on another thread finds the
+                # queue empty, nothing in flight, and returns while
+                # the window's sessions are still being built
+                with self._idle_cv:
+                    self._inflight += 1
         synced = 0
-        if taken and self.mesh_window:
+        if window:
             # window coordinator: every due shard's bucket folds into
             # ONE mesh-sharded program instead of N worker dispatches
-            synced = self._flush_window(taken)
+            try:
+                synced = self._flush_window(taken)
+            finally:
+                with self._idle_cv:
+                    self._inflight -= 1
+                    self._idle_cv.notify_all()
         else:
             for shard, reason, items in taken:
                 if self._flush_workers:
@@ -646,7 +670,9 @@ class MergeScheduler:
           3. fusable rows concatenated ACROSS shards by (cap, max_ins)
              shape class and replayed by `mesh_fused_replay` — one
              `shard_map` program over the serve mesh's `docs` axis per
-             class (uniform-shape window ⇒ exactly one dispatch);
+             class (uniform-shape window ⇒ exactly one dispatch), and
+             one more for every `shards x flush_docs` rows beyond the
+             first when several buckets of a shard were due;
           4. per-shard adoption (`bank.adopt_window`): poisoned /
              length-drift rows evict to the host oracle, serial
              leftovers run the per-doc ladder — the SAME data-fault
@@ -658,6 +684,10 @@ class MergeScheduler:
         not replayed (plans are pure, so those sessions simply stay
         behind their oplogs until their next flush), while the classes
         that committed before it are adopted and accounted as usual.
+
+        The `sched.flush` root's steps are `window.plan` (2),
+        `window.replay` (3, device locks included) and `window.adopt`
+        (4); its counts say where the window's documents went.
 
         Lock order: shard locks (sorted) → oplog lock (inside
         plan/adopt) → device locks (sorted, deduped); the mesh device
@@ -691,11 +721,13 @@ class MergeScheduler:
                     attrs={"shards": len(shards), "docs": n_docs})
         t0 = time.perf_counter()
         with contextlib.ExitStack() as sstack:
-            if obs is not None:
-                sstack.enter_context(
-                    obs.phases.phase("sched.flush", span=fspan))
+            # the root the window's steps, the banks' phases and
+            # `mesh.replay` hang under (obs/phases.py)
+            root = NOOP_PHASE if obs is None else sstack.enter_context(
+                obs.phases.phase("sched.flush", span=fspan))
             for s in shards:
                 sstack.enter_context(self._shard_locks[s])
+            root.step("window.plan")
             wins = [self.banks[s].plan_window(
                         items, self._flush_resolve,
                         oplog_lock=self._sync_lock, min_fuse=1)
@@ -714,15 +746,27 @@ class MergeScheduler:
             # comprehension runs directly over the sorted shard list so
             # the acquisition order is lexically evident (dt-lint
             # unsorted-locks) and matches the witness's rank order.
+            root.step("window.replay")
             seen: set = set()
             dlocks = [lk for s in shards
                       if id(lk := self._device_locks[s]) not in seen
                       and not seen.add(id(lk))]
             dispatches = mesh_docs = padded_rows = staged_bytes = 0
+            homes_off_bank = 0
             failed: List[List[str]] = [[] for _ in entries]
             replayed: List[set] = [set() for _ in entries]
             err: Optional[BaseException] = None
-            for (cap, mi), rows in sorted(classes.items()):
+            # a class's rows go out in dispatches of at most
+            # `mesh_window_rows` (default `shards x flush_docs`: the
+            # largest batch class the boot warm-up and a flush of one
+            # bucket a shard can have compiled). Several buckets of a
+            # shard due at once would otherwise make a batch class of
+            # their own and compile it on the pump
+            max_rows = self.mesh_window_rows
+            chunks = [(key, rows[lo:lo + max_rows])
+                      for key, rows in sorted(classes.items())
+                      for lo in range(0, len(rows), max_rows)]
+            for (cap, mi), rows in chunks:
                 sessions = [r[2] for r in rows]
                 plans = [r[3] for r in rows]
                 t_cls = time.perf_counter()
@@ -765,9 +809,17 @@ class MergeScheduler:
                 PROFILER.observe_window(wall, device_s, len(rows),
                                         len(shards),
                                         staged_bytes=staged)
-                for good, (ei, _s, _sess, _plan, d) in zip(ok, rows):
+                for good, (ei, s, sess, _plan, d) in zip(ok, rows):
                     if good:
                         replayed[ei].add(d)
+                        # a committed row left on another chip than
+                        # its bank's (the fault PR 21 found and
+                        # repaired): counted, and must stay 0
+                        dev = self.banks[s].device
+                        if dev is not None and (
+                                sess.docs.devices() != {dev}
+                                or sess.lens.devices() != {dev}):
+                            homes_off_bank += 1
                     else:
                         failed[ei].append(d)
             # journey: the window path orchestrates the device phase
@@ -783,6 +835,14 @@ class MergeScheduler:
                             j.stamp(it.trace.trace_id,
                                     "device_replayed")
             # adoption + per-bucket flush accounting, per shard
+            root.step("window.adopt")
+            # where the window's documents went: the mesh rung, no
+            # device work (an empty plan), or the per-doc ladder
+            root.count("window_docs", n_docs)
+            root.count("window_mesh_docs", mesh_docs)
+            root.count("window_serial_docs",
+                       sum(len(w["serial"]) for w in wins))
+            root.count("homes_off_bank", homes_off_bank)
             for ei, (s, reason, items) in enumerate(entries):
                 self.banks[s].adopt_window(
                     wins[ei], failed[ei], oplog_lock=self._sync_lock,
