@@ -497,15 +497,16 @@ class Graph:
 
     # --- export for the device tier --------------------------------------
 
-    def as_arrays(self):
-        """Columnar export: (starts, ends, shadows, parent_idx CSR) as numpy."""
+    def as_arrays(self, first: int = 0):
+        """Columnar export of the entries from `first` on: (starts, ends,
+        shadows, parent_idx CSR counted from `first`) as numpy."""
         import numpy as np
-        starts = np.asarray(self.starts, dtype=np.int64)
-        ends = np.asarray(self.ends, dtype=np.int64)
-        shadows = np.asarray(self.shadows, dtype=np.int64)
-        indptr = np.zeros(len(self.parents) + 1, dtype=np.int64)
+        starts = np.asarray(self.starts[first:], dtype=np.int64)
+        ends = np.asarray(self.ends[first:], dtype=np.int64)
+        shadows = np.asarray(self.shadows[first:], dtype=np.int64)
+        indptr = np.zeros(len(starts) + 1, dtype=np.int64)
         flat: List[int] = []
-        for j, ps in enumerate(self.parents):
+        for j, ps in enumerate(self.parents[first:]):
             flat.extend(ps)
             indptr[j + 1] = len(flat)
         return starts, ends, shadows, indptr, np.asarray(flat, dtype=np.int64)

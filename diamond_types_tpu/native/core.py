@@ -19,6 +19,7 @@ _load_error: Optional[str] = None
 # text, not real op LVs) — one definition, shared with native/dt_core.cpp's
 # UNDERWATER constant.
 from ..core.span import UNDERWATER_START as UNDERWATER  # noqa: E402
+from ..text.op import INS as _INS  # noqa: E402
 
 
 def _load():
@@ -61,6 +62,12 @@ def _configure(lib) -> None:
         np.ctypeslib.ndpointer(np.int64, flags="C")]
     lib.dt_load_ins_arena.argtypes = [
         ct.c_void_p, ct.c_int64, np.ctypeslib.ndpointer(np.int32, flags="C")]
+    # the `_tail` loaders: (ctx, from, n, the whole loader's columns)
+    for name in ("graph", "agent_runs", "ops", "ins_arena"):
+        whole = getattr(lib, f"dt_load_{name}")
+        tail = getattr(lib, f"dt_load_{name}_tail")
+        tail.argtypes = [ct.c_void_p, ct.c_int64] + whole.argtypes[1:]
+        tail.restype = ct.c_int64
     lib.dt_merge_into_doc.argtypes = [
         ct.c_void_p, np.ctypeslib.ndpointer(np.int32, flags="C"), ct.c_int64,
         np.ctypeslib.ndpointer(np.int64, flags="C"), ct.c_int64,
@@ -203,7 +210,10 @@ def native_available() -> bool:
 
 class NativeContext:
     """A C++ mirror of an OpLog's merge-relevant state (graph, agent runs,
-    op runs). Rebuilt lazily when the oplog grows."""
+    op runs, insert arena), brought up to date lazily, by `sync()`, when
+    the oplog has grown. The oplog only appends, so the mirror follows it
+    by appending too; it is built whole the first time and wherever a
+    column does not continue what the mirror holds."""
 
     def __init__(self, oplog) -> None:
         lib = _load()
@@ -212,6 +222,14 @@ class NativeContext:
         self._ptr = lib.dt_ctx_new()
         self._built_len = -1
         self._oplog = oplog
+        # entries the mirror holds of each column: agents, graph
+        # entries, agent runs, op runs, insert-arena chars
+        self._held = (0, 0, 0, 0, 0)
+        # syncs that found the oplog grown, by how the mirror followed,
+        # and the column entries the last of them sent
+        self.appended = 0
+        self.rebuilt = 0
+        self.last_sent = 0
 
     def __del__(self):
         try:
@@ -223,39 +241,73 @@ class NativeContext:
         ol = self._oplog
         if self._built_len == len(ol):
             return
+        if self._built_len >= 0 and self._send_from(self._held):
+            self.appended += 1
+            return
         lib = self._lib
-        # Rebuild from scratch (bulk load is cheap: O(n) columnar copies).
         lib.dt_ctx_free(self._ptr)
         self._ptr = lib.dt_ctx_new()
-        for name in ol.cg.agent_assignment.agent_names:
-            lib.dt_add_agent(self._ptr, name.encode("utf8"))
+        self._built_len = -1
+        if not self._send_from((0, 0, 0, 0, 0)):
+            raise RuntimeError("native mirror: the oplog's columns do "
+                               "not load")
+        self.rebuilt += 1
+
+    def _send_from(self, held) -> bool:
+        """Send the ctx every column from what it `held` onwards, the
+        last entry held included: a run-length-encoded last entry may
+        have been extended in place (a graph entry's end, an agent
+        run's lv1, an op run's loc, direction and content span). False
+        where a column is shorter than what was held or a loader finds
+        that it does not continue the ctx's own: the ctx is then to be
+        built anew."""
+        ol = self._oplog
+        lib, ptr = self._lib, self._ptr
+        n_len = len(ol)
+        names = ol.cg.agent_assignment.agent_names
         g = ol.cg.graph
-        starts, ends, shadows, indptr, flat = g.as_arrays()
-        if flat.size == 0:
-            flat = np.zeros(1, dtype=np.int64)
-        lib.dt_load_graph(self._ptr, len(starts),
-                          np.ascontiguousarray(starts),
-                          np.ascontiguousarray(ends),
-                          np.ascontiguousarray(shadows),
-                          np.ascontiguousarray(indptr),
-                          np.ascontiguousarray(flat))
         gr = ol.cg.agent_assignment.global_runs
-        lv0 = np.asarray([r[0] for r in gr], dtype=np.int64)
-        lv1 = np.asarray([r[1] for r in gr], dtype=np.int64)
-        ag = np.asarray([r[2] for r in gr], dtype=np.int64)
-        sq = np.asarray([r[3] for r in gr], dtype=np.int64)
-        lib.dt_load_agent_runs(self._ptr, len(gr), lv0, lv1, ag, sq)
         runs = ol.ops.runs
-        lv = np.asarray([r.lv for r in runs], dtype=np.int64)
-        kind = np.asarray([r.kind for r in runs], dtype=np.uint8)
-        fwd = np.asarray([1 if r.fwd else 0 for r in runs], dtype=np.uint8)
-        st = np.asarray([r.start for r in runs], dtype=np.int64)
-        en = np.asarray([r.end for r in runs], dtype=np.int64)
-        cp, arena, arena_chars = content_columns(ol)
-        lib.dt_load_ops(self._ptr, len(runs), lv, kind, fwd, st, en, cp)
-        lib.dt_load_ins_arena(self._ptr, arena_chars,
-                              np.ascontiguousarray(arena))
-        self._built_len = len(ol)
+        now = (len(names), len(g.starts), len(gr), len(runs),
+               ol.ops.arena_len(_INS))
+        if n_len < self._built_len or any(
+                n < h for n, h in zip(now, held)):
+            return False
+        for name in names[held[0]:]:
+            lib.dt_add_agent(ptr, name.encode("utf8"))
+        i64 = np.int64
+        # the last entry held of each run-length-encoded column again
+        g0, a0, r0 = (max(h - 1, 0) for h in held[1:4])
+        starts, ends, shadows, indptr, flat = g.as_arrays(g0)
+        if flat.size == 0:
+            flat = np.zeros(1, dtype=i64)      # a pointer is passed
+        acols = np.asarray(gr[a0:], dtype=i64).reshape(-1, 4)
+        tail = runs[r0:]
+        ok = (
+            lib.dt_load_graph_tail(ptr, g0, now[1] - g0, starts, ends,
+                                   shadows, indptr, flat) == now[1]
+            and lib.dt_load_agent_runs_tail(
+                ptr, a0, now[2] - a0,
+                *(np.ascontiguousarray(acols[:, c]) for c in range(4))
+            ) == now[2]
+            and lib.dt_load_ops_tail(
+                ptr, r0, now[3] - r0,
+                np.asarray([r.lv for r in tail], dtype=i64),
+                np.asarray([r.kind for r in tail], dtype=np.uint8),
+                np.asarray([1 if r.fwd else 0 for r in tail],
+                           dtype=np.uint8),
+                np.asarray([r.start for r in tail], dtype=i64),
+                np.asarray([r.end for r in tail], dtype=i64),
+                content_offsets(tail)) == now[3]
+            and lib.dt_load_ins_arena_tail(
+                ptr, held[4], now[4] - held[4],
+                arena_chars(ol, held[4])) == now[4])
+        if ok:
+            self._held = now
+            self._built_len = n_len
+            self.last_sent = (now[0] - held[0] + now[1] - g0 + now[2] - a0
+                              + now[3] - r0 + now[4] - held[4])
+        return ok
 
     def transform(self, from_frontier: Sequence[int],
                   merge_frontier: Sequence[int]):
@@ -705,22 +757,31 @@ def graph_rebuild_native(g_start, g_end, g_off, g_par):
             ver[:int(vern[0])])
 
 
-def content_columns(oplog):
-    """(cp, arena) columns in the exact layout dt_load_ops /
-    dt_load_ins_arena expect: per-run insert-arena offset (-1 = no
-    content) and the whole INS arena as utf-32 code points. Shared by
-    NativeContext.sync and tools/dump_columns so the native loaders'
-    arena invariants live in one place."""
-    from ..text.op import INS
-    runs = oplog.ops.runs
-    cp = np.asarray(
+def content_offsets(runs) -> np.ndarray:
+    """Per-run insert-arena offset (-1 = no content) of `runs`, the
+    `cp` column dt_load_ops expects."""
+    return np.asarray(
         [r.content_pos[0] if r.content_pos is not None else -1
          for r in runs], dtype=np.int64)
-    arena_str = oplog.ops._arenas[INS].get((0, oplog.ops.arena_len(INS)))
-    arena = np.frombuffer(arena_str.encode("utf-32-le"), dtype=np.int32)
+
+
+def arena_chars(oplog, start: int = 0) -> np.ndarray:
+    """The INS arena from char `start` on as utf-32 code points, the
+    layout dt_load_ins_arena expects (never empty: a pointer is
+    passed)."""
+    s = oplog.ops.get_content(_INS, (start, oplog.ops.arena_len(_INS)))
+    arena = np.frombuffer(s.encode("utf-32-le"), dtype=np.int32)
     if arena.size == 0:
         arena = np.zeros(1, dtype=np.int32)
-    return cp, arena, len(arena_str)
+    return arena
+
+
+def content_columns(oplog):
+    """(cp, arena, arena chars) columns in the exact layout dt_load_ops /
+    dt_load_ins_arena expect. Shared with tools/dump_columns so the
+    native loaders' arena invariants live in one place."""
+    return (content_offsets(oplog.ops.runs), arena_chars(oplog),
+            oplog.ops.arena_len(_INS))
 
 
 def merge_native(oplog, init: str, from_frontier, merge_frontier):

@@ -354,6 +354,36 @@ def _empty_plan(frontier, synced_to, doc_len, mi) -> TailPlan:
                     doc_len, frontier, synced_to)
 
 
+def _walk_pieces(ol, xf):
+    """(pos, length, content | None for a delete) of each visible piece
+    of the Python walk `xf`; a `pos` of None (a delete that already
+    happened) is a no-op and is skipped."""
+    from ..text.op import INS
+    for _lv, op, pos in xf:
+        if pos is None:
+            continue
+        if op.kind == INS:
+            content = ol.ops.get_run_content(op)
+            yield pos, len(content), content if op.fwd else content[::-1]
+        else:
+            yield pos, len(op), None
+
+
+def _native_pieces(ol, lv, ln, kind, fwd, pos):
+    """The same of `NativeContext.transform`'s columns: `pos` < 0 where
+    the walk gives None; a piece lies inside one op run, whose content
+    the oplog's op store keeps."""
+    from ..text.op import INS
+    for v, n, k, f, p in zip(lv, ln, kind, fwd, pos):
+        if p < 0:
+            continue
+        if k == INS:
+            content = ol.ops.content_slice(v, n)
+            yield p, n, content if f else content[::-1]
+        else:
+            yield p, n, None
+
+
 class FusedDocSession:
     """A live document resident on the device as the replay-kernel
     state: `[cap]` char codes + length. Drop-in for the bank's session
@@ -412,45 +442,61 @@ class FusedDocSession:
 
     def _plan_tail(self, ph) -> TailPlan:
         """`plan_tail` under its `plan.tail` phase `ph`; the steps are
-        the transform walk, the row loop and the numpy fill."""
+        the transform, the row loop and the numpy fill. The transform
+        runs on the oplog's native mirror where there is one; the pure
+        Python walk is the fallback (and the tests' oracle)."""
         ol = self.oplog
         if self.synced_to >= len(ol):
             return _empty_plan(self.frontier, self.synced_to,
                                self.doc_len, self.max_ins)
-        mi = self.max_ins
+        from ..native import native_ctx_or_none
+        ctx = native_ctx_or_none(ol)
         ph.step("plan.xf")
-        xf = ol.get_xf_operations_full(list(self.frontier), ol.version)
-        ph.step("plan.rows")
+        if ctx is None:
+            ph.count("xf_python")
+            xf = ol.get_xf_operations_full(list(self.frontier), ol.version)
+            ph.step("plan.rows")
+            # the walk is lazy and stays so: the seconds inside its
+            # next() leave `plan.rows` for `plan.xf` (which so closes
+            # twice a plan)
+            pieces = _walk_pieces(ol, ph.timed(xf, "plan.xf"))
+        else:
+            ph.count("xf_native")
+            grew = (ctx.appended, ctx.rebuilt)
+            lv, ln, kind, fwd, pos, frontier = ctx.transform(
+                self.frontier, ol.version)
+            ctx.release_tracker()
+            ph.count("mirror_appended", ctx.appended - grew[0])
+            ph.count("mirror_rebuilt", ctx.rebuilt - grew[1])
+            ph.step("plan.rows")
+            pieces = _native_pieces(ol, lv.tolist(), ln.tolist(),
+                                    kind.tolist(), fwd.tolist(),
+                                    pos.tolist())
+        mi = self.max_ins
         rows: List[Tuple[int, int, int, str]] = []
         cur = self.doc_len
         peak = cur
-        from ..text.op import INS
-        # the walk is lazy and stays so: the seconds inside its next()
-        # leave `plan.rows` for `plan.xf` (which so closes twice a plan)
-        for _lv, op, pos in ph.timed(xf, "plan.xf"):
-            if pos is None:
-                continue
-            if op.kind == INS:
-                content = ol.ops.get_run_content(op)
-                if not op.fwd:
-                    content = content[::-1]
+        for pos, n, content in pieces:
+            if content is not None:
                 off = 0
-                while off < len(content):
+                while off < n:
                     chunk = content[off:off + mi]
                     rows.append((pos + off, 0, len(chunk), chunk))
                     off += len(chunk)
-                cur += len(content)
+                cur += n
                 peak = max(peak, cur)
             else:
-                d = len(op)
+                d = n
                 while d:
                     k = min(d, mi)
                     rows.append((pos, k, 0, ""))
                     d -= k
-                cur -= len(op)
+                cur -= n
         k = len(rows)
         ph.step("plan.pack")
-        frontier = tuple(int(x) for x in xf.next_frontier)
+        if ctx is None:
+            frontier = xf.next_frontier     # known once the walk has ended
+        frontier = tuple(int(x) for x in frontier)
         if k == 0:
             plan = _empty_plan(frontier, len(ol), self.doc_len, mi)
             plan.max_len = peak

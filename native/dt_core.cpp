@@ -56,6 +56,37 @@ static EventCounters g_events;
 
 struct Span { i64 start, end; };
 
+// A dense LV -> entry map brought up to date after its RLE column was cut
+// back to `from` entries and grown again to `n`. LVs below `keep` map as
+// before: those of the entries that stayed and, where only the last entry
+// was sent again (the same or longer), that entry's old ones.
+template <class SpanOf>
+static void refill_idx(std::vector<int32_t>& idx_of, size_t from, size_t n,
+                       i64 keep, SpanOf span_of) {
+  idx_of.resize(n ? (size_t)span_of(n - 1).end : 0);
+  for (size_t i = from; i < n; i++) {
+    Span s = span_of(i);
+    for (i64 v = std::max(s.start, keep); v < s.end; v++)
+      idx_of[v] = (int32_t)i;
+  }
+}
+
+// What `refill_idx` may keep when a column of `n_old` entries over LVs
+// [.., top) is cut back to `from` (its entry `from` starting at
+// `cut_start`) and `n_new` entries follow, or -1 where they do not
+// continue it. The log only appends, so the first entry cut comes back:
+// what follows starts where it started or, with nothing cut, at `top`;
+// and where only the last entry was cut, it is as long as it was or longer.
+static i64 keep_below(size_t from, size_t n_old, i64 cut_start, i64 top,
+                      i64 n_new, i64 new_start, i64 new_first_end) {
+  if (from > n_old) return -1;
+  if (n_new == 0) return from == n_old ? top : -1;
+  i64 expect = from < n_old ? cut_start : top;
+  if (new_start != expect) return -1;
+  if (from + 1 == n_old) return new_first_end >= top ? top : -1;
+  return expect;
+}
+
 static inline bool span_empty(const Span& s) { return s.end <= s.start; }
 
 static void push_reversed_rle(std::vector<Span>& out, Span s) {
@@ -83,24 +114,30 @@ struct Graph {
   // checkout fast path (from=[] merging the full graph).
   std::vector<i64> heads;
 
-  void build_idx() {
-    idx_of.assign(starts.empty() ? 0 : (size_t)ends.back(), 0);
-    for (size_t i = 0; i < starts.size(); i++)
-      for (i64 v = starts[i]; v < ends[i]; v++) idx_of[v] = (int32_t)i;
-    dent.resize(starts.size());
-    for (size_t i = 0; i < starts.size(); i++) {
+  // idx_of, dent and heads after the columns were cut back to `from`
+  // entries and grown again (see refill_idx for `keep`). The entries that
+  // came back name at least the parents the cut ones named, so a head
+  // they had struck stays struck.
+  void reindex(size_t from, i64 keep) {
+    size_t n = starts.size();
+    refill_idx(idx_of, from, n, keep,
+               [&](size_t i) { return Span{starts[i], ends[i]}; });
+    dent.resize(n);
+    if (from < n)
+      heads.erase(std::lower_bound(heads.begin(), heads.end(), starts[from]),
+                  heads.end());
+    for (size_t i = from; i < n; i++) {
       dent[i].start = starts[i];
-      size_t n = pn(i);
-      dent[i].np = (int32_t)n;
-      for (size_t k = 0; k < n && k < 2; k++) dent[i].p[k] = pb(i)[k];
+      size_t np = pn(i);
+      dent[i].np = (int32_t)np;
+      for (size_t k = 0; k < np && k < 2; k++) dent[i].p[k] = pb(i)[k];
+      // an entry's last LV is a head until a later entry names it
+      for (size_t k = 0; k < np; k++) {
+        auto it = std::lower_bound(heads.begin(), heads.end(), pb(i)[k]);
+        if (it != heads.end() && *it == pb(i)[k]) heads.erase(it);
+      }
+      heads.push_back(ends[i] - 1);
     }
-    heads.clear();
-    std::vector<i64> ps(pflat);
-    std::sort(ps.begin(), ps.end());
-    for (i64 e : ends)
-      if (!std::binary_search(ps.begin(), ps.end(), e - 1))
-        heads.push_back(e - 1);
-    std::sort(heads.begin(), heads.end());
   }
 
   inline size_t find_idx(i64 v) const { return idx_of[v]; }
@@ -399,15 +436,6 @@ struct Agents {
 
   std::vector<int32_t> idx_of;  // dense LV -> global run index
 
-  void build_idx() {
-    i64 top = 0;
-    for (const GRun& g : global_runs) top = std::max(top, g.lv1);
-    idx_of.assign((size_t)top, 0);
-    for (size_t i = 0; i < global_runs.size(); i++)
-      for (i64 v = global_runs[i].lv0; v < global_runs[i].lv1; v++)
-        idx_of[v] = (int32_t)i;
-  }
-
   inline const GRun& find_global(i64 lv) const {
     if (lv < (i64)idx_of.size()) return global_runs[idx_of[lv]];
     size_t lo = 0, hi = global_runs.size();
@@ -436,16 +464,6 @@ static const u8 INS = 0, DEL = 1;
 struct Ops {
   std::vector<OpRun> runs;
   std::vector<int32_t> idx_of;  // dense LV -> run index
-
-  void build_idx() {
-    i64 top = 0;
-    for (const OpRun& r : runs) top = std::max(top, r.lv + (r.end - r.start));
-    idx_of.assign((size_t)top, 0);
-    for (size_t i = 0; i < runs.size(); i++) {
-      i64 e = runs[i].lv + (runs[i].end - runs[i].start);
-      for (i64 v = runs[i].lv; v < e; v++) idx_of[v] = (int32_t)i;
-    }
-  }
 
   inline size_t find_idx(i64 lv) const {
     if (lv < (i64)idx_of.size()) return idx_of[lv];
@@ -2980,49 +2998,138 @@ void dt_add_agent(void* p, const char* name) {
   c->aa.client_runs.emplace_back();
 }
 
-// bulk loads (columnar)
+// What a ctx derived from its columns at their old length: the tracker
+// kept for the dumps, the compose cache (a new serial, so a packer that
+// holds the old one marshals its columns instead) and the fetch buffers.
+static void drop_derived(Ctx* c) {
+  c->last_tracker.reset();
+  c->zone_common.clear();
+  c->composed.clear();
+  c->compose_serial++;
+  c->linear_pieces.clear();
+  c->pack_steps.clear();
+}
+
+// Column loads. A `_tail` loader cuts its column back to `from` entries
+// and appends `n`: the caller sends a column from the last entry it had
+// sent onwards, since the log may have run-length-extended that entry in
+// place, and from 0 for a whole load. Each returns the column's new
+// length, or -1 (nothing changed) where the entries sent do not continue
+// the ones kept: the caller then loads a new ctx whole. `pindptr` counts
+// from the first entry sent.
+i64 dt_load_graph_tail(void* p, i64 from, i64 n, const i64* starts,
+                       const i64* ends, const i64* shadows,
+                       const i64* pindptr, const i64* pflat) {
+  Ctx* c = (Ctx*)p;
+  Graph& g = c->g;
+  size_t n_old = g.starts.size(), f = (size_t)from;
+  if (from < 0 || n < 0) return -1;
+  i64 keep = keep_below(f, n_old, f < n_old ? g.starts[f] : 0,
+                        n_old ? g.ends.back() : 0, n, n ? starts[0] : 0,
+                        n ? ends[0] : 0);
+  if (keep < 0) return -1;
+  drop_derived(c);
+  g.starts.resize(f); g.starts.insert(g.starts.end(), starts, starts + n);
+  g.ends.resize(f); g.ends.insert(g.ends.end(), ends, ends + n);
+  g.shadows.resize(f); g.shadows.insert(g.shadows.end(), shadows, shadows + n);
+  if (g.pindptr.empty()) g.pindptr.push_back(0);
+  g.pindptr.resize(f + 1);
+  i64 base = g.pindptr[f];
+  g.pflat.resize((size_t)base);
+  g.pflat.insert(g.pflat.end(), pflat, pflat + pindptr[n]);
+  for (i64 i = 1; i <= n; i++) g.pindptr.push_back(base + pindptr[i]);
+  g.reindex(f, keep);
+  return (i64)g.starts.size();
+}
+
+i64 dt_load_agent_runs_tail(void* p, i64 from, i64 n, const i64* lv0,
+                            const i64* lv1, const i64* agent,
+                            const i64* seq0) {
+  Ctx* c = (Ctx*)p;
+  Agents& aa = c->aa;
+  auto& gr = aa.global_runs;
+  size_t n_old = gr.size(), f = (size_t)from;
+  if (from < 0 || n < 0) return -1;
+  for (i64 i = 0; i < n; i++)
+    if (agent[i] < 0 || agent[i] >= (i64)aa.client_runs.size()) return -1;
+  i64 keep = keep_below(f, n_old, f < n_old ? gr[f].lv0 : 0,
+                        n_old ? gr.back().lv1 : 0, n, n ? lv0[0] : 0,
+                        n ? lv1[0] : 0);
+  if (keep < 0) return -1;
+  drop_derived(c);
+  // client_runs stay sorted by seq: the cut runs leave, the new ones are
+  // inserted in their place
+  auto by_seq = [](const AgentRun& a, const AgentRun& b) {
+    return a.seq_start < b.seq_start;
+  };
+  for (size_t i = f; i < n_old; i++) {
+    auto& runs = aa.client_runs[gr[i].agent];
+    AgentRun r{gr[i].seq0, gr[i].seq0 + (gr[i].lv1 - gr[i].lv0), gr[i].lv0};
+    auto it = std::lower_bound(runs.begin(), runs.end(), r, by_seq);
+    while (it != runs.end() && it->lv_start != r.lv_start) ++it;
+    if (it != runs.end()) runs.erase(it);
+  }
+  gr.resize(f);
+  for (i64 i = 0; i < n; i++) {
+    gr.push_back({lv0[i], lv1[i], agent[i], seq0[i]});
+    auto& runs = aa.client_runs[agent[i]];
+    AgentRun r{seq0[i], seq0[i] + (lv1[i] - lv0[i]), lv0[i]};
+    runs.insert(std::upper_bound(runs.begin(), runs.end(), r, by_seq), r);
+  }
+  refill_idx(aa.idx_of, f, gr.size(), keep,
+             [&](size_t i) { return Span{gr[i].lv0, gr[i].lv1}; });
+  return (i64)gr.size();
+}
+
+i64 dt_load_ops_tail(void* p, i64 from, i64 n, const i64* lv, const u8* kind,
+                     const u8* fwd, const i64* start, const i64* end,
+                     const i64* cp) {
+  Ctx* c = (Ctx*)p;
+  auto& runs = c->ops.runs;
+  size_t n_old = runs.size(), f = (size_t)from;
+  if (from < 0 || n < 0) return -1;
+  auto end_lv = [](const OpRun& r) { return r.lv + (r.end - r.start); };
+  i64 keep = keep_below(f, n_old, f < n_old ? runs[f].lv : 0,
+                        n_old ? end_lv(runs.back()) : 0, n, n ? lv[0] : 0,
+                        n ? lv[0] + (end[0] - start[0]) : 0);
+  if (keep < 0) return -1;
+  drop_derived(c);
+  runs.resize(f);
+  runs.reserve(f + (size_t)n);
+  for (i64 i = 0; i < n; i++)
+    runs.push_back({lv[i], kind[i], fwd[i], start[i], end[i], cp[i]});
+  refill_idx(c->ops.idx_of, f, runs.size(), keep,
+             [&](size_t i) { return Span{runs[i].lv, end_lv(runs[i])}; });
+  return (i64)runs.size();
+}
+
+i64 dt_load_ins_arena_tail(void* p, i64 from, i64 n, const int32_t* chars) {
+  Ctx* c = (Ctx*)p;
+  if (from < 0 || n < 0 || from > (i64)c->ins_arena.size()) return -1;
+  c->ins_arena.resize((size_t)from);
+  c->ins_arena.insert(c->ins_arena.end(), chars, chars + n);
+  return (i64)c->ins_arena.size();
+}
+
+// whole loads into a new ctx (bench_main.cpp)
 void dt_load_graph(void* p, i64 n, const i64* starts, const i64* ends,
                    const i64* shadows, const i64* pindptr, const i64* pflat) {
-  Ctx* c = (Ctx*)p;
-  c->g.starts.assign(starts, starts + n);
-  c->g.ends.assign(ends, ends + n);
-  c->g.shadows.assign(shadows, shadows + n);
-  c->g.pindptr.assign(pindptr, pindptr + n + 1);
-  c->g.pflat.assign(pflat, pflat + pindptr[n]);
-  c->g.build_idx();
+  dt_load_graph_tail(p, 0, n, starts, ends, shadows, pindptr, pflat);
 }
 
 void dt_load_agent_runs(void* p, i64 n, const i64* lv0, const i64* lv1,
                         const i64* agent, const i64* seq0) {
-  Ctx* c = (Ctx*)p;
-  c->aa.global_runs.clear();
-  for (i64 i = 0; i < n; i++) {
-    c->aa.global_runs.push_back({lv0[i], lv1[i], agent[i], seq0[i]});
-    c->aa.client_runs[agent[i]].push_back(
-        {seq0[i], seq0[i] + (lv1[i] - lv0[i]), lv0[i]});
-  }
-  for (auto& runs : c->aa.client_runs)
-    std::sort(runs.begin(), runs.end(),
-              [](const AgentRun& a, const AgentRun& b) {
-                return a.seq_start < b.seq_start;
-              });
-  c->aa.build_idx();
+  dt_load_agent_runs_tail(p, 0, n, lv0, lv1, agent, seq0);
 }
 
 void dt_load_ops(void* p, i64 n, const i64* lv, const u8* kind,
                  const u8* fwd, const i64* start, const i64* end,
                  const i64* cp) {
-  Ctx* c = (Ctx*)p;
-  c->ops.runs.clear();
-  c->ops.runs.reserve(n);
-  for (i64 i = 0; i < n; i++)
-    c->ops.runs.push_back({lv[i], kind[i], fwd[i], start[i], end[i], cp[i]});
-  c->ops.build_idx();
+  dt_load_ops_tail(p, 0, n, lv, kind, fwd, start, end, cp);
 }
 
 void dt_load_ins_arena(void* p, i64 n, const int32_t* chars) {
-  Ctx* c = (Ctx*)p;
-  c->ins_arena.assign(chars, chars + n);
+  dt_load_ins_arena_tail(p, 0, n, chars);
 }
 
 // transform: fills internal out buffer; returns count

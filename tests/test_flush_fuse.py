@@ -406,3 +406,200 @@ def test_cli_serve_bench_fused_flags_smoke(capsys):
     assert rc == 0
     assert "parity OK" in out
     assert "fused=off" in out
+
+
+# ---- the plan walk on the oplog's native mirror ----------------------------
+#
+# `plan_tail` transforms a session's tail on the oplog's `NativeContext`
+# (kept current by appending); the Python `TransformedOps` walk is its
+# oracle: the same session planned again under DT_TPU_NO_NATIVE=1 must
+# give the same plan, field for field.
+
+def _plans_equal(a: ff.TailPlan, b: ff.TailPlan) -> None:
+    for f in ("pos", "dlen", "ilen", "chars"):
+        x, y = getattr(a, f), getattr(b, f)
+        assert x.dtype == y.dtype and x.shape == y.shape, f
+        assert (x == y).all(), f
+    for f in ("n_ops", "new_len", "max_len", "frontier", "synced_to"):
+        assert getattr(a, f) == getattr(b, f), f
+        assert type(getattr(a, f)) is type(getattr(b, f)), f
+
+
+def _plan_both_ways(sess, monkeypatch) -> ff.TailPlan:
+    """The native plan, checked against the Python walk's; both are
+    pure reads of the session."""
+    before = (sess.frontier, sess.synced_to, sess.doc_len)
+    native = sess.plan_tail()
+    with monkeypatch.context() as m:
+        m.setenv("DT_TPU_NO_NATIVE", "1")
+        oracle = sess.plan_tail()
+    assert (sess.frontier, sess.synced_to, sess.doc_len) == before
+    _plans_equal(native, oracle)
+    return native
+
+
+def _replay_rows(model: list, plan: ff.TailPlan) -> None:
+    """What the device does with a plan, on a list of code points."""
+    for p, d, il, ch in zip(plan.pos.tolist(), plan.dlen.tolist(),
+                            plan.ilen.tolist(), plan.chars):
+        del model[p:p + d]
+        model[p:p] = ch[:il].tolist()
+
+
+def _commit(sess, plan, model: list) -> None:
+    """Adopt `plan` as a flush would, the device's part played by
+    `_replay_rows` (a plan walk reads the bookkeeping, never the row)."""
+    _replay_rows(model, plan)
+    sess.commit(sess.docs, sess.lens, plan)
+    assert sess.doc_len == len(model)
+
+
+class _Paper:
+    """The `b4-papers` shape at a tenth of the size: a typed document,
+    two `corpus.Typist` writers a region each, from their own heads
+    (they never see each other), the warm rounds' scatters first."""
+
+    def __init__(self, seed: int, n_ops: int = 6000) -> None:
+        import numpy as np
+        from bench import corpus
+        self.ol = ol = _mk_oplog("paper")
+        pos, nd, ni, chars = corpus.doc_columns(seed, 0, n_ops)
+        ol.apply_local_patch_columns(ol.get_or_create_agent_id("seed"),
+                                     pos, nd, ni, chars.decode())
+        self.rng = np.random.default_rng([seed, 99])
+        self.plain = corpus.PlainDoc(
+            "paper", corpus.doc_text(seed, 0, n_ops), 2,
+            [corpus.Typist(np.random.default_rng([seed, 1, w]), {})
+             for w in range(2)])
+        self.heads = [list(ol.version), list(ol.version)]
+
+    def push(self, w: int, ops) -> None:
+        """What the server's edit handler does with a push."""
+        ol = self.ol
+        agent = ol.get_or_create_agent_id(f"writer{w}")
+        f = self.heads[w]
+        for op in ops:
+            if op["kind"] == "ins":
+                f = [ol.add_insert_at(agent, f, op["pos"], op["text"])]
+            else:
+                f = [ol.add_delete_at(agent, f, op["start"], op["end"],
+                                      None)]
+        self.heads[w] = f
+        self.plain.acknowledge(w, ops, f)
+
+    def warm_round(self, n: int) -> None:
+        for w in (0, 1):
+            self.push(w, self.plain.scatter(self.rng, w, n))
+
+    def type(self, w: int, n: int = 8) -> None:
+        self.push(w, self.plain.next_push(w, n))
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3])
+def test_native_plan_equals_the_python_walk_papers_shape(seed, monkeypatch):
+    from diamond_types_tpu.obs.phases import PhaseTable
+    doc = _Paper(seed)
+    sess = ff.FusedDocSession(doc.ol, cap=1 << 14)
+    model = list(doc.plain.text())
+    assert sess.doc_len == len(model)
+    table = PhaseTable()
+    walks = 0
+    with table.phase("sched.flush"):
+        for n in (2, 16, 64):
+            doc.warm_round(n)
+            _commit(sess, _plan_both_ways(sess, monkeypatch), model)
+            walks += 1
+        for i in range(40):
+            doc.type(i % 2)
+            if i % 7 == 3:
+                continue                # two pushes in one tail
+            plan = _plan_both_ways(sess, monkeypatch)
+            walks += 1
+            if i % 5 == 4:
+                continue                # a plan dropped un-committed
+            _commit(sess, plan, model)
+        _commit(sess, _plan_both_ways(sess, monkeypatch), model)
+        walks += 1
+        # nothing pending: no walk, nothing counted
+        assert sess.plan_tail().n_ops == 0
+    assert bytes(model) == bytes(doc.plain.text())
+    counts = table.snapshot()["phases"]["plan.tail"]["counts"]
+    assert counts["xf_native"] == counts["xf_python"] == walks
+    assert counts["mirror_rebuilt"] == 0
+    assert 0 < counts["mirror_appended"] <= walks
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3])
+def test_native_plan_equals_the_python_walk_writers_who_merge(seed,
+                                                              monkeypatch):
+    """Writers who pull each other's heads, inserts longer than
+    `max_ins`, deletes of text the other writer already deleted."""
+    rng = random.Random(seed)
+    ol = _mk_oplog("merging")
+    _random_edits(ol, rng, 12, agent="seed")
+    sess = ff.FusedDocSession(ol, **FUSED_OPTS)
+    model = [ord(c) for c in ol.checkout_tip().snapshot()]
+    agents = [ol.get_or_create_agent_id(n) for n in ("a", "b", "c")]
+    heads = [list(ol.version) for _ in agents]
+    for i in range(60):
+        w = rng.randrange(3)
+        text = ol.checkout(heads[w]).snapshot()
+        for _ in range(rng.randint(1, 3)):
+            if text and rng.random() < 0.4:
+                p = rng.randrange(len(text))
+                e = min(p + rng.randint(1, 9), len(text))
+                heads[w] = [ol.add_delete_at(agents[w], heads[w], p, e,
+                                             None)]
+                text = text[:p] + text[e:]
+            else:
+                p = rng.randint(0, len(text))
+                s = "".join(rng.choice("abcdefgh")
+                            for _ in range(rng.randint(1, 11)))
+                heads[w] = [ol.add_insert_at(agents[w], heads[w], p, s)]
+                text = text[:p] + s + text[p:]
+        if rng.random() < 0.35:
+            o = rng.choice([x for x in range(3) if x != w])
+            heads[w] = list(ol.cg.graph.version_union(heads[w], heads[o]))
+        if i % 3 == 2:
+            plan = _plan_both_ways(sess, monkeypatch)
+            if rng.random() < 0.8:      # else dropped un-committed
+                _commit(sess, plan, model)
+    _commit(sess, _plan_both_ways(sess, monkeypatch), model)
+    assert "".join(map(chr, model)) == ol.checkout_tip().snapshot()
+
+
+def test_a_walk_costs_the_push_not_the_document(monkeypatch):
+    """Counts, since a CPU gives counts and not times: over 200
+    alternating pushes the native path constructs no Python tracker,
+    the mirror is built whole once (at the session's checkout) and
+    every walk hands the loaders a push's worth of entries."""
+    from diamond_types_tpu.listmerge import transform
+    from diamond_types_tpu.native.core import get_native_ctx
+    from diamond_types_tpu.obs.phases import PhaseTable
+
+    def no_tracker(*_a, **_k):
+        raise AssertionError("a Python Tracker on the native path")
+    monkeypatch.setattr(transform, "Tracker", no_tracker)
+    doc = _Paper(4)
+    sess = ff.FusedDocSession(doc.ol, cap=1 << 14)
+    ctx = get_native_ctx(doc.ol)
+    assert (ctx.appended, ctx.rebuilt) == (0, 1)
+    whole = ctx.last_sent
+    assert whole > len(doc.ol.ops.runs) > 500
+    table = PhaseTable()
+    with table.phase("sched.flush"):
+        for n in (2, 16):
+            doc.warm_round(n)
+            sess.commit(sess.docs, sess.lens, sess.plan_tail())
+        for i in range(200):
+            doc.type(i % 2)
+            plan = sess.plan_tail()
+            # 8 keystrokes: at most 8 op runs and 8 characters, a graph
+            # entry, an agent run, and the last entry held of each again
+            assert ctx.last_sent <= 8 + 8 + 1 + 1 + 3
+            sess.commit(sess.docs, sess.lens, plan)
+    assert sess.doc_len == len(doc.plain.text())
+    assert (ctx.appended, ctx.rebuilt) == (202, 1)
+    counts = table.snapshot()["phases"]["plan.tail"]["counts"]
+    assert counts == {"xf_native": 202, "mirror_appended": 202,
+                      "mirror_rebuilt": 0}

@@ -691,10 +691,16 @@ def test_scopes_change_names_only():
     assert flush_fuse.make_replay_body(mi).__name__ == "dt_fused_replay"
 
 
-def test_plan_tail_and_fused_replay_report_their_steps():
+@pytest.mark.parametrize("engine", ["native", "python"])
+def test_plan_tail_and_fused_replay_report_their_steps(engine, monkeypatch):
     """Under an open root the replay rungs' phases record; their
-    signatures (the harness patches them by name) are what they were."""
+    signatures (the harness patches them by name) are what they were.
+    The transform runs on the oplog's native mirror, or, with no native
+    engine, as the lazy Python walk."""
     import inspect
+
+    if engine == "python":
+        monkeypatch.setenv("DT_TPU_NO_NATIVE", "1")
 
     from diamond_types_tpu.text.oplog import OpLog
     from diamond_types_tpu.tpu import flush_fuse
@@ -715,14 +721,21 @@ def test_plan_tail_and_fused_replay_report_their_steps():
         ok, _dev = flush_fuse.fused_replay([sess], [plan])
     assert ok == [True] and sess.text() == "hello, dear world"
     ph = table.snapshot()["phases"]
-    # `plan.xf` closes twice a plan: the graph's diff, then the lazy
-    # walk's seconds, taken out of `plan.rows`
-    assert ph["plan.xf"]["count"] == 2
+    # the native transform is one call. The Python walk's `plan.xf`
+    # closes twice a plan: the graph's diff, then the lazy walk's
+    # seconds, taken out of `plan.rows`
+    assert ph["plan.xf"]["count"] == (1 if engine == "native" else 2)
     for name in ("plan.rows", "plan.pack", "replay.pack",
                  "replay.stack", "replay.dispatch", "replay.fence",
                  "replay.adopt"):
         assert ph[name]["count"] == 1, name
+    # which engine walked and, for the native one, how its mirror
+    # followed the oplog: the walk outside the root had synced it
+    assert ph.pop("plan.tail")["counts"] == (
+        {"xf_native": 1, "mirror_appended": 0, "mirror_rebuilt": 0}
+        if engine == "native" else {"xf_python": 1})
     assert all("counts" not in row for row in ph.values())
+    ph = table.snapshot()["phases"]
     for root, steps in (("plan.tail", ("plan.xf", "plan.rows", "plan.pack")),
                         ("replay", ("replay.pack", "replay.stack",
                                     "replay.dispatch", "replay.fence",
