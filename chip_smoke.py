@@ -8,8 +8,9 @@ calls — `serve()`, `SyncClient`, `POST /doc/{id}/push`,
 lengths of the upstream project's own benchmark corpora, with the merge
 scheduler's device engine flushing to the chip(s) this process owns:
 
-  kernels   every Pallas kernel the serve ladder can reach, compiled for
-            the device and compared with its XLA twin on the same inputs
+  kernels   the Pallas step kernel (parked outside the flush path),
+            compiled for the device and compared with the served XLA
+            replay on the same inputs
   load      each document is built in a client replica and pushed as a
             v1 patch; its session is materialised on the device
   rounds    every document takes an edit burst (paper-length documents
@@ -20,8 +21,9 @@ scheduler's device engine flushing to the chip(s) this process owns:
   restart   the server is closed and started again on the same
             --data-dir with mesh flush windows; every acknowledged edit
             must read back, and the rounds repeat through the mesh rung
-            — one length class per window first (one dispatch each),
-            then the mixed fleet (one dispatch per shape class)
+            — one length class per window first (one shape class
+            each), then the mixed fleet (a dispatch a shape class, one
+            more for every `mesh_window_rows` rows of a class beyond)
   kernels   again, at the largest batch shapes the traffic dispatched
 
 Every check is fatal: a non-zero exit with the reason on the last lines
@@ -64,11 +66,11 @@ ALPHABET = np.frombuffer(b"abcdefghijklmnopqrstuvwxyz    etaoin\n",
 FULL = dict(papers=32, notes=224, paper_ops=259_778,
             note_len=(4_200, 7_800), note_max=8_000, burst_ops=8,
             paste=(2_048, 4_096), pastes_per_round=4, rounds=3,
-            kernel_caps=(1 << 14, 1 << 18), xform_runs=(24, 600))
+            kernel_caps=(1 << 14, 1 << 18))
 TINY = dict(papers=2, notes=6, paper_ops=6_000,
             note_len=(150, 190), note_max=230, burst_ops=2,
             paste=(24, 40), pastes_per_round=2, rounds=2,
-            kernel_caps=(512, 8_192), xform_runs=(6, 40))
+            kernel_caps=(512, 8_192))
 
 DEPLOYMENT = {
     "name": "upstream-bench-lengths/mixed-fleet",
@@ -221,12 +223,16 @@ def require_mosaic(lowered_text: str, what: str) -> None:
 
 
 def check_apply_op_block(rng, cap: int, b: int, n: int) -> dict:
-    """`apply_op_block` through the ladder's own `_pallas_fn` against
-    the XLA replay (`_fused_fn`) on the same seeded ops, at one shape."""
+    """A scan over `apply_op_block` (`replay_ops_pallas`: the hand
+    kernel parked outside the flush path, ROADMAP D4) against the
+    served XLA replay (`_fused_fn`) on the same seeded ops, at one
+    shape."""
+    import jax
     import jax.numpy as jnp
 
     from diamond_types_tpu.tpu import flush_fuse as ff
-    from diamond_types_tpu.tpu.pallas_kernels import _TILE
+    from diamond_types_tpu.tpu.pallas_kernels import (_TILE,
+                                                      replay_ops_pallas)
 
     lens = rng.integers(cap // 4, cap // 2, size=b).astype(np.int32)
     docs = np.zeros((b, cap), np.int32)
@@ -250,7 +256,7 @@ def check_apply_op_block(rng, cap: int, b: int, n: int) -> dict:
             for a in (pos, dlen, ilen, chars)]
     got = {}
     for name, fn in (("xla", ff._fused_fn(b, n, MAX_INS, cap)),
-                     ("pallas", ff._pallas_fn(b, n, MAX_INS, cap))):
+                     ("pallas", jax.jit(replay_ops_pallas))):
         state = (jnp.asarray(docs), jnp.asarray(lens))
         if name == "pallas":
             require_mosaic(fn.lower(*state, *args).as_text(),
@@ -269,72 +275,23 @@ def check_apply_op_block(rng, cap: int, b: int, n: int) -> dict:
 
 
 def check_kernels(cfg, rng) -> dict:
-    """(g) Each Pallas kernel on the serve ladder against its XLA twin,
-    on the same inputs, at both capacity classes. On the TPU the Pallas
+    """(g) The Pallas step kernel against the served XLA replay, on the
+    same inputs, at both capacity classes. On the TPU the Pallas
     side is Mosaic-compiled — asserted on the lowered program itself,
     never interpreted; a kernel the compiler refuses raises here with
     the compiler's words."""
-    import jax
-    import jax.numpy as jnp
-
-    from diamond_types_tpu.tpu import flush_fuse as ff
     from diamond_types_tpu.tpu.runtime import pallas_interpret
 
-    out = {"interpreted": pallas_interpret(),
-           "apply_op_block": [check_apply_op_block(rng, cap, 8, 16)
-                              for cap in cfg["kernel_caps"]],
-           "xform_positions": []}
-
-    # xform_positions_pallas through the transform's own entry point
-    from diamond_types_tpu.text.oplog import OpLog
-    from diamond_types_tpu.tpu.xform import (TailExtract, _xform_fn,
-                                             extract_tail,
-                                             resolve_positions,
-                                             xform_shape_class)
-    for runs in cfg["xform_runs"]:
-        ol = OpLog()
-        a0 = ol.get_or_create_agent_id("base")
-        ol.add_insert(a0, 0, "the quick brown fox jumps over the dog " * 8)
-        sess = ff.FusedDocSession(ol)
-        agents = [ol.get_or_create_agent_id(f"w{i}") for i in (0, 1)]
-        heads = [list(ol.version), list(ol.version)]
-        lens = [sess.doc_len, sess.doc_len]
-        for r in range(runs):
-            w = r % 2
-            p = int(rng.integers(0, lens[w] + 1))
-            text = ALPHABET[rng.integers(0, 26, size=3)].tobytes().decode()
-            heads[w] = [ol.add_insert_at(agents[w], heads[w], p, text)]
-            lens[w] += 3
-        ex = extract_tail(sess)
-        require(isinstance(ex, TailExtract),
-                "the transform's extractor refused a two-writer tail")
-        col = jax.ShapeDtypeStruct(xform_shape_class([ex]), jnp.int32)
-        require_mosaic(
-            _xform_fn(*col.shape, True).lower(*[col] * 7).as_text(),
-            f"xform_positions_pallas ({ex.n} runs)")
-        plans = {p: resolve_positions([ex], pallas=p)[0]
-                 for p in (False, True)}
-        require(all(p is not None for p in plans.values()),
-                f"device transform failed its cross-check ({ex.n} runs)")
-        same = all(np.array_equal(getattr(plans[False], f),
-                                  getattr(plans[True], f))
-                   for f in ("pos", "dlen", "ilen", "chars")) \
-            and plans[False].new_len == plans[True].new_len \
-            and plans[False].max_len == plans[True].max_len
-        out["xform_positions"].append({"runs": ex.n, "plans_equal": same})
-        require(same, f"xform_positions_pallas != XLA scans ({ex.n} runs)")
-        host = sess.plan_tail()
-        require(host.new_len == plans[True].new_len,
-                "device transform length != host tracker walk")
-    return out
+    return {"interpreted": pallas_interpret(),
+            "apply_op_block": [check_apply_op_block(rng, cap, 8, 16)
+                               for cap in cfg["kernel_caps"]]}
 
 
 def check_kernels_at_traffic_shapes(rng) -> list:
     """(g), second half: the step kernel again at the largest `(b, n)`
     the server warmed or the fleet's own traffic dispatched in each
     capacity class (read off the steer table the jit lookups feed) —
-    the pastes run `n` into the hundreds, and a one-device Pallas
-    window holds a whole window's rows behind one SMEM table."""
+    the pastes run `n` into the hundreds."""
     from diamond_types_tpu.tpu.steer import STEER
     seen = {}
     for cache in ("fused", "mesh"):
@@ -517,28 +474,34 @@ def check_phase_end(name: str, fleet, sched, m: dict, mesh: bool,
 
 
 def check_round_windows(name: str, r: int, cfg, m0: dict, m1: dict,
-                        mixed: bool, row: dict) -> None:
-    """(f) the mesh rung's dispatch count, per round: a uniform-shape
-    window takes exactly one program; a window that holds both length
-    classes takes one per shape class, under the same device locks."""
+                        mixed: bool, max_rows: int, row: dict) -> None:
+    """(f) the mesh rung's dispatch count, per round: a window takes one
+    program a shape class it holds, and one more only for every
+    `mesh_window_rows` (`max_rows`) rows of a class beyond the first
+    (several buckets of a shard due at once: `_flush_window`). A
+    uniform-shape window holds exactly one class; a mixed wave must
+    produce windows that hold both."""
     d = {k: m1["window"][k] - m0["window"][k]
-         for k in ("device_windows", "dispatches", "shape_classes")}
+         for k in ("device_windows", "dispatches", "shape_classes",
+                   "mesh_docs")}
     row["windows"] = d
     require(d["device_windows"] > 0,
             f"{name} round {r}: no mesh window did device work")
-    require(d["dispatches"] == d["shape_classes"],
+    require(d["shape_classes"] <= d["dispatches"]
+            <= d["shape_classes"] + d["mesh_docs"] // max_rows,
             f"{name} round {r}: {d['dispatches']} dispatches for "
-            f"{d['shape_classes']} shape classes")
+            f"{d['shape_classes']} shape classes and {d['mesh_docs']} "
+            f"rows at {max_rows} rows a dispatch")
     if mixed:
         # (the CPU pre-flight's 16 documents can fall one bucket to a
         # window; at full size a mixed wave cannot avoid mixed windows)
-        require(d["dispatches"] > d["device_windows"] or cfg is TINY,
+        require(d["shape_classes"] > d["device_windows"] or cfg is TINY,
                 f"{name} round {r}: the mixed wave produced no window "
                 f"holding both length classes ({d})")
     else:
-        require(d["dispatches"] == d["device_windows"],
-                f"{name} round {r}: {d['dispatches']} dispatches in "
-                f"{d['device_windows']} uniform-shape windows, want "
+        require(d["shape_classes"] == d["device_windows"],
+                f"{name} round {r}: {d['shape_classes']} shape classes "
+                f"in {d['device_windows']} uniform-shape windows, want "
                 "exactly 1 each")
 
 
@@ -667,7 +630,8 @@ def run_phase(name: str, cfg, fleet, data_dir: str, device: dict,
                 "three-way equality holds")
             check_counters(m1, store.obs.recorder, f"{name} round {r}")
             if mesh and r > 0:
-                check_round_windows(name, r, cfg, m0, m1, mixed, row)
+                check_round_windows(name, r, cfg, m0, m1, mixed,
+                                    sched.mesh_window_rows, row)
             require(row["reads_from_device"] == len(fleet),
                     f"{name} round {r}: {row['reads_from_device']} of "
                     f"{len(fleet)} reads came from the device")
@@ -754,9 +718,9 @@ def main() -> int:
     report["kernels"] = check_kernels(cfg, rng)
     report["kernels"]["compile"] = COMPILE_STATS.delta(
         COMPILE_STATS.snapshot(), c0)
-    say("kernels: apply_op_block and xform_positions_pallas "
+    say("kernels: apply_op_block "
         + ("(INTERPRETED: cpu)" if report["kernels"]["interpreted"]
-           else "Mosaic-compiled") + " match their XLA twins at "
+           else "Mosaic-compiled") + " matches the XLA replay at "
         f"caps {list(cfg['kernel_caps'])}")
 
     data_dir = args.data_dir or tempfile.mkdtemp(prefix="dt-chip-smoke-")

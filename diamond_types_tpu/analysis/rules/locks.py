@@ -141,13 +141,6 @@ def _classify(expr: ast.AST, class_name: str) -> Optional[str]:
     # racing pair builds twice rather than ever nesting io inside oplog
     if "_frame_cache_lock" in src:
         return "io"
-    # device-transform planning: the xform jit-cache guard is a
-    # DEVICE-class lock (the batched transform dispatch runs in the
-    # planning phase, under shard locks but outside the oplog guard and
-    # the per-device replay locks) — must classify BEFORE the generic
-    # "_jit_lock" leaf rule below
-    if "_xform_jit_lock" in src:
-        return "device"
     # window-arena staging: the donated-buffer recycle table guard is
     # a DEVICE-class lock (acquire/adopt bracket the mesh dispatch but
     # run under the scheduler's per-class replay, outside the oplog

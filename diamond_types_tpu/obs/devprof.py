@@ -110,7 +110,7 @@ class DeviceProfiler:
     def note_transfer(self, nbytes: int, rung: str = "",
                       purpose: str = "") -> None:
         """Count one host->device transfer. `rung` names the ladder
-        rung that paid it (session/fused/mesh/pallas), `purpose` what
+        rung that paid it (session/fused/mesh), `purpose` what
         moved: "stage" (resident doc state), "plan" (the window's op
         arrays — always host-built), or "warmup" (ahead-of-time
         compiles). Untagged calls keep the legacy totals working."""

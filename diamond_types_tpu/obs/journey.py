@@ -10,7 +10,7 @@ sampled edit as it crosses the pipeline stages:
   queued          admission queue took the merge intent
   planned         flush planning produced an op schedule (host or
                   device rung — the rung shows on the trace spans)
-  device_replayed the fused/mesh/pallas device phase replayed the tail
+  device_replayed the fused/mesh device phase replayed the tail
                   (host-engine flushes skip this stamp by design)
   adopted         the merge result was adopted into the session/oplog
   wal_durable     DocStore persisted the doc (atomic tmp+rename)

@@ -406,11 +406,11 @@ def _render_obs(b: _Builder, obs: dict) -> None:
         if k in rec:
             b.add(f"dt_recorder_events_{k}_total", "counter", rec[k])
     dp = obs.get("devprof") or {}
-    # zero-fill the known jit families (the HYDRATION_KEYS idiom): the
-    # "xform"/"pallas" rows exist from the first scrape, not only after
-    # the first transform/Pallas dispatch seeds the cache
-    jit: dict = {k: {} for k in ("fused", "mesh", "micro", "tip",
-                                 "xform", "pallas")} if dp else {}
+    # zero-fill the known jit families (the HYDRATION_KEYS idiom): a
+    # row exists from the first scrape, not only after the first
+    # dispatch seeds the cache
+    jit: dict = {k: {} for k in ("fused", "mesh", "micro", "tip")} \
+        if dp else {}
     jit.update(dp.get("jit_cache") or {})
     for cache, hm in sorted(jit.items()):
         lb = {"cache": cache}

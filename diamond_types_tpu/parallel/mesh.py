@@ -239,15 +239,7 @@ def mesh_fused_replay(mesh: Mesh, sessions, plans):
     the bank's fallback ladder catches violating rows exactly as
     before — and a violating doc in one shard cannot corrupt another
     shard's rows. Padding rows enter with the `lens = -1` sentinel and
-    zero ops on EVERY staging path, so they stay identifiably inert.
-
-    Device-planned tails (serve banks built with `device_plan=True`)
-    need no special handling here: by the time a row reaches this rung
-    its transform has already resolved into a plain doc-order
-    `TailPlan` (tpu/xform.py resolve_positions), indistinguishable
-    from a host tracker-walk plan — the mesh rung consumes either
-    unchanged, and a transform fallback upstream simply arrives as a
-    host plan."""
+    zero ops on EVERY staging path, so they stay identifiably inert."""
     with phase("mesh.replay") as ph:
         return _mesh_fused_replay(mesh, sessions, plans, ph)
 

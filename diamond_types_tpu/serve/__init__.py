@@ -11,7 +11,7 @@ shape-bucketed, per-shard batches:
   * `admission`  — shape-bucketed pending-merge queues with a
                    size-or-deadline flush trigger and bounded depth +
                    backpressure (JIT dynamic batching, arxiv 1904.07421)
-  * `bank`       — per-shard DeviceZoneSession bank with LRU eviction
+  * `bank`       — per-shard FusedDocSession bank with LRU eviction
                    and device-slot capacity accounting
   * `metrics`    — JSON-exportable counters for serve-bench / soak tools
   * `scheduler`  — the composition: DocStore-facing submit/pump/drain

@@ -1,7 +1,8 @@
 """Per-shard session bank: LRU-bounded device residency + host fallback.
 
-One bank per shard owns every device-resident `DeviceZoneSession` placed
-on that shard's chip. Residency is bounded two ways, mirroring the
+One bank per shard owns every device-resident `FusedDocSession`
+(`tpu.flush_fuse`) placed on that shard's chip. Residency is bounded
+two ways, mirroring the
 eviction/resync machinery the multichip dryrun proved out
 (`__graft_entry__._dryrun_session_sharded`):
 
@@ -28,19 +29,22 @@ was asked for and cannot run is an error, not a slower run.
 scheduler then still provides routing/batching/metrics, and no JAX
 backend is touched.
 
-Fused flush (`fused=True`): sessions are `tpu.flush_fuse`
-FusedDocSessions and `sync_docs` replays a whole taken bucket in ONE
-jitted vmapped device call. The fallback ladder, most-fused first:
+The flush path of a device bank is three steps, one way:
 
-  1. fused group   — ≥2 resident fused sessions sharing (cap, max_ins)
-                     whose tails fit: one `fused_replay` call.
-  2. per-doc       — host engine, mixed residency (a non-fused session
-                     already resident), capacity eviction mid-batch,
-                     a tail that overflows its buffer, or a bucket
-                     with <2 fusable docs: `sync_doc` per item.
-  3. host fallback — a poisoned/mismatched fused length: evict the
-                     session and serve the doc from
-                     `oplog.checkout_tip()` (always correct).
+  1. plan   — `_plan_fused`: build or find each doc's session, pack its
+              pending tail (`FusedDocSession.plan_tail`, the native
+              mirror's transform) and group the sessions whose tails
+              fit by (cap, max_ins).
+  2. replay — one `flush_fuse.fused_replay` call a group on the shard's
+              own chip (`sync_docs`); where the scheduler runs mesh
+              windows it replays the groups of every shard itself with
+              `parallel.mesh.mesh_fused_replay` (`plan_window` and
+              `adopt_window` are the two halves it calls).
+  3. adopt  — `adopt_window`: a poisoned or mismatched length evicts
+              the session to the host oracle; what could not be grouped
+              (capacity eviction mid-batch, a tail that overflows its
+              buffer, a bucket with fewer than two docs to group) goes
+              through `sync_doc` one doc at a time.
 
 Locking contract for `sync_docs`: `oplog_lock` (the scheduler's
 narrowed sync lock — e.g. DocStore.lock) is held only around the
@@ -56,6 +60,7 @@ the process really has the device it was asked to use.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from collections import OrderedDict
@@ -94,14 +99,10 @@ class SessionBank:
     def __init__(self, shard_id: int, max_sessions: int = 8,
                  max_slots: int = 1 << 24, engine: str = "device",
                  device=None, metrics: Optional[ServeMetrics] = None,
-                 session_opts: Optional[dict] = None,
-                 fused: bool = False,
                  fused_opts: Optional[dict] = None,
                  warmup: bool = False,
                  flush_docs: int = 8,
-                 mesh_shards: int = 0,
-                 device_plan: bool = False,
-                 pallas: bool = False) -> None:
+                 mesh_shards: int = 0) -> None:
         if engine not in ("device", "host"):
             raise ValueError(f"unknown engine {engine!r}")
         self.shard_id = shard_id
@@ -110,11 +111,8 @@ class SessionBank:
         self.engine = engine
         self.device = device
         self.metrics = metrics
-        self.session_opts = dict(session_opts or {})
-        # fused=True builds tpu.flush_fuse.FusedDocSessions so
-        # sync_docs can replay whole buckets in one device call;
-        # fused_opts (cap / max_ins / headroom) go to that ctor
-        self.fused = bool(fused) and engine == "device"
+        # cap / max_ins / headroom of the FusedDocSessions a device
+        # bank builds
         self.fused_opts = dict(fused_opts or {})
         self.flush_docs = int(flush_docs)
         # >0: the scheduler runs mesh flush windows over this many
@@ -122,16 +120,6 @@ class SessionBank:
         # shape classes (B padded to the mesh) so the first window
         # doesn't eat a cold compile
         self.mesh_shards = int(mesh_shards)
-        # device_plan routes tail PLANNING through the device transform
-        # (tpu/xform.py plan_tails_device) instead of the host tracker
-        # walk; pallas routes the fused REPLAY through the Pallas step
-        # kernel rung (flush_fuse.pallas_fused_replay) in place of the
-        # XLA fused rung, and the device transform's position scans
-        # through xform_positions_pallas — the one selector for every
-        # Pallas kernel on the ladder. Both only apply on the fused
-        # device engine.
-        self.device_plan = bool(device_plan) and self.fused
-        self.pallas = bool(pallas) and self.fused
         self.sessions: "OrderedDict[str, object]" = OrderedDict()
         self._resyncs_seen: Dict[str, int] = {}
         # obs.recorder.FlightRecorder (MergeScheduler.attach_obs);
@@ -148,7 +136,7 @@ class SessionBank:
         self.snapshot_hook = None
         self._warmup_thread: Optional[threading.Thread] = None
         self.warmup_error: Optional[BaseException] = None
-        if warmup and self.fused:
+        if warmup and engine == "device":
             self._warmup_thread = threading.Thread(
                 target=self._warmup, daemon=True)
             self._warmup_thread.start()
@@ -163,16 +151,12 @@ class SessionBank:
         try:
             first_touch()
             from ..tpu.flush_fuse import (DEFAULT_CAP, DEFAULT_MAX_INS,
-                                          WARMUP_SHAPE_CLASSES,
                                           warmup_fused_cache)
             warmup_fused_cache(
                 flush_docs=self.flush_docs,
                 cap=self.fused_opts.get("cap", DEFAULT_CAP),
                 max_ins=self.fused_opts.get("max_ins", DEFAULT_MAX_INS),
-                mesh_shards=self.mesh_shards,
-                xform_classes=(WARMUP_SHAPE_CLASSES if self.device_plan
-                               else ()),
-                pallas=self.pallas)
+                mesh_shards=self.mesh_shards)
         except Exception as e:
             self.warmup_error = e
             self._bump("warmup_errors")
@@ -211,7 +195,7 @@ class SessionBank:
     def device_error(self, rung: str, exc: BaseException,
                      **fields) -> None:
         """Count and record an exception out of a device rung (`rung`:
-        pallas / mesh / fused / per_doc / build). The caller re-raises:
+        mesh / fused / per_doc / build). The caller re-raises:
         a compiler or runtime failure is never answered by a quieter
         rung. The exception is tagged with this shard so a loop that
         survives it (`scheduler._loop_error`) files it where it
@@ -277,24 +261,24 @@ class SessionBank:
 
     # ---- residency -------------------------------------------------------
 
+    def _on_device(self):
+        """`jax.default_device` of this bank's chip: what a session
+        builds and a replay stacks lands there. Nothing for a host
+        bank (no JAX touched) or an unplaced one."""
+        if self.device is None or self.engine == "host":
+            return contextlib.nullcontext()
+        import jax
+        return jax.default_device(self.device)
+
     def _build(self, doc_id: str, oplog):
         if self.engine == "host":
             return _HostDoc(oplog)
         first_touch()
-        if self.fused:
-            from ..tpu.flush_fuse import FusedDocSession as cls
-            opts = self.fused_opts
-        else:
-            from ..tpu.zone_session import DeviceZoneSession as cls
-            opts = self.session_opts
-        if self.device is not None:
-            import jax
-            with jax.default_device(self.device):
-                sess = cls(oplog, **opts)
-        else:
-            sess = cls(oplog, **opts)
+        from ..tpu.flush_fuse import FusedDocSession
+        with self._on_device():
+            sess = FusedDocSession(oplog, **self.fused_opts)
         # the initial build counts as this doc's baseline, not a resync
-        self._resyncs_seen[doc_id] = getattr(sess, "resyncs", 0)
+        self._resyncs_seen[doc_id] = sess.resyncs
         return sess
 
     def session(self, doc_id: str, oplog):
@@ -342,11 +326,7 @@ class SessionBank:
         t0 = time.perf_counter()
         sess = self.session(doc_id, oplog)
         try:
-            if self.device is not None and self.engine == "device":
-                import jax
-                with jax.default_device(self.device):
-                    steps = sess.sync()
-            else:
+            with self._on_device():
                 steps = sess.sync()
             # wall vs device attribution: the sync above returns once
             # dispatch is queued; block_until_ready isolates the device
@@ -354,14 +334,10 @@ class SessionBank:
             # sync point perturbs the async dispatch pipeline.
             device_s = 0.0
             if self.engine == "device" and PROFILER.enabled:
-                carry = getattr(sess, "carry", None)
-                if carry is None:   # fused sessions fence on lens
-                    carry = getattr(sess, "lens", None)
-                if carry is not None:
-                    import jax
-                    td = time.perf_counter()
-                    jax.block_until_ready(carry)
-                    device_s = time.perf_counter() - td
+                import jax
+                td = time.perf_counter()
+                jax.block_until_ready(sess.lens)
+                device_s = time.perf_counter() - td
         except FenceFailure as e:
             self.evict(doc_id)
             self._bump("host_fallbacks")
@@ -400,7 +376,6 @@ class SessionBank:
 
         Returns {"items", "ols", "serial", "groups"} where `groups` is
         [(sessions, plans, doc_ids)] keyed by (cap, max_ins) class."""
-        import contextlib
         olock = oplog_lock if oplog_lock is not None \
             else contextlib.nullcontext()
         # resolve first, outside every lock (non-reentrant store lock)
@@ -408,7 +383,7 @@ class SessionBank:
             ols = {it.doc_id: resolve(it.doc_id) for it in items}
         serial = list(items)
         groups: List[tuple] = []     # (sessions, plans, doc_ids)
-        if self.fused and self.engine == "device":
+        if self.engine == "device":
             # session builds and tail plans, under the oplog guard
             with phase("bank.plan"):
                 serial, groups = self._plan_fused(items, ols, olock,
@@ -441,7 +416,6 @@ class SessionBank:
         everything that couldn't fuse. Shared tail of `sync_docs` and
         the mesh window path, so the fallback ladder is one code path
         regardless of which program replayed the batch."""
-        import contextlib
         olock = oplog_lock if oplog_lock is not None \
             else contextlib.nullcontext()
         dlock = device_lock if device_lock is not None \
@@ -485,8 +459,8 @@ class SessionBank:
 
     def sync_docs(self, items, resolve,
                   oplog_lock=None, device_lock=None) -> dict:
-        """Flush one taken bucket, fusing where possible (module
-        docstring: the fallback ladder). `items` are admission
+        """Flush one taken bucket: plan, replay, adopt (module
+        docstring). `items` are admission
         PendingMerge rows; `resolve(doc_id) -> OpLog` is called OUTSIDE
         `oplog_lock` (DocStore.get takes that same non-reentrant lock).
 
@@ -496,7 +470,6 @@ class SessionBank:
 
         Returns {"docs", "fused_calls", "fused_docs", "fallback_docs"}.
         """
-        import contextlib
         dlock = device_lock if device_lock is not None \
             else contextlib.nullcontext()
         win = self.plan_window(items, resolve, oplog_lock=oplog_lock)
@@ -505,19 +478,19 @@ class SessionBank:
         # device lock ONLY — host threads keep mutating other oplogs
         failed: List[str] = []
         for sessions, plans, doc_ids in win["groups"]:
-            from ..tpu.flush_fuse import fused_replay, pallas_fused_replay
+            # looked up when the group is replayed, not when the module
+            # is imported: bench/instrument.py puts its clock in
+            # `flush_fuse.fused_replay`'s place after the server started
+            from ..tpu.flush_fuse import fused_replay
             t0 = time.perf_counter()
-            with dlock:
-                if self.device is not None:
-                    import jax
-                    with jax.default_device(self.device):
-                        ok, device_s = self._replay_group(
-                            sessions, plans, fused_replay,
-                            pallas_fused_replay)
-                else:
-                    ok, device_s = self._replay_group(
-                        sessions, plans, fused_replay,
-                        pallas_fused_replay)
+            try:
+                with dlock, self._on_device():
+                    ok, device_s = fused_replay(sessions, plans)
+            except Exception as e:
+                # counted, recorded and raised: a replay that cannot
+                # run is never answered by a quieter path
+                self.device_error("fused", e, docs=len(sessions))
+                raise
             wall = time.perf_counter() - t0
             n = len(sessions)
             fused_calls += 1
@@ -540,84 +513,27 @@ class SessionBank:
         out["fused_docs"] = fused_docs
         return out
 
-    def _replay_group(self, sessions, plans, fused_replay,
-                      pallas_fused_replay):
-        """One fused group through its device rung: the Pallas step
-        kernel when the bank was built with it, else the XLA fused
-        kernel. Commit/fence semantics are identical. An exception is
-        counted, recorded and raised — never answered by the other
-        kernel."""
-        rung, replay = ("pallas", pallas_fused_replay) if self.pallas \
-            else ("fused", fused_replay)
-        try:
-            return replay(sessions, plans)
-        except Exception as e:
-            self.device_error(rung, e, docs=len(sessions))
-            raise
-
     def _plan_fused(self, items, ols, olock, min_fuse: int = 2):
-        """Host-side phase of the fused flush: get/build each doc's
-        session, plan its tail, and group fusable sessions by
-        (cap, max_ins). Anything that can't fuse — non-fused residency,
-        overflowing tail, LRU-evicted mid-batch, a bucket with fewer
-        than `min_fuse` fusable docs — lands in the serial list.
-
-        With `device_plan` the planning itself is split the same way
-        the replay is: tail EXTRACTION (native transform + columns)
-        under `olock`, the batched device order/position resolution
-        OUTSIDE it (extracts are self-contained), then adoption and
-        per-doc host re-planning for cross-check failures back under
-        `olock` — the transform ladder's own host rung."""
-        from ..tpu.flush_fuse import FusedDocSession
+        """Host-side phase of the flush, one pass under one hold of
+        `olock`: get/build each doc's session, plan its tail, and group
+        the sessions to replay by (cap, max_ins). Anything that can't
+        be grouped — overflowing tail, LRU-evicted mid-batch, a bucket
+        with fewer than `min_fuse` docs to replay — lands in the serial
+        list."""
         serial = []
         fusable: List[tuple] = []    # (sess, plan, doc_id)
-        planned = []                 # (it, sess, TailPlan | TailExtract)
         with olock:
+            planned = []
             for it in items:
                 # a build failure is counted in session() and raises
                 sess = self.session(it.doc_id, ols[it.doc_id])
-                if not isinstance(sess, FusedDocSession):
-                    serial.append(it)
-                    continue
-                if self.device_plan:
-                    from ..tpu.xform import extract_tail
-                    half = extract_tail(sess)   # TailExtract | TailPlan
-                else:
-                    half = sess.plan_tail()
-                planned.append((it, sess, half))
-        if self.device_plan:
-            # device half OUTSIDE the oplog guard: one batched dispatch
-            # resolves every extract's order + positions
-            from ..tpu.xform import TailExtract, resolve_positions
-            ext = [(j, h) for j, (_it, _s, h) in enumerate(planned)
-                   if isinstance(h, TailExtract)]
-            stats = {"device_docs": 0,
-                     "host_docs": len(planned) - len(ext),
-                     "fallbacks": 0, "batches": 1 if ext else 0}
-            if ext:
-                resolved = resolve_positions([h for _, h in ext],
-                                             pallas=self.pallas)
-                for (j, _), plan in zip(ext, resolved):
-                    it, sess, _ = planned[j]
-                    if plan is None:
-                        stats["fallbacks"] += 1
-                    else:
-                        stats["device_docs"] += 1
-                    planned[j] = (it, sess, plan)
-            if self.metrics is not None and (ext or stats["host_docs"]):
-                self.metrics.record_transform(self.shard_id, **stats)
-        with olock:
+                planned.append((it, sess, sess.plan_tail()))
             for it, sess, plan in planned:
-                if plan is None:
-                    # device cross-check failed: host re-plan (the
-                    # per-doc host rung of the transform ladder)
-                    plan = sess.plan_tail()
                 if not plan.fits(sess.cap):
                     serial.append(it)   # overflow -> per-doc resync
-                    continue
                 # building session N can LRU-evict already-planned M:
                 # only still-resident sessions may commit device state
-                if self.sessions.get(it.doc_id) is not sess:
+                elif self.sessions.get(it.doc_id) is not sess:
                     serial.append(it)
                 elif plan.n_ops == 0:
                     # frontier advance with no visible ops (e.g. a
@@ -657,7 +573,6 @@ class SessionBank:
         never issues device work while holding the oplog guard — a
         stale session serves the durable tip and the flush pipeline
         catches it up off the read path."""
-        import contextlib
         olock = oplog_lock if oplog_lock is not None \
             else contextlib.nullcontext()
         dlock = device_lock if device_lock is not None \

@@ -17,8 +17,8 @@ dryrun-only. Two workload shapes:
                  shapes again — while positions derive from a per-doc
                  rng, so content and merge order genuinely differ.
 
-Parity: for engine="device" the scheduler's answer comes from the zone
-kernel's device state (DeviceZoneSession.text()) while the single-engine
+Parity: for engine="device" the scheduler's answer comes from the replay
+kernel's device state (FusedDocSession.text()) while the single-engine
 result is the host tracker checkout — two independent engines, compared
 byte-for-byte per document. Runs on CPU (JAX_PLATFORMS=cpu + virtual
 devices); a real mesh only changes placement, not the code path.
@@ -156,16 +156,13 @@ def run_serve_bench(shards: int = 4, docs: int = 8,
                     flush_docs: int = 4, flush_deadline_s: float = 0.02,
                     max_pending: int = 64, max_sessions: int = 4,
                     seed: int = 7, place_on_devices: bool = True,
-                    session_opts: Optional[dict] = None,
                     obs_sample_rate: float = 0.01,
-                    fused: bool = True, flush_workers: bool = True,
+                    flush_workers: bool = True,
                     warmup: bool = False,
                     steady_rounds: int = 0,
                     mesh_window: bool = False,
                     telemetry: bool = True,
                     journey: bool = True,
-                    device_plan: bool = False,
-                    pallas: bool = False,
                     steer: bool = True,
                     device_stage: bool = True) -> dict:
     """Replay the workload through a fresh scheduler; returns a JSON-able
@@ -177,11 +174,7 @@ def run_serve_bench(shards: int = 4, docs: int = 8,
     flushes through the scheduler's mesh flush-window coordinator (one
     shard_map dispatch per window instead of one device call per
     shard) — the report's `device_calls_per_window` is the direct
-    A/B signal against the per-shard default. `device_plan=True` plans
-    flush tails through the device transform (tpu/xform.py) instead of
-    the host tracker walk — the report's `transform` block counts how
-    many tails actually resolved on device — and `pallas=True` adds the
-    Pallas replay rung at the top of the flush ladder.
+    A/B signal against the per-shard default.
 
     `steer=False` / `device_stage=False` are the PR-20 A/B control
     arms: no batch-shape steering (every window dispatches its raw
@@ -240,11 +233,10 @@ def run_serve_bench(shards: int = 4, docs: int = 8,
         max_sessions_per_shard=max_sessions,
         max_pending=max_pending, flush_docs=flush_docs,
         flush_deadline_s=flush_deadline_s,
-        place_on_devices=place_on_devices, session_opts=session_opts,
-        sync_lock=oplog_lock, fused=fused,
+        place_on_devices=place_on_devices,
+        sync_lock=oplog_lock,
         flush_workers=flush_workers, warmup=warmup,
-        mesh_window=mesh_window, device_plan=device_plan,
-        pallas=pallas)
+        mesh_window=mesh_window)
     obs = Observability(sample_rate=obs_sample_rate, seed=seed,
                         telemetry=telemetry, journey=journey)
     sched.attach_obs(obs)
@@ -335,11 +327,11 @@ def run_serve_bench(shards: int = 4, docs: int = 8,
     obs.slo.evaluate()
     slo_verdict = obs.slo.verdict()
 
-    # jit hit rates over the REPLAY caches (fused/mesh/pallas — the
+    # jit hit rates over the REPLAY caches (fused/mesh — the
     # classes steering snaps); steady rate from the post-burst deltas,
     # the ">= 90% steady-state hits" number ISSUE 20 gates on
     devprof = PROFILER.snapshot()
-    _replay = ("fused", "mesh", "pallas")
+    _replay = ("fused", "mesh")
 
     def _rate(now, base):
         hits = lookups = 0
@@ -363,12 +355,9 @@ def run_serve_bench(shards: int = 4, docs: int = 8,
                    "flush_deadline_s": flush_deadline_s,
                    "max_pending": max_pending,
                    "max_sessions": max_sessions, "seed": seed,
-                   "fused": sched.fused,
                    "flush_workers": flush_workers, "warmup": warmup,
                    "steady_rounds": steady_rounds,
                    "mesh_window": sched.mesh_window,
-                   "device_plan": sched.device_plan,
-                   "pallas": sched.pallas,
                    "steer": steer, "device_stage": device_stage,
                    "telemetry": telemetry, "journey": journey},
         "total_ops": total_ops,
@@ -395,9 +384,6 @@ def run_serve_bench(shards: int = 4, docs: int = 8,
         "steady_jit_lookups": steady_lookups,
         "staged_bytes_per_window": staged_per_window,
         "steer": STEER.snapshot(),
-        # the transform rung's engagement: tails whose merge positions
-        # resolved on device vs. the host tracker walk
-        "transform": m["transform"],
         "metrics": m,
         "devprof": devprof,
         "obs": {"trace": obs.tracer.stats(),

@@ -7,7 +7,7 @@ metrics path can run inside flush loops without perturbing timings.
 
 Schema (snapshot()):
 
-  {"version": 14,                  # counter-set schema; bump on change
+  {"version": 15,                  # counter-set schema; bump on change
    "uptime_s": s,                  # monotonic since construction
    "shards": N, "flush_docs": B,
    "totals": {"submits", "coalesced", "rejects", "denied", "fenced",
@@ -29,8 +29,6 @@ Schema (snapshot()):
               "shape_classes",                # one program per class
               "mesh_occupancy",               # docs / padded rows
               "shards_hist": {"2": n, ...}},  # shards per window
-   "transform": {"device_docs", "host_docs", "fallbacks", "batches",
-                 "device_ratio"},             # device tail planning
    "hydration": {"prefetches", "warm_hits", "hydrations", ...},
                                     # the residency tier's counter set
                                     # (HYDRATION_KEYS; all zero until a
@@ -131,8 +129,8 @@ class ServeMetrics:
     # item, the admission-SLO signal) + the live-telemetry double-write
     # (`ts` TimeSeries, wired by attach_obs: every counter/latency also
     # lands in the windowed ring so rate()/quantile() answer "now");
-    # v10 = the `transform` block (device-resident tail planning,
-    # tpu/xform.py: docs planned on device vs. the host tracker walk,
+    # v10 = the `transform` block (device-resident tail planning:
+    # docs planned on device vs. the host tracker walk,
     # per-doc cross-check fallbacks, batched dispatches) + the
     # `pallas_fallbacks` shard counter (Pallas replay rung failures
     # that fell to the XLA fused rung);
@@ -151,8 +149,12 @@ class ServeMetrics:
     # `device_errors` / `warmup_errors` / `pump_errors` count what used
     # to be swallowed, `reads_from_device` / `reads_from_host` say
     # where bank.text() answered from, and `window.shape_classes`
-    # counts the (cap, max_ins) classes the mesh windows held
-    SCHEMA_VERSION = 14
+    # counts the (cap, max_ins) classes the mesh windows held;
+    # v15 = the `transform` block left with the device-plan transform
+    # it counted: every tail is planned by
+    # `FusedDocSession.plan_tail`, whose `plan.tail` phase row counts
+    # its walks (`xf_native` / `xf_python`)
+    SCHEMA_VERSION = 15
 
     def __init__(self, n_shards: int, flush_docs: int,
                  max_pending: int) -> None:
@@ -179,12 +181,6 @@ class ServeMetrics:
         self.window_staged_bytes = 0  # host->device staging paid
         self.window_shape_classes = 0  # (cap, max_ins) classes held
         self.window_shards_hist: Dict[int, int] = {}
-        # device-transform planning accounting (scheduler-level: the
-        # batched dispatch is shared across a bucket)
-        self.xform_device_docs = 0   # tails planned by the device xform
-        self.xform_host_docs = 0     # tails the extractor host-planned
-        self.xform_fallbacks = 0     # device cross-check -> host re-plan
-        self.xform_batches = 0       # batched xform dispatches
         self.max_depth_seen = 0
         self.queue_bound_violations = 0
         self.queue_depth: List[int] = [0] * n_shards
@@ -273,22 +269,6 @@ class ServeMetrics:
             self.window_shape_classes += shape_classes
             self.window_shards_hist[n_shards] = \
                 self.window_shards_hist.get(n_shards, 0) + 1
-
-    def record_transform(self, shard: int, device_docs: int = 0,
-                         host_docs: int = 0, fallbacks: int = 0,
-                         batches: int = 0) -> None:
-        """One bucket's device-transform planning outcome
-        (tpu/xform.plan_tails_device stats): how many tails resolved
-        their merge positions on device vs. fell to the host tracker
-        walk — the `device_ratio` in the snapshot is the transform
-        rung's engagement signal."""
-        with self._lock:
-            self.xform_device_docs += device_docs
-            self.xform_host_docs += host_docs
-            self.xform_fallbacks += fallbacks
-            self.xform_batches += batches
-        if self.ts is not None and device_docs:
-            self.ts.inc("serve.xform_device_docs", device_docs)
 
     def observe_device_time(self, shard: int, wall_s: float,
                             device_s: float) -> None:
@@ -408,16 +388,6 @@ class ServeMetrics:
                 "shards_hist": {
                     str(k): v for k, v in
                     sorted(self.window_shards_hist.items())},
-            },
-            "transform": {
-                "device_docs": self.xform_device_docs,
-                "host_docs": self.xform_host_docs,
-                "fallbacks": self.xform_fallbacks,
-                "batches": self.xform_batches,
-                "device_ratio": round(
-                    self.xform_device_docs
-                    / max(self.xform_device_docs + self.xform_host_docs
-                          + self.xform_fallbacks, 1), 4),
             },
             "hydration": dict(self.hydration),
             "read": read_snap,

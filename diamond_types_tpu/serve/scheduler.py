@@ -75,17 +75,13 @@ class MergeScheduler:
                  flush_docs: int = 8,
                  flush_deadline_s: float = 0.05,
                  place_on_devices: bool = False,
-                 session_opts: Optional[dict] = None,
                  sync_lock=None,
                  admit: Optional[Callable[[str], bool]] = None,
-                 fused: bool = True,
                  fused_opts: Optional[dict] = None,
                  flush_workers: bool = True,
                  warmup: bool = False,
                  mesh_window: bool = False,
-                 mesh_window_rows: Optional[int] = None,
-                 device_plan: bool = False,
-                 pallas: bool = False) -> None:
+                 mesh_window_rows: Optional[int] = None) -> None:
         """`resolve(doc_id) -> OpLog` is the document authority —
         DocStore.get fits directly. `sync_lock` (e.g. DocStore.lock) is
         the OPLOG guard: held around host-side oplog reads (session
@@ -93,12 +89,13 @@ class MergeScheduler:
         handler threads mutating the oplog; `resolve` is always called
         OUTSIDE it (DocStore.get takes that same non-reentrant lock).
         Device execution is guarded by per-device locks instead — see
-        the module docstring. `fused=True` (device engine only) builds
-        flush-fuse sessions and replays whole buckets in one vmapped
-        device call; `flush_workers=True` flushes through per-shard
-        worker threads; `warmup=True` pre-compiles the fused kernels on
+        the module docstring. A device engine holds every document as
+        a `FusedDocSession` (built with `fused_opts`: cap / max_ins /
+        headroom) and replays a whole bucket in one vmapped device
+        call; `flush_workers=True` flushes through per-shard
+        worker threads; `warmup=True` pre-compiles the replay kernels on
         a background thread at construction. `mesh_window=True`
-        (fused device engine only) inverts the flush concurrency model:
+        (device engine only) inverts the flush concurrency model:
         instead of handing each shard's bucket to its own worker (N
         device dispatches per window), `pump()` assembles EVERY due
         shard's fusable tails into one mesh-sharded super-batch and
@@ -106,14 +103,11 @@ class MergeScheduler:
         see `_flush_window`; `mesh_window_rows` is the most rows of one
         class that go out in one such program (default `n_shards x
         flush_docs`, one bucket a shard: a deployment states it when
-        its warm-up is built around it). `device_plan=True` (fused
-        device engine only) plans tails through the device transform
-        (tpu/xform.plan_tails_device) instead of the host tracker walk;
-        `pallas=True` replays through the Pallas step kernel where one
-        device holds the window. The ladder below a replay (per-doc →
-        host) answers DATA faults only — an overflowing tail, a
-        poisoned or drifting length. A rung that raises is counted
-        (`device_errors`), recorded and re-raised: see serve/bank.py."""
+        its warm-up is built around it). What follows a replay
+        (per-doc → host) answers DATA faults only — an overflowing
+        tail, a poisoned or drifting length. A replay that raises is
+        counted (`device_errors`), recorded and re-raised: see
+        serve/bank.py."""
         self.resolve = resolve
         self._sync_lock = sync_lock if sync_lock is not None \
             else contextlib.nullcontext()
@@ -131,32 +125,27 @@ class MergeScheduler:
             if place_on_devices:
                 from ..parallel.mesh import serve_shard_devices
                 devices = serve_shard_devices(n_shards)
-        self.fused = bool(fused) and engine == "device"
-        # mesh flush windows ride on fused sessions (the super-batch is
-        # assembled from FusedDocSession plan rows)
-        self.mesh_window = bool(mesh_window) and self.fused
+        # mesh flush windows are assembled from FusedDocSession plan
+        # rows: a host engine has none
+        self.mesh_window = bool(mesh_window) and engine == "device"
         if mesh_window_rows is None:
             mesh_window_rows = n_shards * flush_docs
         if int(mesh_window_rows) < 1:
             raise ValueError(
                 f"mesh_window_rows={mesh_window_rows!r}: at least 1")
         self.mesh_window_rows = int(mesh_window_rows)
-        self.device_plan = bool(device_plan) and self.fused
-        self.pallas = bool(pallas) and self.fused
         self._mesh = None          # lazy: first window / warmup builds
         self.banks = [
             SessionBank(i, max_sessions=max_sessions_per_shard,
                         max_slots=max_slots_per_shard, engine=engine,
                         device=devices[i], metrics=self.metrics,
-                        session_opts=session_opts,
-                        fused=fused, fused_opts=fused_opts,
+                        fused_opts=fused_opts,
                         # the jit cache is process-global: one warmer
                         # covers every shard's shape classes
                         warmup=(warmup and i == 0),
                         flush_docs=flush_docs,
                         mesh_shards=(n_shards if self.mesh_window
-                                     else 0),
-                        device_plan=device_plan, pallas=pallas)
+                                     else 0))
             for i in range(n_shards)]
         # per-DEVICE locks: shards placed on the same chip share one;
         # unplaced shards (device=None) get their own (the default
@@ -779,31 +768,19 @@ class MergeScheduler:
                             parent=fspan.context(),
                             attrs={"docs": len(rows), "cap": cap,
                                    "max_ins": mi})
-                    staged = 0
-                    # the Pallas step-kernel replay takes single-device
-                    # windows only — its program is not mesh-sharded,
-                    # so a window spanning devices takes the mesh one
-                    rung = "pallas" if self.pallas and len(dlocks) <= 1 \
-                        else "mesh"
                     try:
-                        if rung == "pallas":
-                            from ..tpu import flush_fuse as _ff
-                            ok, device_s = _ff.pallas_fused_replay(
-                                sessions, plans)
-                            dspan.end(rung="pallas")
-                        else:
-                            ok, device_s, bp, staged = \
-                                mesh_fused_replay(mesh, sessions, plans)
-                            mesh_docs += len(rows)
-                            padded_rows += bp
-                            staged_bytes += staged
-                            dspan.end(padded_b=bp, staged_bytes=staged)
+                        ok, device_s, bp, staged = \
+                            mesh_fused_replay(mesh, sessions, plans)
                     except Exception as e:
                         dspan.end(error=e.__class__.__name__)
                         self.banks[rows[0][1]].device_error(
-                            rung, e, docs=len(rows), cap=cap)
+                            "mesh", e, docs=len(rows), cap=cap)
                         err = e
                         break
+                    mesh_docs += len(rows)
+                    padded_rows += bp
+                    staged_bytes += staged
+                    dspan.end(padded_b=bp, staged_bytes=staged)
                     dispatches += 1
                 wall = time.perf_counter() - t_cls
                 PROFILER.observe_window(wall, device_s, len(rows),
@@ -960,7 +937,7 @@ class MergeScheduler:
         snap = self.metrics.snapshot()
         snap["router_counts"] = self.router.counts()
         if self.obs is not None:
-            # beside, not inside, the ServeMetrics schema (version 14)
+            # beside, not inside, the ServeMetrics schema
             snap["phases"] = self.obs.phases.snapshot()
         return snap
 

@@ -236,11 +236,10 @@ def cmd_serve_bench(args) -> int:
               flush_deadline_s=args.flush_deadline,
               max_pending=args.max_pending,
               max_sessions=args.max_sessions, seed=args.seed,
-              fused=args.fused, flush_workers=args.workers,
+              flush_workers=args.workers,
               warmup=args.warmup, steady_rounds=args.steady_rounds,
               mesh_window=args.mesh_window, telemetry=args.telemetry,
               journey=args.journey,
-              device_plan=args.device_plan, pallas=args.pallas,
               steer=args.steer, device_stage=args.device_stage)
     if args.dry_run:
         # CI smoke preset: host engine, tiny workload, no jax needed
@@ -257,8 +256,7 @@ def cmd_serve_bench(args) -> int:
         print(f"serve-bench: {report['config']['docs']} docs / "
               f"{report['config']['shards']} shards "
               f"({report['config']['engine']} engine, "
-              f"{report['config']['mode']} mode, "
-              f"fused={'on' if report['config'].get('fused') else 'off'}): "
+              f"{report['config']['mode']} mode): "
               f"{report['total_ops']} ops in {report['wall_s']}s "
               f"({report['ops_per_sec']} ops/s), "
               f"occupancy {m['batch_occupancy']}, "
@@ -936,9 +934,7 @@ def cmd_obs_watch(args) -> int:
             dp = (doc.get("obs") or {}).get("devprof") or {}
             jit = dp.get("jit_cache") or {}
             if dp.get("enabled") and jit:
-                # one row per jit family — the PR-13 device-resident
-                # tail transform (`xform`) and Pallas replay rung
-                # (`pallas`) surface here next to micro/tip/fused
+                # one row per jit family
                 print("== device (jit cache) ==")
                 for fam, row in sorted(jit.items()):
                     h, m = row.get("hits", 0), row.get("misses", 0)
@@ -1333,11 +1329,6 @@ def main(argv=None) -> int:
     c.add_argument("--max-pending", type=int, default=64)
     c.add_argument("--max-sessions", type=int, default=4)
     c.add_argument("--seed", type=int, default=7)
-    c.add_argument("--fused", action=argparse.BooleanOptionalAction,
-                   default=True,
-                   help="fused vmapped bucket flush (--no-fused = the "
-                   "serial per-doc zone-session path, for speedup "
-                   "comparisons)")
     c.add_argument("--workers", action=argparse.BooleanOptionalAction,
                    default=True,
                    help="per-shard flush worker threads "
@@ -1348,19 +1339,6 @@ def main(argv=None) -> int:
                    help="mesh flush windows: every due shard's bucket "
                    "replayed in ONE shard_map dispatch per window "
                    "(default: one device call per shard)")
-    c.add_argument("--device-plan",
-                   action=argparse.BooleanOptionalAction,
-                   default=False,
-                   help="device-resident tail transform: resolve "
-                   "concurrent merge positions on device "
-                   "(tpu/xform.py) instead of the host tracker walk; "
-                   "per-doc host fallback on any guard trip")
-    c.add_argument("--pallas",
-                   action=argparse.BooleanOptionalAction,
-                   default=False,
-                   help="Pallas step-kernel replay rung at the top of "
-                   "the flush ladder (pallas -> mesh -> fused -> "
-                   "per-doc -> host)")
     c.add_argument("--steer",
                    action=argparse.BooleanOptionalAction,
                    default=True,

@@ -1588,7 +1588,7 @@ def serve(port: int = 8008, data_dir: Optional[str] = None,
     environment named cpu — tpu/runtime.py), and so does a warm-up
     compile that fails. `sched_opts` are further MergeScheduler kwargs
     — bank budgets (`max_sessions_per_shard`, `max_slots_per_shard`),
-    `flush_docs`, `max_pending`, `mesh_window`, `pallas`, ...; the
+    `flush_docs`, `max_pending`, `mesh_window`, ...; the
     device engine defaults to `place_on_devices=True` (one shard per
     chip, wrapping) and `warmup=True`. Either engine needs the native
     host core: a failed build or load raises (native.require_native)

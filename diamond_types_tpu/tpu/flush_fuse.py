@@ -37,7 +37,6 @@ engine must never pull in a backend.
 
 from __future__ import annotations
 
-import threading
 import time
 from dataclasses import dataclass
 from typing import List, Sequence, Tuple
@@ -127,106 +126,10 @@ def _fused_fn(b: int, n: int, mi: int, cap: int):
     return fn
 
 
-_pallas_jit_cache = {}
-_pallas_jit_lock = _make_lock("pallas_jit", "leaf")
-
-
-def make_pallas_replay_body(mi: int, interpret: bool):
-    """The fused replay body with the op-step kernel in Pallas
-    (pallas_kernels.apply_op_block — scalar-controlled lane rotations
-    instead of the XLA formulation's per-lane gathers, which Mosaic caps
-    at ~128 lanes). Poison masking and the -1 length sentinel are
-    byte-identical to make_replay_body, so `adopt_results` fences this
-    rung exactly like the fused and mesh rungs."""
-    import jax
-    import jax.numpy as jnp
-
-    from .pallas_kernels import apply_op_block
-
-    def run(docs, lens, pos, dlen, ilen, chars):
-        bad = (dlen > mi) | (ilen > mi)
-        dlen = jnp.where(bad, 0, dlen)
-        ilen = jnp.where(bad, 0, ilen)
-        bad_doc = jnp.any(bad, axis=1)
-
-        def step(carry, op):
-            d, l = carry
-            p, dl, il, c = op
-            d, l = apply_op_block(p, dl, il, c, d, l, interpret=interpret)
-            return (d, l), None
-
-        ops = (jnp.swapaxes(pos, 0, 1), jnp.swapaxes(dlen, 0, 1),
-               jnp.swapaxes(ilen, 0, 1), jnp.swapaxes(chars, 0, 1))
-        (docs, lens), _ = jax.lax.scan(step, (docs, lens), ops)
-        return docs, jnp.where(bad_doc, -1, lens)
-
-    return run
-
-
-def _pallas_fn(b: int, n: int, mi: int, cap: int):
-    """Jitted Pallas-rung replay, cache "pallas" — same pow2 shape-class
-    discipline as `_fused_fn`. Off the TPU the kernel runs interpreted
-    (`runtime.pallas_interpret`), so the rung stays exercisable on the
-    CPU-simulated mesh."""
-    import jax
-
-    from .runtime import pallas_interpret
-    interpret = pallas_interpret()
-    key = (b, n, mi, cap, interpret)
-    with _pallas_jit_lock:
-        fn = _pallas_jit_cache.get(key)
-        from ..obs.devprof import note_jit_lookup
-        note_jit_lookup("pallas", fn is not None)
-        if fn is None:
-            fn = jax.jit(make_pallas_replay_body(mi, interpret),
-                         donate_argnums=(0, 1))
-            _pallas_jit_cache[key] = fn
-    from .steer import STEER
-    STEER.note_warm("pallas", mi, cap, b, n)
-    return fn
-
-
-def pallas_fused_replay(sessions: List["FusedDocSession"],
-                        plans: List["TailPlan"]
-                        ) -> Tuple[List[bool], float]:
-    """The ladder's TOP rung: fused bucket replay through the Pallas
-    step kernel. Same packing, fences, and commit protocol as
-    `fused_replay`."""
-    import jax.numpy as jnp
-
-    b = len(sessions)
-    assert b == len(plans) and b >= 1
-    cap = sessions[0].cap
-    mi = sessions[0].max_ins
-    from .steer import STEER
-    n0 = _pow2(max(max(p.n_ops for p in plans), 1))
-    bp0 = _pow2(b) if b > 1 else 1
-    bp, n = STEER.snap("pallas", bp0, n0, mi, cap)
-    pos, dlen, ilen, chars = pack_plans(plans, n, mi, bp)
-    from ..obs.devprof import note_transfer
-    note_transfer(pos.nbytes + dlen.nbytes + ilen.nbytes + chars.nbytes,
-                  rung="pallas", purpose="plan")
-    docs = jnp.stack([s.docs for s in sessions]
-                     + [sessions[0].docs] * (bp - b))
-    lens = jnp.stack([s.lens for s in sessions]
-                     + [sessions[0].lens] * (bp - b))
-    fn = _pallas_fn(bp, n, mi, cap)
-    out_docs, out_lens = fn(docs, lens, jnp.asarray(pos),
-                            jnp.asarray(dlen), jnp.asarray(ilen),
-                            jnp.asarray(chars))
-    t_fence = time.perf_counter()
-    got = np.asarray(out_lens)
-    device_s = time.perf_counter() - t_fence
-    return adopt_results(sessions, plans, out_docs, out_lens, got), \
-        device_s
-
-
 def warmup_fused_cache(flush_docs: int = 8, cap: int = DEFAULT_CAP,
                        max_ins: int = DEFAULT_MAX_INS,
                        shape_classes: Sequence[int] = WARMUP_SHAPE_CLASSES,
-                       mesh_shards: int = 0,
-                       xform_classes: Sequence[int] = (),
-                       pallas: bool = False) -> int:
+                       mesh_shards: int = 0) -> int:
     """Compile the fused kernel for every (batch, ops) shape class a
     bank configured with `flush_docs` can emit, so the first REAL flush
     hits a warm jit cache instead of eating a compile on the request
@@ -237,13 +140,7 @@ def warmup_fused_cache(flush_docs: int = 8, cap: int = DEFAULT_CAP,
     (`parallel.mesh.mesh_flush_fn`) for every super-batch shape class a
     `mesh_shards`-shard window can assemble — B padded to the mesh per
     `pad_batch_to_mesh` — so the first mesh window doesn't eat a cold
-    compile either (cache "mesh").
-
-    `xform_classes` pre-compiles the device-transform dispatch
-    (tpu/xform.py, cache "xform") for those run-count classes — its
-    Pallas variant under `pallas=True`, which also pre-compiles the
-    Pallas replay rung (cache "pallas") for the same shape classes as
-    the fused rung."""
+    compile either (cache "mesh")."""
     import jax
     import jax.numpy as jnp
 
@@ -297,33 +194,6 @@ def warmup_fused_cache(flush_docs: int = 8, cap: int = DEFAULT_CAP,
                               + ch.nbytes, rung="mesh",
                               purpose="warmup")
                 _out, out_lens = fn(docs, lens, z, z, z, ch)
-                jax.block_until_ready(out_lens)
-                compiled += 1
-    if xform_classes:
-        # all-padding tables (parent=root, huge keys, zero visibility)
-        # exercise exactly the (bp, n) signature resolve_positions emits
-        from .xform import INT32_MAX, _xform_fn
-        for b in batches:
-            for ncls in xform_classes:
-                n = _pow2(ncls)
-                fn = _xform_fn(b, n, pallas)
-                parent = jnp.full((b, n), n, jnp.int32)
-                side = jnp.ones((b, n), jnp.int32)
-                keys = jnp.full((b, n), INT32_MAX, jnp.int32)
-                z = jnp.zeros((b, n), jnp.int32)
-                out = fn(parent, side, keys, keys, keys, z, z)
-                jax.block_until_ready(out[2])
-                compiled += 1
-    if pallas:
-        for b in batches:
-            for ncls in shape_classes:
-                n = _pow2(ncls)
-                fn = _pallas_fn(b, n, max_ins, cap)
-                docs = jnp.zeros((b, cap), jnp.int32)
-                lens = jnp.zeros((b,), jnp.int32)
-                z = jnp.zeros((b, n), jnp.int32)
-                ch = jnp.zeros((b, n, max_ins), jnp.int32)
-                _d, out_lens = fn(docs, lens, z, z, z, ch)
                 jax.block_until_ready(out_lens)
                 compiled += 1
     return compiled

@@ -52,9 +52,9 @@ class DeviceDoc:
     frontier: Optional[List[int]] = None  # version the checkout lands on
 
 
-# The agent-rank and insert-arena columns moved to listmerge/columnar.py
-# (shared with the device transform, tpu/xform.py); the historical names
-# stay importable — plan_kernels and the bench harnesses use them.
+# The agent-rank and insert-arena columns live in listmerge/columnar.py;
+# the historical names stay importable — plan_kernels and
+# listmerge/zone_np.py use them.
 from ..listmerge.columnar import (agent_key_columns as _agent_keys,
                                   arena_offset_columns as _arena_offsets)
 

@@ -24,8 +24,6 @@ The kernels here are therefore gather-free:
   VMEM need and program size do not grow with the capacity class,
   replacing the per-lane gathers of the XLA formulation in
   tpu/batch.py.
-* `xform_positions_pallas` is chunked prefix scans with a carried
-  scalar row.
 
 Tests exercise the kernels interpreted on the CPU (the one mapping from
 backend to interpret mode is `runtime.pallas_interpret`) AND assert TPU
@@ -269,110 +267,25 @@ def materialize_pallas(perm, vis_len, arena_off, arena, cap: int,
     return out[0, :cap], total
 
 
-# ---------------------------------------------------------------------------
-# transform position resolution: prefix scans with a carried chunk state
-# ---------------------------------------------------------------------------
+def replay_ops_pallas(docs, lens, pos, dlen, ilen, chars,
+                      interpret: Optional[bool] = None):
+    """`apply_op_block` inside lax.scan over op index, continued from
+    the `[b, cap]` state `(docs, lens)`: the signature of the served
+    XLA replay (`flush_fuse.make_replay_body`), which chip_smoke.py
+    compares it with on the chip."""
+    def step(carry, op):
+        return apply_op_block(*op, *carry, interpret=interpret), None
 
-_XCB = 512          # scan-chunk lanes (4 int32 vregs)
-
-
-def _xform_pos_kernel(nv_ref, ov_ref, pos_ref, stats_ref, *, cb: int):
-    """One chunk of the transform's position-resolution scan (grid =
-    chunks, sequential on TPU so the stats row carries across steps).
-
-    Given DOC-ORDERED visible-length columns (nv = chars after the
-    merge, ov = chars at the session frontier), each run's edit position
-    is the exclusive prefix sum of nv, the projected length is Σnv, and
-    the replay's peak length offset is the running max of Σ(nv-ov).
-
-    Gather-free by construction (the Mosaic ≤128-lane gather limit —
-    module doc): the caller applies the device-computed Fugue order
-    BEFORE this kernel, so everything here is chunked cumsums + a
-    carried scalar row — no per-lane table lookups at all.
-
-    stats row: [0] chars emitted so far, [1] running Σ(nv-ov),
-    [2] running peak of Σ(nv-ov)."""
-    k = pl.program_id(0)
-
-    @pl.when(k == 0)
-    def _init():
-        for j in range(3):      # SMEM takes scalar stores only
-            stats_ref[0, j] = 0
-
-    base = stats_ref[0, 0]
-    cdelta = stats_ref[0, 1]
-    peak = stats_ref[0, 2]
-    nv = nv_ref[...]                    # [1, cb]
-    dv = nv - ov_ref[...]
-    pos_ref[...] = base + _lane_cumsum(nv, cb) - nv
-    stats_ref[0, 0] = base + jnp.sum(nv)
-    stats_ref[0, 1] = cdelta + jnp.sum(dv)
-    stats_ref[0, 2] = jnp.maximum(peak,
-                                  cdelta + jnp.max(_lane_cumsum(dv, cb)))
-
-
-def _lane_cumsum(x, cb: int):
-    """Inclusive prefix sum along the lanes of a [1, cb] row, as
-    log2(cb) rotate-and-add steps (Mosaic has no cumsum lowering; lane
-    rotation and masked adds it has)."""
-    idx = jax.lax.broadcasted_iota(jnp.int32, (1, cb), 1)
-    s = 1
-    while s < cb:
-        x = x + jnp.where(idx >= s, pltpu.roll(x, s, 1), 0)
-        s *= 2
-    return x
-
-
-def xform_positions_pallas(nv, ov, *, interpret: Optional[bool] = None):
-    """Gather-free Pallas run of the transform position-resolution hot
-    loop (drop-in for the jnp scans in tpu/xform._xform_single; inputs
-    are the doc-order-permuted visibility columns). Returns
-    (pos [n] int32, new_len, peak_delta >= 0)."""
-    if interpret is None:
-        interpret = pallas_interpret()
-    n = nv.shape[0]
-    cb = min(_XCB, _round_up(max(n, 1), 128))
-    npad = _round_up(max(n, 1), cb)
-    nv_p = jnp.zeros((1, npad), jnp.int32).at[0, :n].set(
-        nv.astype(jnp.int32))
-    ov_p = jnp.zeros((1, npad), jnp.int32).at[0, :n].set(
-        ov.astype(jnp.int32))
-    tab = pl.BlockSpec((1, cb), lambda k: (0, k), memory_space=pltpu.VMEM)
-    stat = pl.BlockSpec((1, 4), lambda k: (0, 0), memory_space=pltpu.SMEM)
-    pos, stats = pl.pallas_call(
-        functools.partial(_xform_pos_kernel, cb=cb),
-        grid=(npad // cb,),
-        in_specs=[tab, tab],
-        out_specs=[tab, stat],
-        out_shape=[jax.ShapeDtypeStruct((1, npad), jnp.int32),
-                   jax.ShapeDtypeStruct((1, 4), jnp.int32)],
-        interpret=interpret,
-    )(nv_p, ov_p)
-    return (pos[0, :n], stats[0, 0],
-            jnp.maximum(stats[0, 2], jnp.int32(0)))
-
-
-def _next_pow2(x: int) -> int:
-    return 1 << max(1, int(x) - 1).bit_length()
+    ops = tuple(jnp.swapaxes(a, 0, 1) for a in (pos, dlen, ilen, chars))
+    return jax.lax.scan(step, (docs, lens), ops)[0]
 
 
 @functools.partial(jax.jit, static_argnames=("cap", "interpret"))
 def replay_batch_pallas(pos, dlen, ilen, chars, cap: int,
                         interpret: Optional[bool] = None):
-    """Full batched replay with the Pallas step kernel inside lax.scan
-    (drop-in for tpu.batch.replay_batch)."""
+    """Full batched replay from empty documents with the Pallas step
+    kernel (drop-in for tpu.batch.replay_batch)."""
     b = pos.shape[0]
-    docs0 = jnp.zeros((b, cap), dtype=jnp.int32)
-    lens0 = jnp.zeros((b,), dtype=jnp.int32)
-
-    def step(carry, op):
-        docs, lens = carry
-        p, d, i, c = op
-        docs, lens = apply_op_block(p, d, i, c, docs, lens,
-                                    interpret=interpret)
-        return (docs, lens), None
-
-    ops = (jnp.swapaxes(pos, 0, 1), jnp.swapaxes(dlen, 0, 1),
-           jnp.swapaxes(ilen, 0, 1), jnp.swapaxes(chars, 0, 1))
-    (docs, lens), _ = jax.lax.scan(step, (docs0, lens0), ops)
-    return docs, lens
+    return replay_ops_pallas(jnp.zeros((b, cap), dtype=jnp.int32),
+                             jnp.zeros((b,), dtype=jnp.int32),
+                             pos, dlen, ilen, chars, interpret=interpret)

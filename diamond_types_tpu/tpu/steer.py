@@ -1,6 +1,6 @@
 """Batch-shape steering: snap flush windows onto WARM jit shape classes.
 
-The dispatch rungs (pallas / mesh / fused) key their jit caches on the
+The two replay paths (mesh / fused) key their jit caches on the
 padded `(b, n, max_ins, cap)` shape class, and pow2 rounding keeps the
 class count O(log^2) — but pow2 rounding alone still lets a drifting
 workload thrash the cache: a flash crowd whose per-window op counts
@@ -8,8 +8,8 @@ wander across pow2 buckets recompiles mid-flush even though a slightly
 LARGER warmed class could have absorbed the window with bounded padding
 waste. This module closes that gap with a tiny process-global policy:
 
-  * `ShapeSteer` tracks the WARM set per jit cache ("fused" / "pallas"
-    / "mesh") — populated by `note_warm` from the cache-lookup sites
+  * `ShapeSteer` tracks the WARM set per jit cache ("fused" / "mesh")
+    — populated by `note_warm` from the cache-lookup sites
     themselves (warmup compiles and observed flush compiles alike), so
     the table can never drift from the real jit caches.
   * `snap()` maps a window's pow2-floored `(bp0, n0)` to the shape
@@ -26,7 +26,8 @@ rows replicate row 0 (per-shard rungs) or carry the `lens = -1` inert
 sentinel (mesh rung), and op-axis padding rows are all-zero no-ops —
 exactly the invariants `pack_plans` and the replay body already
 maintain for pow2 rounding. The `adopt_results` length fence and the
-five-rung fallback ladder sit BELOW this policy untouched.
+data-fault ladder below it (per-doc, host) are untouched by this
+policy.
 
 `cap_class()` / `warmup_batches()` are the single source of truth for
 capacity flooring and warmup batch enumeration — `warmup_fused_cache`

@@ -60,7 +60,7 @@ def _witness_clean():
     ("bad_ts_lock_order.py", "lock-order", 15, "error"),
     ("bad_incident_lock_order.py", "lock-order", 15, "error"),
     ("bad_wire_lock_order.py", "lock-order", 14, "error"),
-    ("bad_xform_lock_order.py", "lock-order", 15, "error"),
+    ("bad_arena_lock_order.py", "lock-order", 15, "error"),
     ("bad_steer_lock_order.py", "lock-order", 15, "error"),
     ("bad_unsorted_locks.py", "unsorted-locks", 15, "error"),
     ("bad_device_under_lock.py", "device-under-lock", 13, "error"),

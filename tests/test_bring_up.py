@@ -193,8 +193,7 @@ def test_warmup_compile_failure_surfaces(monkeypatch):
 
     monkeypatch.setattr(ff, "warmup_fused_cache", refuse)
     m = ServeMetrics(1, flush_docs=4, max_pending=16)
-    bank = SessionBank(0, engine="device", metrics=m, fused=True,
-                       warmup=True)
+    bank = SessionBank(0, engine="device", metrics=m, warmup=True)
     bank.recorder = FlightRecorder()
     with pytest.raises(RuntimeError, match="warm-up failed.*Mosaic"):
         bank.join_warmup(timeout=60)
@@ -362,27 +361,23 @@ def test_apply_op_block_tiled_matches_xla_twin():
 
 
 def test_serve_ladder_pallas_kernels_lower_for_tpu_at_both_classes():
-    """Real (interpret=False) Mosaic lowering of the two Pallas kernels
-    the serve ladder reaches, at the note and paper capacity classes.
-    `xform_positions_pallas` had never lowered for real before PR 21: its
-    old form stored a vector to SMEM and used cumsum, both of which the
-    TPU lowering refuses."""
+    """Real (interpret=False) Mosaic lowering of a replay scan over
+    `apply_op_block`, the hand kernel parked outside the flush path
+    (ROADMAP D4), at the note and paper capacity classes: what
+    chip_smoke.py's kernel phase runs on the chip lowers here first."""
+    import functools
     import jax
     import jax.numpy as jnp
-    from diamond_types_tpu.tpu import pallas_kernels as pk
-    from diamond_types_tpu.tpu.flush_fuse import make_pallas_replay_body
+    from diamond_types_tpu.tpu.pallas_kernels import replay_ops_pallas
     b, n, mi = 8, 4, 16
+    replay = functools.partial(replay_ops_pallas, interpret=False)
     for cap in (1 << 14, 1 << 18):
         z = jnp.zeros((b, n), jnp.int32)
-        jax.jit(make_pallas_replay_body(mi, False)).trace(
+        text = jax.jit(replay).trace(
             jnp.zeros((b, cap), jnp.int32), jnp.zeros((b,), jnp.int32),
             z, z, z, jnp.zeros((b, n, mi), jnp.int32)
-        ).lower(lowering_platforms=("tpu",))
-    for runs in (64, 4096):
-        v = jnp.ones((runs,), jnp.int32)
-        jax.jit(lambda a, c: pk.xform_positions_pallas(
-            a, c, interpret=False)).trace(v, v).lower(
-                lowering_platforms=("tpu",))
+        ).lower(lowering_platforms=("tpu",)).as_text()
+        assert "tpu_custom_call" in text
 
 
 def test_materialize_pallas_refuses_an_oversized_run_table():
@@ -410,6 +405,7 @@ def test_serve_device_engine_end_to_end(tmp_path):
     the device text equals the host engine's HTTP body, reads are counted
     as device reads, nothing falls back, and a restart on the same data
     dir reads everything back."""
+    from diamond_types_tpu.serve import ServeMetrics
     from diamond_types_tpu.tools.server import serve
     so = dict(flush_docs=4, flush_deadline_s=0.02)
     httpd = serve(port=0, data_dir=str(tmp_path), serve_shards=2,
@@ -418,7 +414,7 @@ def test_serve_device_engine_end_to_end(tmp_path):
     base = f"http://127.0.0.1:{httpd.server_address[1]}"
     docs = [f"doc{i}" for i in range(6)]
     sched = httpd.store.scheduler
-    assert sched.banks[0].engine == "device" and sched.fused
+    assert sched.banks[0].engine == "device"
     assert {b.device for b in sched.banks} != {None}    # placed
     try:
         heads = {d: [] for d in docs}
@@ -437,7 +433,7 @@ def test_serve_device_engine_end_to_end(tmp_path):
             assert sched.text(d) == texts[d] != ""
         with urllib.request.urlopen(f"{base}/metrics") as r:
             m = json.loads(r.read())["serve"]
-        assert m["version"] == 14
+        assert m["version"] == ServeMetrics.SCHEMA_VERSION
         t = m["totals"]
         assert t["reads_from_device"] == len(docs)
         assert t["reads_from_host"] == 0

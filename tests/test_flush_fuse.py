@@ -116,29 +116,35 @@ def test_capacity_overflow_resyncs_then_converges():
 # ---- bank-level: fused vs per-doc vs host --------------------------------
 
 def test_sync_docs_three_engine_parity():
-    """The same randomized bucket through fused, per-doc zone-session,
-    and host banks — all three parity with the oplog authority."""
-    rng = random.Random(23)
+    """The same randomized bucket through the fused replay
+    (`sync_docs`), the per-doc path below it (`sync_doc`, one replay a
+    document) and a host bank — all three parity with the oplog
+    authority."""
     docs = [f"p{i}" for i in range(4)]
 
-    def run(engine, fused):
+    def run(engine, per_doc=False):
         ols = {d: _mk_oplog(d) for d in docs}
         # fresh rng per engine so all three see identical histories
         r = random.Random(77)
+        bank = SessionBank(0, engine=engine, fused_opts=FUSED_OPTS,
+                           metrics=ServeMetrics(1, 4, 64))
+
+        def flush():
+            if per_doc:
+                return [bank.sync_doc(d, ols[d]) for d in docs]
+            return bank.sync_docs(_items(docs), ols.__getitem__)
+
         for d in docs:
             _random_edits(ols[d], r, 3)
-        bank = SessionBank(0, engine=engine, fused=fused,
-                           fused_opts=FUSED_OPTS,
-                           metrics=ServeMetrics(1, 4, 64))
-        bank.sync_docs(_items(docs), ols.__getitem__)
+        flush()
         for d in docs:
             _random_edits(ols[d], r, 2)
-        res = bank.sync_docs(_items(docs), ols.__getitem__)
+        res = flush()
         return {d: bank.text(d, ols[d]) for d in docs}, ols, res, bank
 
-    fused_txt, fols, fres, fbank = run("device", True)
-    perdoc_txt, pols, _pres, _ = run("device", False)
-    host_txt, hols, _hres, _ = run("host", False)
+    fused_txt, fols, fres, fbank = run("device")
+    perdoc_txt, pols, pres, pbank = run("device", per_doc=True)
+    host_txt, hols, _hres, _ = run("host")
     for d in docs:
         want = fols[d].checkout_tip().snapshot()
         assert fused_txt[d] == want
@@ -152,31 +158,11 @@ def test_sync_docs_three_engine_parity():
     m = fbank.metrics.snapshot()
     assert m["fused"]["device_calls"] >= 1
     assert m["fused"]["occupancy"] > 1
-
-
-def test_sync_docs_mixed_residency_falls_back_per_doc():
-    """A non-fused session already resident in the bucket must not
-    break the flush: it goes per-doc, the rest still parity."""
-    from diamond_types_tpu.tpu.zone_session import DeviceZoneSession
-    docs = ["m0", "m1", "m2"]
-    ols = {d: _mk_oplog(d) for d in docs}
-    rng = random.Random(5)
-    for d in docs:
-        _random_edits(ols[d], rng, 3)
-    bank = SessionBank(0, engine="device", fused=True,
-                       fused_opts=FUSED_OPTS,
-                       metrics=ServeMetrics(1, 4, 64))
-    # pre-plant a legacy per-doc session for m0
-    bank.sessions["m0"] = DeviceZoneSession(ols["m0"])
-    bank._resyncs_seen["m0"] = 0
-    bank.sync_docs(_items(docs), ols.__getitem__)
-    for d in docs:
-        _random_edits(ols[d], rng, 2)
-    res = bank.sync_docs(_items(docs), ols.__getitem__)
-    assert res["fallback_docs"] >= 1     # m0 went per-doc
-    for d in docs:
-        assert bank.text(d, ols[d]) == \
-            ols[d].checkout_tip().snapshot()
+    # the per-doc path answered from the device too, a replay a doc
+    assert all(r["engine"] == "device" for r in pres)
+    pm = pbank.metrics.snapshot()
+    assert pm["fused"]["device_calls"] == 0
+    assert pm["totals"]["reads_from_device"] == len(docs)
 
 
 def test_poisoned_lens_propagates_to_host_fallback(monkeypatch):
@@ -189,8 +175,8 @@ def test_poisoned_lens_propagates_to_host_fallback(monkeypatch):
     for d in docs:
         _random_edits(ols[d], rng, 3)
     metrics = ServeMetrics(1, 4, 64)
-    bank = SessionBank(0, engine="device", fused=True,
-                       fused_opts=FUSED_OPTS, metrics=metrics)
+    bank = SessionBank(0, engine="device", fused_opts=FUSED_OPTS,
+                       metrics=metrics)
     bank.sync_docs(_items(docs), ols.__getitem__)   # builds
     for d in docs:
         _random_edits(ols[d], rng, 2)
@@ -218,6 +204,57 @@ def test_poisoned_lens_propagates_to_host_fallback(monkeypatch):
             ols[d].checkout_tip().snapshot()
 
 
+def test_bank_fused_rung_failure_propagates(monkeypatch):
+    """A `fused_replay` that raises (compiler, runtime) is no data
+    fault: counted (`device_errors`), recorded as rung "fused" with its
+    text, and raised — nothing replays the bucket on a quieter path.
+    The docs stay byte-correct through the host oracle, and the read
+    says so (`reads_from_host`)."""
+    from diamond_types_tpu.obs import Observability
+    ols = {}
+    sched = MergeScheduler(1, resolve=lambda d: ols[d], engine="device",
+                           fused_opts=FUSED_OPTS, flush_docs=8,
+                           flush_deadline_s=10.0, flush_workers=False)
+    sched.attach_obs(Observability())
+    rng = random.Random(31)
+    docs = [f"d{i}" for i in range(4)]
+    per_doc = []
+    real_sync = ff.FusedDocSession.sync
+    monkeypatch.setattr(
+        ff.FusedDocSession, "sync",
+        lambda self: per_doc.append(self.oplog.doc_id) or real_sync(self))
+    for rnd in range(3):
+        for d in docs:
+            if rnd == 0:
+                ols[d] = _mk_oplog(d)
+            _random_edits(ols[d], rng, 2)
+            assert sched.submit(d, n_ops=2)["accepted"]
+        if rnd == 2:
+            def boom(sessions, plans):
+                raise RuntimeError("injected fused failure")
+            # sync_docs re-resolves the module attribute a call
+            monkeypatch.setattr(ff, "fused_replay", boom)
+            with pytest.raises(RuntimeError, match="injected fused"):
+                sched.pump(force=True)
+        else:
+            sched.pump(force=True)
+    monkeypatch.undo()
+    assert per_doc == []
+    m = sched.metrics_json()
+    assert m["totals"]["device_errors"] == 1
+    assert m["totals"]["host_fallbacks"] == 0
+    assert m["fused"]["device_calls"] == 1      # round 1's, not round 2's
+    ev = [e for e in sched.obs.recorder.dump()
+          if e["kind"] == "device_error"]
+    assert [e["rung"] for e in ev] == ["fused"]
+    assert "injected fused failure" in ev[0]["error"]
+    for d in docs:
+        assert sched.text(d) == ols[d].checkout_tip().snapshot()
+    m = sched.metrics_json()
+    assert m["totals"]["reads_from_host"] == len(docs)
+    assert m["totals"]["reads_from_device"] == 0
+
+
 # ---- scheduler-level: workers, concurrency, fencing ----------------------
 
 def _two_shard_docs(sched, n=2):
@@ -242,8 +279,7 @@ def test_two_shard_concurrent_flush_windows():
     design) would deadlock the barrier."""
     ols = {}
     sched = MergeScheduler(2, resolve=lambda d: ols[d],
-                           engine="device", fused=True,
-                           fused_opts=FUSED_OPTS,
+                           engine="device", fused_opts=FUSED_OPTS,
                            flush_docs=2, flush_deadline_s=10.0,
                            flush_workers=True)
     by_shard = _two_shard_docs(sched)
@@ -286,8 +322,7 @@ def test_fencing_recheck_runs_inside_worker():
     be dropped BY THE WORKER at flush time, not merged."""
     ols = {}
     sched = MergeScheduler(1, resolve=lambda d: ols[d],
-                           engine="device", fused=True,
-                           fused_opts=FUSED_OPTS,
+                           engine="device", fused_opts=FUSED_OPTS,
                            flush_docs=8, flush_deadline_s=10.0,
                            flush_workers=True)
     epoch = {"n": 1}
@@ -314,8 +349,7 @@ def test_scheduler_fused_end_to_end_counters():
     from diamond_types_tpu.obs.devprof import PROFILER
     ols = {}
     sched = MergeScheduler(1, resolve=lambda d: ols[d],
-                           engine="device", fused=True,
-                           fused_opts=FUSED_OPTS,
+                           engine="device", fused_opts=FUSED_OPTS,
                            flush_docs=8, flush_deadline_s=10.0,
                            flush_workers=False)
     docs = [f"e{i}" for i in range(3)]
@@ -331,7 +365,7 @@ def test_scheduler_fused_end_to_end_counters():
                 assert sched.submit(d, n_ops=1)["accepted"]
             sched.pump(force=True)
         m = sched.metrics_json()
-        assert m["version"] == 14
+        assert m["version"] == ServeMetrics.SCHEMA_VERSION
         assert m["fused"]["device_calls"] >= 1
         assert m["fused"]["occupancy"] > 1
         assert m["fused"]["occupancy_hist"]
@@ -369,7 +403,7 @@ def test_warmup_populates_fused_jit_cache():
 
 
 def test_bank_background_warmup_thread_joins():
-    bank = SessionBank(0, engine="device", fused=True,
+    bank = SessionBank(0, engine="device",
                        fused_opts={"cap": 64, "max_ins": 2},
                        warmup=True, flush_docs=2)
     bank.join_warmup()
@@ -397,15 +431,20 @@ def test_prom_renders_fused_block():
 # ---- CLI flags -----------------------------------------------------------
 
 def test_cli_serve_bench_fused_flags_smoke(capsys):
-    """--fused/--no-fused, --workers/--no-workers, --warmup, --parity,
-    --steady-rounds all parse and the dry-run smoke passes parity."""
+    """--workers/--no-workers, --parity, --steady-rounds all parse and
+    the dry-run smoke passes parity; the switches that selected the
+    retired flush paths are refused by the parser."""
     from diamond_types_tpu.tools.cli import main
-    rc = main(["serve-bench", "--dry-run", "--no-fused",
+    rc = main(["serve-bench", "--dry-run",
                "--no-workers", "--parity", "--steady-rounds", "0"])
     out = capsys.readouterr().out
     assert rc == 0
     assert "parity OK" in out
-    assert "fused=off" in out
+    assert "fused calls" in out
+    for flag in ("--no-fused", "--device-plan", "--pallas"):
+        with pytest.raises(SystemExit):
+            main(["serve-bench", "--dry-run", flag])
+    capsys.readouterr()
 
 
 # ---- the plan walk on the oplog's native mirror ----------------------------
@@ -566,6 +605,33 @@ def test_native_plan_equals_the_python_walk_writers_who_merge(seed,
                 _commit(sess, plan, model)
     _commit(sess, _plan_both_ways(sess, monkeypatch), model)
     assert "".join(map(chr, model)) == ol.checkout_tip().snapshot()
+
+
+def test_64_way_concurrent_merge_planned_on_the_native_mirror(monkeypatch):
+    """64 agents insert concurrently from the same frontier: one
+    `plan_tail` on the native mirror resolves the whole sibling order
+    (the Python walk agrees, field for field), one `fused_replay`
+    applies it, and the device row is the host oracle's text."""
+    from diamond_types_tpu.obs.phases import PhaseTable
+    ol = _mk_oplog("wide")
+    a0 = ol.get_or_create_agent_id("seed")
+    ol.add_insert(a0, 0, "base ")
+    sess = ff.FusedDocSession(ol, cap=1024, max_ins=4)
+    base = list(ol.version)
+    for k in range(64):
+        ag = ol.get_or_create_agent_id(f"w{k}")
+        ol.add_insert_at(ag, base, 0, f"[{k:02d}]")
+    table = PhaseTable()
+    with table.phase("sched.flush"):
+        plan = _plan_both_ways(sess, monkeypatch)
+    counts = table.snapshot()["phases"]["plan.tail"]["counts"]
+    assert counts["xf_native"] == counts["xf_python"] == 1
+    assert plan.n_ops == 64 and plan.fits(sess.cap)
+    ok, _dev = ff.fused_replay([sess], [plan])
+    assert ok == [True]
+    want = ol.checkout_tip().snapshot()
+    assert len(want) == 5 + 64 * 4 and sess.text() == want
+    assert sess.synced_to == len(ol) and sess.plan_tail().n_ops == 0
 
 
 def test_a_walk_costs_the_push_not_the_document(monkeypatch):

@@ -345,7 +345,7 @@ def test_a_class_goes_out_in_dispatches_of_shards_x_flush_docs(
         ol.doc_id = f"d{i:02d}"
         ol.add_insert_at(ol.get_or_create_agent_id("a"), [], 0, "seed")
     sched = MergeScheduler(2, resolve=lambda d: ols[d], engine="device",
-                           fused=True, fused_opts={"cap": 64, "max_ins": 4},
+                           fused_opts={"cap": 64, "max_ins": 4},
                            flush_docs=4, flush_deadline_s=60.0,
                            flush_workers=False, mesh_window=True,
                            mesh_window_rows=stated,
@@ -401,7 +401,7 @@ def test_a_row_left_off_its_banks_chip_is_counted(monkeypatch):
         ol.doc_id = f"d{i}"
         ol.add_insert_at(ol.get_or_create_agent_id("a"), [], 0, "seed")
     sched = MergeScheduler(SHARDS, resolve=lambda d: ols[d],
-                           engine="device", fused=True,
+                           engine="device",
                            fused_opts={"cap": 64, "max_ins": 4},
                            flush_workers=False, mesh_window=True,
                            place_on_devices=True)
@@ -439,7 +439,7 @@ def test_drain_waits_for_the_pump_threads_window(monkeypatch):
         ol.doc_id = f"d{i}"
         ol.add_insert_at(ol.get_or_create_agent_id("a"), [], 0, "seed")
     sched = MergeScheduler(SHARDS, resolve=lambda d: ols[d],
-                           engine="device", fused=True,
+                           engine="device",
                            fused_opts={"cap": 64, "max_ins": 4},
                            flush_deadline_s=0.01, mesh_window=True,
                            place_on_devices=True)

@@ -430,10 +430,10 @@ def test_obs_watch_convergence_and_devprof_panels(capsys):
         key = obs.journey.begin("w", 1, doc="wdoc")
         obs.journey.stamp(key, "applied_at_peer", peer="peer-9")
         obs.journey.stamp(key, "advert_usable", peer="peer-9")
-        # the PR-13 jit families surface in the device panel
+        # the replay jit families surface in the device panel
         PROFILER.enabled = True
-        note_jit_lookup("xform", True)
-        note_jit_lookup("pallas", False)
+        note_jit_lookup("fused", True)
+        note_jit_lookup("mesh", False)
         from diamond_types_tpu.tools import cli
         rc = cli.main(["obs-watch", addr, "--rounds", "1",
                        "--interval", "0"])
@@ -443,7 +443,8 @@ def test_obs_watch_convergence_and_devprof_panels(capsys):
         assert "lag peer-9" in out
         assert "advert_usable=1" in out
         assert "== device (jit cache) ==" in out
-        assert "xform" in out and "pallas" in out
+        panel = out.split("== device (jit cache) ==")[1]
+        assert "fused " in panel and "mesh " in panel
         assert "visibility_p99" in out
     finally:
         PROFILER.enabled = False

@@ -58,7 +58,6 @@ def _random_edits(ol: OpLog, rng: random.Random, n: int,
 
 def _mk_sched(ols, n_shards, **kw):
     kw.setdefault("engine", "device")
-    kw.setdefault("fused", True)
     kw.setdefault("fused_opts", FUSED_OPTS)
     kw.setdefault("flush_docs", 8)
     kw.setdefault("flush_deadline_s", 10.0)
@@ -240,6 +239,54 @@ def test_cross_shard_poison_isolation(monkeypatch):
 
 
 # ---- dispatch accounting -------------------------------------------------
+
+def test_window_rung_failure_propagates(monkeypatch):
+    """A mesh flush window whose replay raises: counted on the failing
+    class's shard, recorded as rung "mesh", raised once the window is
+    wound up — no quieter path replays the window (not the per-shard
+    `fused_replay`, not the per-doc one), and the host oracle keeps
+    every doc byte-correct."""
+    from diamond_types_tpu.obs import Observability
+    ols = {}
+    sched = _mk_sched(ols, 1, mesh_window=True)
+    sched.attach_obs(Observability())
+    rng = random.Random(37)
+    docs = [f"d{i}" for i in range(4)]
+    quieter = []
+    for rnd in range(3):
+        for d in docs:
+            if rnd == 0:
+                ols[d] = _mk_oplog(d)
+            _random_edits(ols[d], rng, 2)
+            assert sched.submit(d, n_ops=2)["accepted"]
+        if rnd == 2:
+            def boom(*a, **k):
+                raise RuntimeError("injected rung failure")
+            # the window's call-time import re-resolves the attribute
+            monkeypatch.setattr(pm, "mesh_fused_replay", boom)
+            monkeypatch.setattr(
+                ff, "fused_replay",
+                lambda *a, **k: quieter.append("fused") or boom())
+            monkeypatch.setattr(
+                ff.FusedDocSession, "sync",
+                lambda self: quieter.append("per_doc") or boom())
+            with pytest.raises(RuntimeError, match="injected rung"):
+                sched.pump(force=True)
+        else:
+            sched.pump(force=True)
+    monkeypatch.undo()
+    assert quieter == []
+    m = sched.metrics_json()
+    # the failed window is accounted, with no dispatch to its name
+    assert m["window"]["windows"] == 3
+    assert m["window"]["device_windows"] == 1
+    assert m["totals"]["device_errors"] == 1
+    ev = [e for e in sched.obs.recorder.dump()
+          if e["kind"] == "device_error"]
+    assert [e["rung"] for e in ev] == ["mesh"]
+    for d in docs:
+        assert sched.text(d) == ols[d].checkout_tip().snapshot()
+
 
 def test_one_dispatch_per_window_vs_per_shard_control():
     """>= 2 shards' buckets due in one window: the mesh path issues

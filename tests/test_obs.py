@@ -21,6 +21,7 @@ from diamond_types_tpu.obs.prom import (CONTENT_TYPE, escape_label_value,
 from diamond_types_tpu.obs.recorder import FlightRecorder
 from diamond_types_tpu.obs.trace import (NOOP_SPAN, TRACE_HEADER, Tracer,
                                          format_context, parse_header)
+from diamond_types_tpu.serve.metrics import ServeMetrics
 
 pytestmark = pytest.mark.obs
 
@@ -419,7 +420,7 @@ def test_metrics_endpoint_formats_and_debug_events():
             assert r.headers["Content-Type"].startswith(
                 "application/json")
             doc = json.loads(r.read())
-        assert doc["serve"]["version"] == 14
+        assert doc["serve"]["version"] == ServeMetrics.SCHEMA_VERSION
         assert doc["serve"]["latencies"]["flush"]["count"] >= 1
         assert doc["obs"]["trace"]["started"] >= 1
         assert any(row["count"] >= 1

@@ -8,7 +8,14 @@ import os
 import pytest
 
 from diamond_types_tpu.text import ot
-from tests.conftest import reference_path
+from tests.conftest import REFERENCE_DIR, reference_path
+
+if not os.path.isdir(REFERENCE_DIR):
+    # the vectors are the upstream project's files: not in this repo, and
+    # the container cannot fetch them (ROADMAP D12)
+    pytest.skip(f"the reference's golden vectors are absent "
+                f"({REFERENCE_DIR} does not exist)",
+                allow_module_level=True)
 
 DATA = reference_path("test_data", "ot")
 

@@ -24,6 +24,7 @@ from diamond_types_tpu.obs import Observability
 from diamond_types_tpu.obs import phases as phases_mod
 from diamond_types_tpu.obs.phases import NOOP_PHASE, PhaseTable, phase
 from diamond_types_tpu.obs.prom import render_metrics
+from diamond_types_tpu.serve.metrics import ServeMetrics
 
 pytestmark = pytest.mark.obs
 
@@ -419,7 +420,8 @@ def test_exports_metrics_json_obs_snapshot_and_prometheus():
         assert _wait_for(
             lambda: "http.edit" in table.snapshot()["phases"])
         mj = httpd.store.scheduler.metrics_json()
-        assert mj["version"] == 14 and "router_counts" in mj
+        assert mj["version"] == ServeMetrics.SCHEMA_VERSION
+        assert "router_counts" in mj
         assert mj["phases"]["version"] == 1
         assert "http.edit" in mj["phases"]["phases"]
         assert "phases" not in httpd.store.scheduler.metrics.snapshot()

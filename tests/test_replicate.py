@@ -20,6 +20,7 @@ from diamond_types_tpu.replicate.metrics import ReplicationMetrics
 from diamond_types_tpu.replicate.ownership import (ACTIVE, GRANTED,
                                                    RELEASED,
                                                    LeaseManager)
+from diamond_types_tpu.serve.metrics import ServeMetrics
 
 pytestmark = pytest.mark.replicate
 
@@ -450,7 +451,8 @@ def test_two_server_smoke(tmp_path):
             # v3: histogram latencies + derived v2 keys
             assert "handoff" in m["replication"]["latencies"]
             assert m["replication"]["handoffs"]["latency_s_total"] >= 0
-            assert m["serve"]["version"] == 14
+            assert m["serve"]["version"] == \
+                ServeMetrics.SCHEMA_VERSION
             assert m["serve"]["uptime_s"] >= 0
             assert "denied" in m["serve"]["totals"]
             assert "fenced" in m["serve"]["totals"]

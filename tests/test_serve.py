@@ -282,7 +282,7 @@ def test_bank_reads_counted_by_source():
     """bank.text() says where it answered from: the resident device
     session only when it is caught up with the oplog."""
     m = ServeMetrics(1, flush_docs=4, max_pending=16)
-    bank = SessionBank(0, engine="device", metrics=m, fused=True)
+    bank = SessionBank(0, engine="device", metrics=m)
     ol = _mk_oplog("a")
     assert bank.text("a", ol) == "hello"             # no session yet
     assert (m.shard[0]["reads_from_host"],
@@ -300,6 +300,67 @@ def test_bank_reads_counted_by_source():
     host.sync_doc("a", ol)
     assert host.text("a", ol) == "hello!"
     assert m.shard[0]["reads_from_host"] == 3
+
+
+# ---- one flush path: what selects the replay, what is refused -----------------
+
+@pytest.mark.parametrize("kw", ["device_plan", "pallas", "fused",
+                                "session_opts"])
+def test_retired_flush_switches_are_refused(kw):
+    """The switches that selected the retired flush paths are no
+    keywords of the scheduler or the bank: a configuration that still
+    states one fails at boot (`serve()` hands `sched_opts` straight to
+    `MergeScheduler`) and never runs another path silently."""
+    value = {} if kw == "session_opts" else True
+    with pytest.raises(TypeError, match=kw):
+        MergeScheduler(1, resolve={}.__getitem__, engine="host",
+                       **{kw: value})
+    with pytest.raises(TypeError, match=kw):
+        SessionBank(0, engine="host", **{kw: value})
+
+
+@pytest.mark.parametrize("layout", ["per_shard", "mesh_window"])
+def test_the_layout_decides_the_rung(layout, monkeypatch):
+    """Counting stand-ins in both replay functions' places: a one-shard
+    scheduler replays through `fused_replay` only, a four-shard
+    `mesh_window` one through `mesh_fused_replay` only; either way the
+    device rows are the oplogs' text."""
+    from diamond_types_tpu.parallel import mesh as pm
+    from diamond_types_tpu.tpu import flush_fuse as ff
+    calls = {"fused": 0, "mesh": 0}
+    real_fused, real_mesh = ff.fused_replay, pm.mesh_fused_replay
+
+    def fused(sessions, plans):
+        calls["fused"] += 1
+        return real_fused(sessions, plans)
+
+    def mesh(m, sessions, plans):
+        calls["mesh"] += 1
+        return real_mesh(m, sessions, plans)
+
+    monkeypatch.setattr(ff, "fused_replay", fused)
+    monkeypatch.setattr(pm, "mesh_fused_replay", mesh)
+    mesh_window = layout == "mesh_window"
+    ols = {f"d{i}": _mk_oplog(f"d{i}") for i in range(8)}
+    sched = MergeScheduler(4 if mesh_window else 1,
+                           resolve=ols.__getitem__, engine="device",
+                           fused_opts={"cap": 256, "max_ins": 4},
+                           flush_docs=8, flush_deadline_s=10.0,
+                           flush_workers=False, mesh_window=mesh_window)
+    for rnd in range(3):         # round 0 builds the sessions at the tip
+        for d, ol in ols.items():
+            ol.add_insert(ol.get_or_create_agent_id("a"), 0, f"r{rnd} ")
+            assert sched.submit(d, n_ops=1)["accepted"]
+        sched.pump(force=True)
+    assert calls["mesh" if mesh_window else "fused"] >= 2
+    assert calls["fused" if mesh_window else "mesh"] == 0
+    sessions = [s for b in sched.banks for s in b.sessions.values()]
+    assert len(sessions) == len(ols)
+    assert all(isinstance(s, ff.FusedDocSession) for s in sessions)
+    for d, ol in ols.items():
+        assert sched.text(d) == ol.checkout_tip().snapshot()
+    t = sched.metrics_json()["totals"]
+    assert t["reads_from_device"] == len(ols) and t["reads_from_host"] == 0
 
 
 # ---- scheduler (host engine) ----------------------------------------------

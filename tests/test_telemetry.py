@@ -403,7 +403,7 @@ def test_metrics_double_write_into_timeseries():
     # the cumulative tier recorded too (double-write, not a move)
     snap = sm.snapshot()
     assert snap["latencies"]["queue_wait"]["count"] == 1
-    assert snap["version"] == 14
+    assert snap["version"] == ServeMetrics.SCHEMA_VERSION
 
 
 # ---- zero-fill satellite -------------------------------------------------

@@ -420,7 +420,7 @@ def test_metrics_v7_hydration_block_and_prom_families():
     m.record_hydration("evictions_to_snapshot", 3)
     m.observe_cold_start(0.012)
     snap = m.snapshot()
-    assert snap["version"] == 14
+    assert snap["version"] == ServeMetrics.SCHEMA_VERSION
     assert set(HYDRATION_KEYS) <= set(snap["hydration"])
     assert snap["hydration"]["prefetches"] == 1
     assert snap["hydration"]["evictions_to_snapshot"] == 3
