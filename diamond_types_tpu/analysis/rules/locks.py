@@ -155,6 +155,11 @@ def _classify(expr: ast.AST, class_name: str) -> Optional[str]:
         return "leaf"
     if "_first_touch_lock" in src or "_jit_lock" in src:
         return "leaf"
+    # an oplog's native mirror (native/core.py NativeContext): taken
+    # under the oplog guard by every walk and checkout, and alone by
+    # the autosave's encode; nothing is acquired under it
+    if "mirror_lock" in src:
+        return "leaf"
     # live-telemetry tier: the TimeSeries ring guard (`_ts_lock`, also
     # the exemplar store) and the top-K sketch guard (`_sketch_lock`)
     # are leaf rungs — record_*/note() double-writes happen while the

@@ -138,6 +138,22 @@ class _ContentChunk:
         return bytes(body)
 
 
+def encode_mirror(ctx, doc_id: Optional[str],
+                  opts: EncodeOptions = ENCODE_FULL) -> Optional[bytes]:
+    """The full snapshot of what the native mirror `ctx` holds AS IT
+    STANDS: what `encode_oplog` gave at the length the mirror was last
+    synced to, byte for byte. It reads nothing of the Python oplog, so
+    it needs no lock but the mirror's own, which it takes (the
+    autosave's encode, outside `DocStore.lock`). None where these
+    options or this mirror have no native encode: the caller then
+    encodes the oplog itself, under whatever guards it."""
+    if opts.store_deleted_content:
+        return None
+    return ctx.encode_held(doc_id, opts.user_data,
+                           opts.store_inserted_content,
+                           opts.compress_content)
+
+
 def encode_oplog(oplog: OpLog, opts: EncodeOptions = ENCODE_FULL,
                  from_version: Optional[Sequence[int]] = None) -> bytes:
     from_version = sorted(from_version) if from_version else []

@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import ctypes as ct
+import functools
 import sys
 from typing import List, Optional, Sequence, Tuple
 
@@ -208,16 +209,38 @@ def native_available() -> bool:
     return _load() is not None
 
 
+def _mirror_locked(method):
+    """The method runs under the mirror's own lock, so a call and the
+    fetch of its result are one use of the ctx."""
+    @functools.wraps(method)
+    def locked(self, *args, **kwargs):
+        with self.mirror_lock:
+            return method(self, *args, **kwargs)
+    return locked
+
+
 class NativeContext:
     """A C++ mirror of an OpLog's merge-relevant state (graph, agent runs,
     op runs, insert arena), brought up to date lazily, by `sync()`, when
     the oplog has grown. The oplog only appends, so the mirror follows it
     by appending too; it is built whole the first time and wherever a
-    column does not continue what the mirror holds."""
+    column does not continue what the mirror holds.
+
+    The mirror is touched only under its own lock, `mirror_lock`: every
+    public method takes it, and a caller that needs a sequence to be one
+    use (`transform` -> `release_tracker` -> `last_collisions`) holds it
+    across (it is reentrant). Nothing is acquired under it. `sync()`, and
+    every method that syncs first, reads the Python oplog too, so its
+    caller also holds whatever guards that oplog against writers (the
+    server's `DocStore.lock`, taken BEFORE this one, never after);
+    `encode_held` reads the mirror alone and needs this lock only."""
 
     def __init__(self, oplog) -> None:
         lib = _load()
         assert lib is not None
+        from ..analysis.witness import make_lock
+        self.mirror_lock = make_lock("native.mirror", "leaf",
+                                     reentrant=True)
         self._lib = lib
         self._ptr = lib.dt_ctx_new()
         self._built_len = -1
@@ -237,6 +260,7 @@ class NativeContext:
         except Exception:
             pass
 
+    @_mirror_locked
     def sync(self) -> None:
         ol = self._oplog
         if self._built_len == len(ol):
@@ -309,6 +333,7 @@ class NativeContext:
                               + now[3] - r0 + now[4] - held[4])
         return ok
 
+    @_mirror_locked
     def transform(self, from_frontier: Sequence[int],
                   merge_frontier: Sequence[int]):
         """Returns (lv, len, kind, fwd, pos arrays, final_frontier)."""
@@ -337,13 +362,14 @@ class NativeContext:
         frontier = [int(x) for x in fbuf[:k]]
         return lv, ln, kind, fwd, pos, frontier
 
-
+    @_mirror_locked
     def compose_serial(self) -> int:
         """Identity of the current native compose cache (bumped by every
         dt_compose_plan) — the zone packer validates it before packing
         from the cache."""
         return int(self._lib.dt_compose_serial(self._ptr))
 
+    @_mirror_locked
     def zone_ins_runs(self, spans):
         """INS sub-runs of the given spans as (lv0, len, cp) int64
         arrays — prepare_zone's table pass in C++; None on unsupported
@@ -367,6 +393,7 @@ class NativeContext:
             return None
         return lv0[:k], ln[:k], cp[:k]
 
+    @_mirror_locked
     def compose_cache_only(self, spans) -> bool:
         """Run the native composer, leaving results ONLY in the ctx
         cache (no Python column round-trip) — the zone packer reads
@@ -380,6 +407,7 @@ class NativeContext:
             [e for _, e in spans] or [0], dtype=np.int64)
         return self._lib.dt_compose_plan(self._ptr, n, s0, s1) == 0
 
+    @_mirror_locked
     def compose_plan(self, spans):
         """Native zone-engine composer (listmerge/compose.py's hot path in
         C++): compose each entry span into entry-start coordinates.
@@ -449,11 +477,26 @@ class NativeContext:
             odo += ndo
         return out
 
+    @_mirror_locked
     def encode_full(self, doc_id, user_data, store_ins: bool,
                     compress: bool):
-        """Native v1 full-snapshot encode (from_version=[]); None on
-        failure (caller falls back to the Python writer)."""
+        """Native v1 full-snapshot encode (from_version=[]) of the oplog
+        at its tip; None on failure (caller falls back to the Python
+        writer)."""
         self.sync()
+        return self.encode_held(doc_id, user_data, store_ins, compress)
+
+    @_mirror_locked
+    def encode_held(self, doc_id, user_data, store_ins: bool,
+                    compress: bool):
+        """`encode_full` of the mirror AS IT STANDS: no `sync()`, so it
+        reads nothing of the Python oplog and runs with no lock but the
+        mirror's own (the autosave, outside `DocStore.lock`). The mirror
+        is a prefix of an append-only oplog in local-version order, so
+        what it holds is a causally closed oplog. None where the mirror
+        was never built, or on failure."""
+        if self._built_len < 0:
+            return None
         lib = self._lib
         did = doc_id.encode("utf8") if doc_id is not None else None
         n = lib.dt_encode_full(
@@ -466,6 +509,7 @@ class NativeContext:
         lib.dt_encode_fetch(self._ptr, out)
         return out.tobytes()
 
+    @_mirror_locked
     def encode_patch(self, doc_id, user_data, store_ins: bool,
                      compress: bool, from_version):
         """Native v1 patch encode (encode_from; reference:
@@ -485,6 +529,7 @@ class NativeContext:
         lib.dt_encode_fetch(self._ptr, out)
         return out.tobytes()
 
+    @_mirror_locked
     def compose_linear(self, spans):
         """Alive own pieces (lv, len arrays) of a linear-history
         composition over an empty base (assemble_prefix's hot loop), or
@@ -502,15 +547,18 @@ class NativeContext:
             lib.dt_fetch_linear(self._ptr, lv, ln)
         return lv, ln
 
+    @_mirror_locked
     def release_tracker(self) -> None:
         """Free the tracker tables retained for dump_tracker/zone_common."""
         self._lib.dt_release_tracker(self._ptr)
 
+    @_mirror_locked
     def last_collisions(self) -> int:
         """Colliding concurrent inserts during the last transform
         (reference: has_conflicts_when_merging, src/list/merge.rs:51)."""
         return int(self._lib.dt_last_collisions(self._ptr))
 
+    @_mirror_locked
     def zone_common(self):
         """Common-ancestor frontier of the last transform's conflict zone
         (the version whose document the underwater id space tiles)."""
@@ -522,6 +570,7 @@ class NativeContext:
             lib.dt_get_zone_common(self._ptr, buf, k)
         return [int(x) for x in buf[:k]]
 
+    @_mirror_locked
     def dump_tracker(self, keep_underwater: bool = False):
         """Item table of the last transform's tracker, in DOCUMENT order:
         (ids, len, origin_left, origin_right, state, ever) arrays.
@@ -545,6 +594,7 @@ class NativeContext:
                     ev[keep])
         return (ids, ln, ol, orr, st, ev)
 
+    @_mirror_locked
     def dump_del_rows(self):
         """Delete-target rows of the last transform's tracker, sorted by
         op LV: (lv0, lv1, t0, t1, fwd) arrays — op lv0+k deletes item
@@ -564,6 +614,7 @@ class NativeContext:
         o = np.argsort(lv0, kind="stable")
         return lv0[o], lv1[o], t0[o], t1[o], fwd[o]
 
+    @_mirror_locked
     def merge_to_string(self, init: str, from_frontier: Sequence[int],
                         merge_frontier: Sequence[int]):
         """Full native merge: returns (final_doc_str, final_frontier)."""

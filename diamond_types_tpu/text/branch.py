@@ -149,11 +149,12 @@ class Branch:
             from ..native import merge_native
             n_before = _top(self.version)
             t0 = _time.perf_counter()
-            doc, frontier = merge_native(oplog, self.snapshot(),
-                                         self.version, merge_frontier)
+            with ctx.mirror_lock:   # the merge and its collision count
+                doc, frontier = merge_native(oplog, self.snapshot(),
+                                             self.version, merge_frontier)
+                self.last_merge_collisions = ctx.last_collisions()
             self.content = Rope(doc)
             self.version = frontier
-            self.last_merge_collisions = ctx.last_collisions()
             self.last_merge_engine = _policy.TRACKER
             _policy.GLOBAL.record(_policy.TRACKER,
                                   _top(self.version) - n_before,

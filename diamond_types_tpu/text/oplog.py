@@ -250,9 +250,10 @@ class OpLog:
         from ..native import native_ctx_or_none
         ctx = native_ctx_or_none(self)
         if ctx is not None:
-            ctx.transform(frm, merge)
-            ctx.release_tracker()
-            return ctx.last_collisions()
+            with ctx.mirror_lock:   # the three: one use of the mirror
+                ctx.transform(frm, merge)
+                ctx.release_tracker()
+                return ctx.last_collisions()
         xf = self.get_xf_operations_full(frm, merge)
         for _ in xf:
             pass

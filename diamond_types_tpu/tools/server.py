@@ -131,7 +131,9 @@ from typing import Dict, Optional
 
 from ..causalgraph.summary import intersect_with_summary, summarize_versions
 from ..encoding.decode import decode_into, load_oplog
-from ..encoding.encode import ENCODE_FULL, ENCODE_PATCH, encode_oplog
+from ..encoding.encode import (ENCODE_FULL, ENCODE_PATCH, encode_mirror,
+                               encode_oplog)
+from ..native import native_ctx_or_none
 from ..obs.phases import NOOP_PHASE
 from ..obs.trace import TRACE_HEADER, parse_header
 from ..text.oplog import OpLog
@@ -199,9 +201,12 @@ class DocStore:
         self._flusher: Optional[threading.Thread] = None
 
     def start_flusher(self) -> None:
-        """Run autosave on a background thread so the (lock-holding) encode
-        never stalls request handlers (reference: the wiki server's
-        rate-limited autosave is a timer, not inline in handlers)."""
+        """Run autosave on a background thread so a pass never runs
+        inline in a request handler (reference: the wiki server's
+        rate-limited autosave is a timer, not inline in handlers). A
+        pass holds the store lock once, to fix what it saves, and
+        encodes each document's native mirror outside it, under the
+        mirror's own lock (`_flush_pass`)."""
         if self.data_dir is None or self._flusher is not None:
             return
 
@@ -306,9 +311,13 @@ class DocStore:
             self._flush_pass(force, ph)
 
     def _flush_pass(self, force: bool, ph) -> None:
-        """One autosave pass; `ph` is its `autosave.pass` phase: the
-        encode under the store lock (the wait for it included) and the
-        file loop are its two steps."""
+        """One autosave pass; `ph` is its `autosave.pass` phase. Step
+        `autosave.encode`: ONE hold of the store lock (the wait for it
+        included) fixes what the pass saves (which documents are due,
+        their dirty flags cleared, each one's native mirror brought to
+        the tip by appending), then every mirror is encoded as it
+        stands with the store lock free. Step `autosave.write`: the
+        file loop."""
         os.makedirs(self.data_dir, exist_ok=True)
         now = time.monotonic()
         # io_lock serializes whole flush passes: without it, a flusher
@@ -316,10 +325,18 @@ class DocStore:
         # concurrent flush(force=True) (e.g. server_close) with its stale
         # blob after the dirty flag was already cleared.
         with self.io_lock:
-            # Encode UNDER the store lock (/push and /edit mutate oplogs
-            # under it; an encode racing a mutation could crash or persist
-            # a torn snapshot); only the disk write happens outside it.
-            blobs = []
+            # /push and /edit mutate oplogs under the store lock, so
+            # whatever reads a Python oplog does so under it: the
+            # mirror's sync(), and the whole encode of a document with
+            # no mirror. A mirror is touched only under its own lock
+            # (native/core.py), and it is a prefix of an append-only
+            # oplog in local-version order: encoded outside the store
+            # lock it gives a causally closed snapshot no older than
+            # the version its flag was cleared at. A plan walk that
+            # appends to it first only makes the file newer, and the
+            # edit behind that append has set the flag again.
+            blobs = []      # (doc, bytes): encoded under the store lock
+            fixed = []      # (doc, oplog, doc_id, mirror): to encode
             ph.step("autosave.encode")
             with self.lock:
                 due = [d for d, t in self.dirty.items()
@@ -330,20 +347,25 @@ class DocStore:
                     if ol is None:
                         continue
                     try:
-                        blobs.append((d, encode_oplog(ol, ENCODE_FULL)))
+                        ctx = _mirror_at_tip(ol)
+                        if ctx is None:
+                            blobs.append((d, encode_oplog(ol, ENCODE_FULL)))
+                        else:
+                            fixed.append((d, ol, ol.doc_id, ctx))
                     except Exception:
-                        # One unencodable doc (e.g. poisoned before input
-                        # validation existed) must not abort the pass and
-                        # silently drop OTHER docs' dirty flags; re-mark
-                        # it so the failure stays visible to retries —
-                        # but with exponential backoff (cap 10 min) and
-                        # the full traceback only on the FIRST failure,
-                        # so a persistently-broken doc degrades to one
-                        # retry per backoff window instead of stderr spam
-                        # on every pass.
-                        if self._note_flush_failure(d, now, "encode") == 1:
-                            import traceback
-                            traceback.print_exc()
+                        self._encode_failed(d, now)
+            locked = len(blobs)
+            for d, ol, doc_id, ctx in fixed:
+                try:
+                    blob = encode_mirror(ctx, doc_id)
+                    if blob is None:    # no native encode after all
+                        with self.lock:
+                            blob = encode_oplog(ol, ENCODE_FULL)
+                        locked += 1
+                    blobs.append((d, blob))
+                except Exception:
+                    with self.lock:
+                        self._encode_failed(d, now)
             # Disk writes get the SAME per-doc failure handling: an
             # ENOSPC/EIO on one doc's tmp file must not abort the loop
             # and silently drop the remaining docs' (already-cleared)
@@ -351,6 +373,9 @@ class DocStore:
             # persisted again.
             ph.step("autosave.write")
             ph.count("docs", len(blobs))
+            ph.count("docs_unlocked", len(blobs) - locked)
+            ph.count("docs_locked", locked)
+            saved = []
             for doc_id, blob in blobs:
                 path = self._path(doc_id)
                 tmp = path + ".tmp"
@@ -358,18 +383,36 @@ class DocStore:
                     with open(tmp, "wb") as f:
                         f.write(blob)
                     os.replace(tmp, path)  # atomic
-                    # persistence truly completed: only now is the
-                    # consecutive-failure streak over (clearing on encode
-                    # success would reset a write-failure backoff every
-                    # pass and bring back the per-pass log spam)
-                    with self.lock:
-                        self.flush_failures.pop(doc_id, None)
+                    saved.append(doc_id)
                     if self.obs is not None:
                         self.obs.journey.stamp_doc(doc_id,
                                                    "wal_durable")
                 except OSError:
                     with self.lock:
                         self._note_flush_failure(doc_id, now, "write")
+            # persistence truly completed: only now is a document's
+            # consecutive-failure streak over (clearing on encode
+            # success would reset a write-failure backoff every pass
+            # and bring back the per-pass log spam). Once a pass, and
+            # only where some document has a streak at all: the lock is
+            # not taken again after every file.
+            if self.flush_failures:
+                with self.lock:
+                    for doc_id in saved:
+                        self.flush_failures.pop(doc_id, None)
+
+    def _encode_failed(self, d: str, now: float) -> None:
+        """One document's encode failure (caller holds self.lock and is
+        inside the `except` block). One unencodable doc (e.g. poisoned
+        before input validation existed) must not abort the pass and
+        silently drop OTHER docs' dirty flags; it is re-marked so the
+        failure stays visible to retries, with exponential backoff (cap
+        10 min) and the full traceback only on the FIRST failure, so a
+        persistently-broken doc degrades to one retry per backoff
+        window instead of stderr spam on every pass."""
+        if self._note_flush_failure(d, now, "encode") == 1:
+            import traceback
+            traceback.print_exc()
 
     def _note_flush_failure(self, d: str, now: float, stage: str) -> int:
         """Record one flush failure for doc `d` (caller holds self.lock
@@ -399,6 +442,19 @@ class DocStore:
             # retry); that timestamp must win over the backoff re-mark
             self.dirty[d] = now + backoff - self.save_interval
         return fails
+
+
+def _mirror_at_tip(ol):
+    """The native mirror of `ol` brought to its tip, or None where it
+    has none: something that is no `OpLog`, `DT_TPU_NO_NATIVE`, a
+    library that did not load. The caller holds the store lock, which
+    `sync()` needs because it reads the Python oplog."""
+    if not isinstance(ol, OpLog):
+        return None
+    ctx = native_ctx_or_none(ol)
+    if ctx is not None:
+        ctx.sync()
+    return ctx
 
 
 def _utf8_clean(s: str) -> bool:
@@ -1487,10 +1543,12 @@ class SyncHandler(BaseHTTPRequestHandler):
             req = json.loads(body or b"{}")
             n = min(max(_ix(req.get("n", 16)), 1), 64)
             # Under the store lock like every other checkout endpoint:
-            # checkouts share the per-oplog native context, and a
-            # concurrent push rebuilding that context mid-call would be a
-            # use-after-free. (Host strips are a few hundred ms worst
-            # case; the device path is opt-in — see doc_history_strip.)
+            # a checkout reads the Python oplog, which a concurrent
+            # push mutates, and brings the oplog's native mirror to the
+            # tip from it (`sync()`). The mirror itself is guarded by
+            # its own lock, not by this one (native/core.py). (Host
+            # strips are a few hundred ms worst case; the device path
+            # is opt-in — see doc_history_strip.)
             with self.store.lock:
                 snaps = doc_history_strip(ol, n, list(ol.version))
             return self._send(200, json.dumps({"snapshots": snaps})

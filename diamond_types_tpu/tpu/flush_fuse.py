@@ -332,12 +332,23 @@ class FusedDocSession:
             pieces = _walk_pieces(ol, ph.timed(xf, "plan.xf"))
         else:
             ph.count("xf_native")
-            grew = (ctx.appended, ctx.rebuilt)
-            lv, ln, kind, fwd, pos, frontier = ctx.transform(
-                self.frontier, ol.version)
-            ctx.release_tracker()
-            ph.count("mirror_appended", ctx.appended - grew[0])
-            ph.count("mirror_rebuilt", ctx.rebuilt - grew[1])
+            # the mirror's own lock across the walk: the one other
+            # holder is an autosave encoding THIS document's mirror
+            # outside the store lock, and the walk waits that one
+            # encode out
+            busy = not ctx.mirror_lock.acquire(blocking=False)
+            if busy:
+                ctx.mirror_lock.acquire()
+            try:
+                grew = (ctx.appended, ctx.rebuilt)
+                lv, ln, kind, fwd, pos, frontier = ctx.transform(
+                    self.frontier, ol.version)
+                ctx.release_tracker()
+                ph.count("mirror_appended", ctx.appended - grew[0])
+                ph.count("mirror_rebuilt", ctx.rebuilt - grew[1])
+            finally:
+                ctx.mirror_lock.release()
+            ph.count("mirror_busy_waits", int(busy))
             ph.step("plan.rows")
             pieces = _native_pieces(ol, lv.tolist(), ln.tolist(),
                                     kind.tolist(), fwd.tolist(),

@@ -75,19 +75,22 @@ def prepare_doc(oplog, from_frontier: Sequence[int] = (),
     frm = [int(x) for x in from_frontier]
     merge = ([int(x) for x in oplog.version] if merge_frontier is None
              else [int(x) for x in merge_frontier])
-    *_rest, union = ctx.transform(frm, merge)
-    ids, ln, ol, orr, st, ev = ctx.dump_tracker(keep_underwater=True)
-    common = ctx.zone_common()
+    with ctx.mirror_lock:   # the transform, its tracker's dump, the prefix
+        *_rest, union = ctx.transform(frm, merge)
+        ids, ln, ol, orr, st, ev = ctx.dump_tracker(keep_underwater=True)
+        common = ctx.zone_common()
+        # The underwater id space tiles the document at the conflict
+        # zone's COMMON ANCESTOR (the version the tracker's walk starts
+        # from) — NOT at [min insert id - 1]: zone ops that are pure
+        # deletes toggle underwater text without creating tracker items.
+        # With no conflict zone at all the prefix is the whole document.
+        at = union if len(ids) == 0 else common
+        prefix = ctx.merge_to_string("", [], at)[0] if at else ""
+        ctx.release_tracker()  # the dump above is all we needed
 
-    # The underwater id space tiles the document at the conflict zone's
-    # COMMON ANCESTOR (the version the tracker's walk starts from) — NOT
-    # at [min insert id - 1]: zone ops that are pure deletes toggle
-    # underwater text without creating tracker items.
     if len(ids) == 0:
         # no conflict zone at all (purely linear history): the document is
         # the fast-forward result; model it as one visible pseudo-run
-        prefix, _ = ctx.merge_to_string("", [], union)
-        ctx.release_tracker()
         arr = np.frombuffer(prefix.encode("utf-32-le"), dtype=np.int32)
         n = 1
         return DeviceDoc(
@@ -100,11 +103,6 @@ def prepare_doc(oplog, from_frontier: Sequence[int] = (),
             char_off=np.zeros(n, dtype=np.int32),
             chars=arr if len(arr) else np.zeros(1, np.int32),
             total_len=len(arr), frontier=union)
-    if common:
-        prefix, _ = ctx.merge_to_string("", [], common)
-    else:
-        prefix = ""
-    ctx.release_tracker()  # the dump above is all we needed
     prefix_arr = np.frombuffer(prefix.encode("utf-32-le"), dtype=np.int32)
     plen = len(prefix_arr)
 

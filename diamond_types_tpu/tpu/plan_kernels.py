@@ -107,14 +107,15 @@ def source_native(oplog, plan: MergePlan2, from_frontier,
     from ..text.op import INS
 
     ctx = get_native_ctx(oplog)
-    ctx.transform([int(x) for x in from_frontier],
-                  [int(x) for x in merge_frontier])
-    common = ctx.zone_common()
-    assert sorted(common) == sorted(plan.common), \
-        "native transform and plan disagree on the conflict zone"
-    ids, lens, *_rest = ctx.dump_tracker(keep_underwater=True)
-    lv0, lv1, t0, t1, fwd = ctx.dump_del_rows()
-    ctx.release_tracker()
+    with ctx.mirror_lock:   # the transform and the dumps of its tracker
+        ctx.transform([int(x) for x in from_frontier],
+                      [int(x) for x in merge_frontier])
+        common = ctx.zone_common()
+        assert sorted(common) == sorted(plan.common), \
+            "native transform and plan disagree on the conflict zone"
+        ids, lens, *_rest = ctx.dump_tracker(keep_underwater=True)
+        lv0, lv1, t0, t1, fwd = ctx.dump_del_rows()
+        ctx.release_tracker()
 
     journal = []
     bounds = set()

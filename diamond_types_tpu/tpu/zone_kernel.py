@@ -247,6 +247,13 @@ def _pack_native(prep: ZonePrep, MB: int, MC: int, MD: int):
     lib = ctx._lib
     if not hasattr(lib, "dt_zone_pack"):
         return None
+    with ctx.mirror_lock:   # the pack and the fetch of its steps: one use
+        return _pack_native_held(prep, lib, ctx, MB, MC, MD)
+
+
+def _pack_native_held(prep: ZonePrep, lib, ctx, MB: int, MC: int,
+                      MD: int):
+    """`_pack_native` under the mirror's lock."""
     n = len(prep.plan.entries)
 
     acts = prep.plan.actions

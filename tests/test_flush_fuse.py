@@ -668,4 +668,4 @@ def test_a_walk_costs_the_push_not_the_document(monkeypatch):
     assert (ctx.appended, ctx.rebuilt) == (202, 1)
     counts = table.snapshot()["phases"]["plan.tail"]["counts"]
     assert counts == {"xf_native": 202, "mirror_appended": 202,
-                      "mirror_rebuilt": 0}
+                      "mirror_rebuilt": 0, "mirror_busy_waits": 0}

@@ -386,9 +386,11 @@ def test_an_edit_and_a_get_leave_their_parts(tmp_path):
         assert ph["get.checkout"]["count"] == ph["get.respond"]["count"] == 1
         assert ph["http.accept_wait"]["count"] >= 6
         assert httpd.accepted_at == {}
-        # the autosave pass: encode under the lock, then the file loop
+        # the autosave pass: what it saves fixed under one hold of the
+        # lock and encoded outside it, then the file loop
         assert ph["autosave.pass"]["count"] >= 1
-        assert ph["autosave.pass"]["counts"] == {"docs": 1}
+        assert ph["autosave.pass"]["counts"] == {
+            "docs": 1, "docs_unlocked": 1, "docs_locked": 0}
         assert ph["autosave.encode"]["count"] == ph["autosave.write"]["count"]
         # the flush path's root and what hangs under it
         assert ph["sched.flush"]["count"] >= 1
@@ -408,6 +410,41 @@ def test_an_edit_and_a_get_leave_their_parts(tmp_path):
         assert type(httpd.store.scheduler.lock) is witness.WitnessLock
         total_hold = sum(c["hold_s"] for c in sites.values())
         assert 0 < total_hold < 60
+    finally:
+        _stop(httpd)
+
+
+def test_an_autosave_pass_holds_the_store_lock_once_at_its_encode(tmp_path):
+    """A pass fixes what it saves under ONE hold of the store lock,
+    filed under the site `autosave.encode` whatever the number of
+    documents, and encodes them outside it; its root says how many it
+    encoded where."""
+    httpd, addr = _serve(data_dir=str(tmp_path))
+    try:
+        store = httpd.store
+        store.stop_flusher()            # the one pass is this test's
+        for i in range(6):
+            _edit(addr, f"d{i}", text="hello")
+        table = store.obs.phases
+        assert _wait_for(lambda: table.snapshot()["phases"].get(
+            "http.edit", {}).get("count") == 6)
+        store.scheduler.drain()
+        assert "autosave.pass" not in table.snapshot()["phases"]
+        store.flush(force=True)
+        snap = table.snapshot()
+        ph, sites = snap["phases"], snap["locks"]["store.oplog"]
+        assert ph["autosave.pass"]["count"] == 1
+        assert ph["autosave.pass"]["counts"] == {
+            "docs": 6, "docs_unlocked": 6, "docs_locked": 0}
+        assert sites["autosave.encode"]["acquires"] == 1
+        assert 0 < sites["autosave.encode"]["hold_s"] \
+            <= ph["autosave.encode"]["sum_s"]
+        # no file's write took the lock: no document had a failure
+        # streak to end
+        assert "autosave.write" not in sites
+        assert ph["autosave.pass"]["lock_wait_s"] \
+            == ph["autosave.encode"]["lock_wait_s"]
+        assert len(list(tmp_path.glob("*.dt"))) == 6
     finally:
         _stop(httpd)
 
@@ -734,7 +771,8 @@ def test_plan_tail_and_fused_replay_report_their_steps(engine, monkeypatch):
     # which engine walked and, for the native one, how its mirror
     # followed the oplog: the walk outside the root had synced it
     assert ph.pop("plan.tail")["counts"] == (
-        {"xf_native": 1, "mirror_appended": 0, "mirror_rebuilt": 0}
+        {"xf_native": 1, "mirror_appended": 0, "mirror_rebuilt": 0,
+         "mirror_busy_waits": 0}
         if engine == "native" else {"xf_python": 1})
     assert all("counts" not in row for row in ph.values())
     ph = table.snapshot()["phases"]

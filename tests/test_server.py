@@ -221,9 +221,12 @@ def test_edit_endpoint_rejects_bad_ops(tmp_path):
 
 
 def test_flush_races_concurrent_edits(tmp_path):
-    """Autosave encoding must run under the store lock: hammer /edit from
-    two threads while forcing flushes; the persisted .dt must always load
-    (ADVICE r2 medium: flush() used to encode outside the lock)."""
+    """Whatever the autosave reads of a Python oplog it must read under
+    the store lock (the mirror's sync(), the whole encode of a document
+    with no mirror); a native mirror is encoded outside it, under the
+    mirror's own lock. Hammer /edit from two threads while forcing
+    flushes; the persisted .dt must always load (ADVICE r2 medium:
+    flush() used to encode the live oplog outside the lock)."""
     from diamond_types_tpu.encoding.decode import load_oplog
     httpd = serve(port=0, data_dir=str(tmp_path))
     store = httpd.RequestHandlerClass.store
