@@ -118,6 +118,8 @@ fails at start-up (tpu/runtime.py) unless the environment named cpu.
 from __future__ import annotations
 
 import argparse
+import email.parser
+import http.client
 import json
 import os
 import re
@@ -126,6 +128,7 @@ import threading
 import time
 import urllib.parse
 import urllib.request
+from http import HTTPStatus
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Dict, Optional
 
@@ -683,21 +686,162 @@ def _parse_frontier_token(tok: str):
     return out
 
 
+class _Headers:
+    """A request's headers as the lean parser read them: the value of
+    the first line of each name, whatever the case it is asked in, and
+    None for a name that was not sent, as `email.message.Message`
+    answers `.get`, `[...]` and `in`."""
+
+    __slots__ = ("_first",)
+
+    def __init__(self, first: Dict[str, str]) -> None:
+        self._first = first         # lower-cased name -> value
+
+    def get(self, name: str, default=None):
+        return self._first.get(name.lower(), default)
+
+    def __getitem__(self, name: str):
+        return self._first.get(name.lower())
+
+    def __contains__(self, name: str) -> bool:
+        return name.lower() in self._first
+
+
+# the stdlib's own limits on a header block (`http.client`): beyond
+# either the answer is its 431
+_MAX_LINE = http.client._MAXLINE
+_MAX_HEADERS = http.client._MAXHEADERS
+# header names the lean parser hands to the stdlib's: each changes how
+# the body is read or asks for an interim response
+_STDLIB_NAMES = ("expect", "transfer-encoding")
+
+
 class SyncHandler(BaseHTTPRequestHandler):
     store: DocStore = None  # class attr, set by serve()
+    # which parser took the request, "lean" or "stdlib": counted on the
+    # request's root phase
+    _parsed = "stdlib"
+    # (protocol version, code) -> status line; (second, `Server` and
+    # `Date` lines): a response formats neither
+    _status_lines: Dict[tuple, bytes] = {}
+    _dated = (0, b"")
 
     def log_message(self, *a):  # quiet
         pass
 
+    def parse_request(self) -> bool:
+        """`BaseHTTPRequestHandler.parse_request` for the requests this
+        server is sent, in one pass over the request line and the
+        header lines and with no `email.parser`: what it sets
+        (`command`, `path`, `request_version`, `requestline`,
+        `close_connection`, `headers`) is what the stdlib would have
+        set from the same bytes. What it cannot take goes the stdlib's
+        way, told from those bytes alone: a request line that is not
+        three words ending in HTTP/1.0 or HTTP/1.1, before a header is
+        read; a header block with a line `email.parser` would not read
+        as one whole `name: value` (folded, no colon, a name with a
+        blank or a control in it, a bare CR), or one that names
+        `Expect` or `Transfer-Encoding`, once its lines are read."""
+        self._parsed = "stdlib"
+        requestline = str(self.raw_requestline, "iso-8859-1").rstrip("\r\n")
+        words = requestline.split()
+        if len(words) != 3 or words[2] not in ("HTTP/1.0", "HTTP/1.1"):
+            return super().parse_request()
+        self.requestline = requestline
+        self.command, path, self.request_version = words
+        # gh-87389, as the stdlib: `//host` must not read as a URI
+        self.path = "/" + path.lstrip("/") if path.startswith("//") else path
+        self.close_connection = not (words[2] == "HTTP/1.1"
+                                     and self.protocol_version >= "HTTP/1.1")
+        lines = []
+        first: Dict[str, str] = {}
+        lean = True
+        readline = self.rfile.readline
+        while True:
+            line = readline(_MAX_LINE + 1)
+            if len(line) > _MAX_LINE:
+                self.send_error(
+                    HTTPStatus.REQUEST_HEADER_FIELDS_TOO_LARGE,
+                    "Line too long",
+                    str(http.client.LineTooLong("header line")))
+                return False
+            lines.append(line)
+            if len(lines) > _MAX_HEADERS:
+                self.send_error(
+                    HTTPStatus.REQUEST_HEADER_FIELDS_TOO_LARGE,
+                    "Too many headers",
+                    "got more than %d headers" % _MAX_HEADERS)
+                return False
+            if line in (b"\r\n", b"\n", b""):
+                break
+            if lean:
+                name, colon, value = line.decode("iso-8859-1").removesuffix(
+                    "\n").removesuffix("\r").partition(":")
+                key = name.lower()
+                # a name is `email.feedparser`'s: printable ASCII with
+                # no blank, so a folded line fails here too
+                lean = bool(colon and name and name.isascii()
+                            and name.isprintable() and " " not in name
+                            and "\r" not in value
+                            and key not in _STDLIB_NAMES)
+                if lean:
+                    first.setdefault(key, value.lstrip(" \t"))
+        if lean:
+            self._parsed = "lean"
+            self.headers = _Headers(first)
+        else:
+            # as `http.client.parse_headers` on the same lines
+            self.headers = email.parser.Parser(
+                _class=self.MessageClass).parsestr(
+                    b"".join(lines).decode("iso-8859-1"))
+        conntype = self.headers.get("Connection", "").lower()
+        if conntype == "close":
+            self.close_connection = True
+        elif conntype == "keep-alive" \
+                and self.protocol_version >= "HTTP/1.1":
+            self.close_connection = False
+        if (self.headers.get("Expect", "").lower() == "100-continue"
+                and self.protocol_version >= "HTTP/1.1"
+                and self.request_version >= "HTTP/1.1"):
+            return self.handle_expect_100()
+        return True
+
     def _send(self, code: int, body: bytes, ctype: str = "application/json",
               extra: Optional[dict] = None):
-        self.send_response(code)
-        self.send_header("Content-Type", ctype)
-        self.send_header("Content-Length", str(len(body)))
-        for k, v in (extra or {}).items():
-            self.send_header(k, v)
-        self.end_headers()
-        self.wfile.write(body)
+        """The whole response in one `sendall`: the status line,
+        `Server`, `Date` (formatted once a second), `Content-Type`,
+        `Content-Length`, then `extra` in order, as `send_response` /
+        `send_header` / `end_headers` would have written them, and the
+        body behind them. HTTP/0.9 is answered with the body alone, as
+        those writers do."""
+        if self.request_version == "HTTP/0.9":
+            head = b""
+        else:
+            head = self._head(code, ctype, len(body), extra)
+        self.wfile.write(head + body)
+
+    def _head(self, code: int, ctype: str, length: int,
+              extra: Optional[dict]) -> bytes:
+        key = (self.protocol_version, code)
+        status = self._status_lines.get(key)
+        if status is None:
+            status = self._status_lines[key] = ("%s %d %s\r\n" % (
+                self.protocol_version, code,
+                self.responses[code][0] if code in self.responses else "")
+                ).encode("latin-1", "strict")
+        now = int(time.time())
+        second, dated = self._dated
+        if second != now:
+            dated = ("Server: %s\r\nDate: %s\r\n" % (
+                self.version_string(), self.date_time_string(now))
+                ).encode("latin-1", "strict")
+            type(self)._dated = (now, dated)
+        rest = "Content-Type: %s\r\nContent-Length: %d\r\n" % (
+            ctype, length)
+        if extra:
+            rest += "".join("%s: %s\r\n" % kv for kv in extra.items())
+        return b"".join((status, dated,
+                         rest.encode("latin-1", "strict"), b"\r\n"))
 
     def _wire(self):
         """This node's WireChannel, or None when replication is off
@@ -782,6 +926,7 @@ class SyncHandler(BaseHTTPRequestHandler):
         slow = None if action == "changes" else {
             "doc": doc_id, "accept_wait_ms": round((wait or 0.0) * 1e3, 3)}
         ph = obs.phases.phase(name, slow=slow)
+        ph.count(self._parsed)
         if wait is not None:
             ph.note("http.accept_wait", wait)   # written with the root
         return ph
