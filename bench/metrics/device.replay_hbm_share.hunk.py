@@ -1,0 +1,3 @@
+"""`a2-sources.hunk-sat`'s share of the HBM roofline the replays reached: one
+reader for both block-edit cells, in bench/block.py."""
+from bench.block import replay_hbm_share as read  # noqa: F401

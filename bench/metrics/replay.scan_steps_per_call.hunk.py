@@ -1,0 +1,3 @@
+"""Scan steps a `fused_replay` call was padded to in `a2-sources.hunk-sat`
+(bench/block.py)."""
+from bench.block import scan_steps_per_call as read  # noqa: F401

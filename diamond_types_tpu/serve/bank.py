@@ -10,8 +10,10 @@ eviction/resync machinery the multichip dryrun proved out
   * `max_slots`    — total device-slot footprint (sum of each session's
                      `footprint_slots()`, dominated by the W_cap x
                      n_rows state matrix) stays under a VMEM-shaped
-                     budget. A session that GROWS past the budget on
-                     resync evicts its least-recently-used neighbors.
+                     budget. A session whose tail outgrows its
+                     capacity class GROWS on the device, and past the
+                     budget it evicts its least-recently-used neighbors
+                     first (`before_growth`, set in `_build`).
 
 Eviction drops the device carry; the document itself lives in its host
 OpLog, so an evicted doc costs one rebuild (resync) on its next merge —
@@ -33,8 +35,10 @@ The flush path of a device bank is three steps, one way:
 
   1. plan   — `_plan_fused`: build or find each doc's session, pack its
               pending tail (`FusedDocSession.plan_tail`, the native
-              mirror's transform) and group the sessions whose tails
-              fit by (cap, max_ins).
+              mirror's transform), grow on the device each session
+              whose tail overflows its capacity class
+              (`FusedDocSession.make_room`) and group the sessions by
+              (cap, max_ins).
   2. replay — one `flush_fuse.fused_replay` call a group on the shard's
               own chip (`sync_docs`); where the scheduler runs mesh
               windows it replays the groups of every shard itself with
@@ -42,9 +46,9 @@ The flush path of a device bank is three steps, one way:
               `adopt_window` are the two halves it calls).
   3. adopt  — `adopt_window`: a poisoned or mismatched length evicts
               the session to the host oracle; what could not be grouped
-              (capacity eviction mid-batch, a tail that overflows its
-              buffer, a bucket with fewer than two docs to group) goes
-              through `sync_doc` one doc at a time.
+              (capacity eviction mid-batch, a bucket with fewer than
+              two docs to group) goes through `sync_doc` one doc at a
+              time.
 
 Locking contract for `sync_docs`: `oplog_lock` (the scheduler's
 narrowed sync lock — e.g. DocStore.lock) is held only around the
@@ -61,6 +65,7 @@ the process really has the device it was asked to use.
 from __future__ import annotations
 
 import contextlib
+import functools
 import threading
 import time
 from collections import OrderedDict
@@ -122,6 +127,9 @@ class SessionBank:
         self.mesh_shards = int(mesh_shards)
         self.sessions: "OrderedDict[str, object]" = OrderedDict()
         self._resyncs_seen: Dict[str, int] = {}
+        # capacity classes this bank has built a session of: their
+        # copy programs are compiled (`_warm_growth`)
+        self._classes_built: set = set()
         # obs.recorder.FlightRecorder (MergeScheduler.attach_obs);
         # evictions and fallbacks are rare enough to record each one
         self.recorder = None
@@ -277,9 +285,32 @@ class SessionBank:
         from ..tpu.flush_fuse import FusedDocSession
         with self._on_device():
             sess = FusedDocSession(oplog, **self.fused_opts)
+            self._warm_growth(sess.cap)
+        # the slots a growth adds are found BEFORE the copy, at the cost
+        # of other documents and never of this one, so the budget holds
+        # while both rows live: by `_plan_fused` itself on the fused
+        # path, through this on the per-doc ladder (`sess.sync()`)
+        sess.before_growth = functools.partial(self._evict_until_fits,
+                                               keep=doc_id)
         # the initial build counts as this doc's baseline, not a resync
         self._resyncs_seen[doc_id] = sess.resyncs
         return sess
+
+    def _warm_growth(self, cap: int) -> None:
+        """At the first session this bank builds in capacity class
+        `cap`: compile the copy programs a growth out of or into that
+        class can need, to the next class up and between it and every
+        class built before, so that a session which outgrows its class
+        under traffic compiles nothing on the flush path. Runs on the
+        bank's chip, as the growth will."""
+        if cap in self._classes_built:
+            return
+        from ..tpu.flush_fuse import warm_grow
+        pairs = {(cap, 2 * cap)} | {(min(cap, c), max(cap, c))
+                                    for c in self._classes_built}
+        self._classes_built.add(cap)
+        for lo, hi in sorted(pairs):
+            warm_grow(lo, hi)
 
     def session(self, doc_id: str, oplog):
         """Get-or-build the doc's resident session, updating LRU order
@@ -516,24 +547,25 @@ class SessionBank:
     def _plan_fused(self, items, ols, olock, min_fuse: int = 2):
         """Host-side phase of the flush, one pass under one hold of
         `olock`: get/build each doc's session, plan its tail, and group
-        the sessions to replay by (cap, max_ins). Anything that can't
-        be grouped — overflowing tail, LRU-evicted mid-batch, a bucket
-        with fewer than `min_fuse` docs to replay — lands in the serial
-        list."""
+        the sessions to replay by (cap, max_ins). A session whose tail
+        overflows its capacity class grows on the device first and is
+        grouped by its new class: its slots are found under that hold,
+        the copy runs once the guard is released (no device work under
+        it), and the grouping then takes a second hold. Anything that
+        can't be grouped — LRU-evicted mid-batch, a growth the device
+        refused, a bucket with fewer than `min_fuse` docs to replay —
+        lands in the serial list."""
         serial = []
         fusable: List[tuple] = []    # (sess, plan, doc_id)
-        with olock:
-            planned = []
-            for it in items:
-                # a build failure is counted in session() and raises
-                sess = self.session(it.doc_id, ols[it.doc_id])
-                planned.append((it, sess, sess.plan_tail()))
+
+        def sort_planned() -> None:
             for it, sess, plan in planned:
-                if not plan.fits(sess.cap):
-                    serial.append(it)   # overflow -> per-doc resync
-                # building session N can LRU-evict already-planned M:
-                # only still-resident sessions may commit device state
-                elif self.sessions.get(it.doc_id) is not sess:
+                # building or growing session N can LRU-evict
+                # already-planned M: only still-resident sessions may
+                # commit device state. A tail that still does not fit
+                # takes the per-doc path, whose growth may rebuild
+                if self.sessions.get(it.doc_id) is not sess \
+                        or not plan.fits(sess.cap):
                     serial.append(it)
                 elif plan.n_ops == 0:
                     # frontier advance with no visible ops (e.g. a
@@ -542,6 +574,30 @@ class SessionBank:
                     self._bump("syncs")
                 else:
                     fusable.append((sess, plan, it.doc_id))
+
+        with olock:
+            planned, growing, short = [], [], 0
+            for it in items:
+                # a build failure is counted in session() and raises
+                sess = self.session(it.doc_id, ols[it.doc_id])
+                plan = sess.plan_tail()
+                if not plan.fits(sess.cap):
+                    # with the slots of this batch's earlier growers,
+                    # whose copies are still to come
+                    short += sess.slots_short(plan)
+                    self._evict_until_fits(incoming_slots=short,
+                                           keep=it.doc_id)
+                    growing.append((it.doc_id, sess, plan))
+                planned.append((it, sess, plan))
+            if not growing:
+                sort_planned()
+        if growing:
+            with self._on_device():
+                for doc_id, sess, plan in growing:
+                    if self.sessions.get(doc_id) is sess:
+                        sess.make_room(plan, rebuild=False)
+            with olock:
+                sort_planned()
         if len(fusable) < min_fuse:
             # below min_fuse the per-doc path amortizes nothing on the
             # per-shard path (the mesh coordinator passes min_fuse=1:

@@ -126,6 +126,47 @@ def _fused_fn(b: int, n: int, mi: int, cap: int):
     return fn
 
 
+_grow_fns = {}
+
+
+def _grow_fn(cap: int, cap2: int):
+    """The copy program of one (cap, cap2) pair of capacity classes: a
+    resident `[cap]` row into a zeroed `[cap2]` row, on the device the
+    row lives on. Its name (`jit_dt_grow`) and its scope are what a
+    profiler trace shows of a growth."""
+    import jax
+
+    key = (cap, cap2)
+    with _fused_jit_lock:
+        fn = _grow_fns.get(key)
+        if fn is None:
+            import jax.numpy as jnp
+
+            def dt_grow(row):
+                with jax.named_scope("dt.grow"):
+                    return jnp.pad(row, (0, cap2 - cap))
+
+            fn = _grow_fns[key] = jax.jit(dt_grow)
+    return fn
+
+
+def warm_grow(cap: int, cap2: int) -> None:
+    """Compile the copy from capacity class `cap` to `cap2` by running
+    it on an empty row, on the default device: a bank calls this when
+    it builds its first session of a class, so that a session which
+    outgrows its class later compiles nothing. JAX keeps one program
+    for a row that is committed to its chip (how a mesh window hands
+    rows back, `parallel.mesh._rows_at`) and one for a row that is
+    not, so both are run."""
+    import jax
+    import jax.numpy as jnp
+
+    fn = _grow_fn(cap, cap2)
+    row = jnp.zeros((cap,), jnp.int32)
+    for r in (row, jax.device_put(row, next(iter(row.devices())))):
+        jax.block_until_ready(fn(r))
+
+
 def warmup_fused_cache(flush_docs: int = 8, cap: int = DEFAULT_CAP,
                        max_ins: int = DEFAULT_MAX_INS,
                        shape_classes: Sequence[int] = WARMUP_SHAPE_CLASSES,
@@ -203,7 +244,8 @@ def warmup_fused_cache(flush_docs: int = 8, cap: int = DEFAULT_CAP,
 class TailPlan:
     """Host-side packing of one doc's pending op tail (see
     FusedDocSession.plan_tail). `max_len` past the session cap means
-    the plan does not fit — the caller resyncs at a larger capacity."""
+    the plan does not fit — the caller grows the session first
+    (`FusedDocSession.make_room`)."""
     pos: np.ndarray
     dlen: np.ndarray
     ilen: np.ndarray
@@ -267,6 +309,9 @@ class FusedDocSession:
         self.headroom = float(headroom)
         self.resyncs = -1          # the first build counts up to 0
         self.merges = 0
+        # a bank's slot budget: `sync` calls it with the int32 slots a
+        # growth is about to add (`SessionBank._build` sets it)
+        self.before_growth = None
         self._materialize(min_cap=cap)
 
     # ---- full (re)build --------------------------------------------------
@@ -274,7 +319,10 @@ class FusedDocSession:
     def _materialize(self, min_cap: int = 0) -> None:
         """Host checkout -> device buffer. Always correct (the host
         tracker is the oracle); costs one full upload, so it only runs
-        at build time and on capacity growth."""
+        at build time (a first build, and the rebuild that follows an
+        eviction or a `FenceFailure`) and where a growth on the device
+        itself fails (`make_room`). `headroom` sizes a build, not a
+        growth."""
         import jax.numpy as jnp
 
         text = self.oplog.checkout_tip().snapshot()
@@ -297,6 +345,56 @@ class FusedDocSession:
         self._arena_tag = None     # full rebuild invalidates any slot
         from ..obs.devprof import note_transfer
         note_transfer(buf.nbytes, rung="session", purpose="stage")
+
+    # ---- growth ----------------------------------------------------------
+
+    def grow(self, cap2: int) -> None:
+        """Move to capacity class `cap2` on the device: the resident
+        row copied into a zeroed `[cap2]` row by the one small program
+        of the (cap, cap2) pair. No host checkout and no upload; the
+        length, the frontier and the pending tail stay as they are. A
+        window-arena tag names a row of the old capacity, so it goes."""
+        self.docs = _grow_fn(self.cap, cap2)(self.docs)
+        self.cap = cap2
+        self._arena_tag = None
+
+    def slots_short(self, plan: TailPlan) -> int:
+        """The int32 slots a growth for `plan` adds: up to the smallest
+        class that holds the plan's peak, however many classes up."""
+        from .steer import cap_class
+        return cap_class(plan.max_len) - self.cap
+
+    def make_room(self, plan: TailPlan, rebuild: bool = True) -> bool:
+        """`plan` does not fit: grow on the device to the smallest class
+        that holds its peak, in one step. True where the session grew
+        and `plan` is still to replay. Where the device refuses the
+        copy: with `rebuild` the session is rebuilt from the host at
+        the tip (counted `grow_rebuilt`), which leaves nothing pending;
+        without it (a caller that does not hold the oplog's guard, and
+        so may not check the document out) the session stays as it
+        was. False either way."""
+        import jax
+
+        cap, docs = self.cap, self.docs
+        cap2 = cap + self.slots_short(plan)
+        with phase("bank.grow") as ph:
+            try:
+                self.grow(cap2)
+                # the copy is dispatched, not done: a device that has
+                # no room for the new row says so when it is waited
+                # for, so it is waited for here, where it can still be
+                # answered
+                jax.block_until_ready(self.docs)
+            except jax.errors.JaxRuntimeError:
+                self.cap, self.docs = cap, docs
+                if rebuild:
+                    self._materialize(min_cap=cap2)
+                    ph.count("grow_rebuilt")
+                    ph.count("grow_slots", self.cap - cap)
+                return False
+            ph.count("grown")
+            ph.count("grow_slots", cap2 - cap)
+        return True
 
     # ---- host-side planning ----------------------------------------------
 
@@ -357,7 +455,10 @@ class FusedDocSession:
         rows: List[Tuple[int, int, int, str]] = []
         cur = self.doc_len
         peak = cur
+        block_rows = 0      # rows cut from a piece longer than `mi`
         for pos, n, content in pieces:
+            if n > mi:
+                block_rows += -(-n // mi)
             if content is not None:
                 off = 0
                 while off < n:
@@ -375,6 +476,8 @@ class FusedDocSession:
                 cur -= n
         k = len(rows)
         ph.step("plan.pack")
+        ph.count("rows", k)
+        ph.count("block_rows", block_rows)
         if ctx is None:
             frontier = xf.next_frontier     # known once the walk has ended
         frontier = tuple(int(x) for x in frontier)
@@ -421,14 +524,18 @@ class FusedDocSession:
 
     def sync(self) -> int:
         """Per-doc path (the fused fallback ladder's last device rung):
-        plan, then replay this doc alone at batch size 1. Resyncs on
-        capacity overflow. Raises `FenceFailure` on a poisoned result
-        (the bank's sync_doc evicts and serves from the host engine)."""
+        plan, then replay this doc alone at batch size 1. A tail that
+        overflows the capacity grows the session on the device first
+        (`make_room`) and is then replayed; only a growth that fails
+        rebuilds from the host, and then nothing is left to replay.
+        Raises `FenceFailure` on a poisoned result (the bank's sync_doc
+        evicts and serves from the host engine)."""
         plan = self.plan_tail()
         if not plan.fits(self.cap):
-            self._materialize(
-                min_cap=_pow2(int(plan.max_len * self.headroom)))
-            return 0
+            if self.before_growth is not None:
+                self.before_growth(self.slots_short(plan))
+            if not self.make_room(plan):
+                return 0
         if plan.n_ops == 0:
             self.commit_host(plan)
             return 0
@@ -533,6 +640,7 @@ def _fused_replay(sessions, plans, ph) -> Tuple[List[bool], float]:
     bp0 = _pow2(b) if b > 1 else 1
     bp, n = STEER.snap("fused", bp0, n0, mi, cap)
     pos, dlen, ilen, chars = pack_plans(plans, n, mi, bp)
+    ph.count("scan_steps", n)
     from ..obs.devprof import note_transfer
     note_transfer(pos.nbytes + dlen.nbytes + ilen.nbytes + chars.nbytes,
                   rung="fused", purpose="plan")

@@ -100,7 +100,9 @@ def test_fused_fn_per_doc_poison():
     assert got[0] == -1 and got[1] == 2
 
 
-def test_capacity_overflow_resyncs_then_converges():
+def test_capacity_overflow_grows_then_converges():
+    """Since PR 36 an overflowing tail grows the session on the device
+    (tests/test_session_growth.py); it is not rebuilt from the host."""
     ol = _mk_oplog("grow")
     a = ol.get_or_create_agent_id("a")
     ol.add_insert(a, 0, "seed")
@@ -109,7 +111,7 @@ def test_capacity_overflow_resyncs_then_converges():
     ol.add_insert(a, 0, "y" * 600)     # tail overflows cap=256
     sess.sync()
     sess.sync()
-    assert sess.resyncs == r0 + 1
+    assert sess.resyncs == r0 and sess.cap == 1024
     assert sess.text() == ol.checkout_tip().snapshot()
 
 
@@ -667,5 +669,8 @@ def test_a_walk_costs_the_push_not_the_document(monkeypatch):
     assert sess.doc_len == len(doc.plain.text())
     assert (ctx.appended, ctx.rebuilt) == (202, 1)
     counts = table.snapshot()["phases"]["plan.tail"]["counts"]
+    # since PR 36 also the plan rows the walks made; keystrokes make no
+    # block row
+    assert counts.pop("rows") >= 202 and counts.pop("block_rows") == 0
     assert counts == {"xf_native": 202, "mirror_appended": 202,
                       "mirror_rebuilt": 0, "mirror_busy_waits": 0}

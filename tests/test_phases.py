@@ -770,10 +770,16 @@ def test_plan_tail_and_fused_replay_report_their_steps(engine, monkeypatch):
         assert ph[name]["count"] == 1, name
     # which engine walked and, for the native one, how its mirror
     # followed the oplog: the walk outside the root had synced it
-    assert ph.pop("plan.tail")["counts"] == (
+    # and (since PR 36) the plan rows it made, both of them pieces of
+    # an insert longer than `max_ins`; the replay says how many scan
+    # steps its call was padded to (2, or a warm class's the steer
+    # table snapped it to)
+    assert ph.pop("plan.tail")["counts"] == dict(
         {"xf_native": 1, "mirror_appended": 0, "mirror_rebuilt": 0,
          "mirror_busy_waits": 0}
-        if engine == "native" else {"xf_python": 1})
+        if engine == "native" else {"xf_python": 1},
+        rows=2, block_rows=2)
+    assert ph.pop("replay")["counts"]["scan_steps"] >= 2
     assert all("counts" not in row for row in ph.values())
     ph = table.snapshot()["phases"]
     for root, steps in (("plan.tail", ("plan.xf", "plan.rows", "plan.pack")),
