@@ -12,6 +12,13 @@ import numpy as np
 from .build import NativeBuildError, build as _build
 
 _lib = None
+# the same library loaded through `ct.PyDLL`: a call through it keeps the
+# interpreter (the GIL) where one through `_lib` lets it go and queues
+# for it again on return. For the calls that run for microseconds (a
+# tail's loaders, the fetch of a result): under a lock that other
+# threads wait for, each hand-off is that lock held for as long as the
+# interpreter takes to come back.
+_lib_kept = None
 # why the build or load failed, once it has (negative cache: a failed
 # g++ run is not retried per call). None while untried or loaded.
 _load_error: Optional[str] = None
@@ -29,19 +36,22 @@ def _load():
     speed). The reason is kept in `_load_error`; `native.require_native`
     turns it into an error where the slow engine must not pass unseen
     (serve() start-up, chip_smoke.py)."""
-    global _lib, _load_error
+    global _lib, _lib_kept, _load_error
     if _lib is not None or _load_error is not None:
         return _lib
     try:
-        lib = ct.CDLL(_build())
+        path = _build()
+        lib = ct.CDLL(path)
         _configure(lib)
+        kept = ct.PyDLL(path)
+        _configure(kept)
     except (NativeBuildError, OSError, AttributeError) as e:
         # AttributeError: a symbol _configure declares is missing
         _load_error = f"{e.__class__.__name__}: {e}"
         sys.stderr.write(f"native host core unavailable ({_load_error}); "
                          "library callers use the pure-Python engine\n")
         return None
-    _lib = lib
+    _lib, _lib_kept = lib, kept
     return lib
 
 
@@ -242,6 +252,7 @@ class NativeContext:
         self.mirror_lock = make_lock("native.mirror", "leaf",
                                      reentrant=True)
         self._lib = lib
+        self._kept = _lib_kept
         self._ptr = lib.dt_ctx_new()
         self._built_len = -1
         self._oplog = oplog
@@ -286,7 +297,11 @@ class NativeContext:
         that it does not continue the ctx's own: the ctx is then to be
         built anew."""
         ol = self._oplog
-        lib, ptr = self._lib, self._ptr
+        # a tail's loaders keep the interpreter: a sync runs under the
+        # lock that guards the oplog, and four hand-offs a document were
+        # most of that hold; a whole build (milliseconds) lets it go
+        lib = self._kept if self._built_len >= 0 else self._lib
+        ptr = self._ptr
         n_len = len(ol)
         names = ol.cg.agent_assignment.agent_names
         g = ol.cg.graph
@@ -352,13 +367,16 @@ class NativeContext:
         kind = np.empty(n, dtype=np.uint8)
         fwd = np.empty(n, dtype=np.uint8)
         pos = np.empty(n, dtype=np.int64)
+        # the walk itself lets the interpreter go; the copies out of its
+        # result keep it
+        kept = self._kept
         if n:
-            lib.dt_get_out(self._ptr, lv, ln, kind, fwd, pos)
+            kept.dt_get_out(self._ptr, lv, ln, kind, fwd, pos)
         fbuf = np.empty(16, dtype=np.int64)
-        k = lib.dt_get_out_frontier(self._ptr, fbuf, 16)
+        k = kept.dt_get_out_frontier(self._ptr, fbuf, 16)
         if k > 16:
             fbuf = np.empty(k, dtype=np.int64)
-            lib.dt_get_out_frontier(self._ptr, fbuf, k)
+            kept.dt_get_out_frontier(self._ptr, fbuf, k)
         frontier = [int(x) for x in fbuf[:k]]
         return lv, ln, kind, fwd, pos, frontier
 
@@ -550,7 +568,7 @@ class NativeContext:
     @_mirror_locked
     def release_tracker(self) -> None:
         """Free the tracker tables retained for dump_tracker/zone_common."""
-        self._lib.dt_release_tracker(self._ptr)
+        self._kept.dt_release_tracker(self._ptr)
 
     @_mirror_locked
     def last_collisions(self) -> int:

@@ -499,12 +499,15 @@ class SessionBank:
         plan, fallback bookkeeping), `device_lock` around the fused
         device replay only — see the module docstring.
 
-        Returns {"docs", "fused_calls", "fused_docs", "fallback_docs"}.
+        Returns {"docs", "fused_calls", "fused_docs", "fallback_docs",
+        "device_s"}: the last the seconds the fused calls waited for
+        the device.
         """
         dlock = device_lock if device_lock is not None \
             else contextlib.nullcontext()
         win = self.plan_window(items, resolve, oplog_lock=oplog_lock)
         fused_calls = fused_docs = 0
+        device_total = 0.0
         # ---- device phase: one jitted call per fused group, under the
         # device lock ONLY — host threads keep mutating other oplogs
         failed: List[str] = []
@@ -526,6 +529,7 @@ class SessionBank:
             n = len(sessions)
             fused_calls += 1
             fused_docs += n
+            device_total += device_s
             if self.metrics is not None:
                 self.metrics.record_fused(self.shard_id, n)
                 self.metrics.observe_device_time(self.shard_id, wall,
@@ -542,6 +546,7 @@ class SessionBank:
                                 device_lock=device_lock)
         out["fused_calls"] = fused_calls
         out["fused_docs"] = fused_docs
+        out["device_s"] = device_total
         return out
 
     def _plan_fused(self, items, ols, olock, min_fuse: int = 2):

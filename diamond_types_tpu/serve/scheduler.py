@@ -15,7 +15,11 @@ submits and reads for other shards never stall behind one shard's
 device call. With `flush_workers=True` (default) `pump()` only TAKES
 due buckets under the global lock and hands them to per-shard worker
 threads, so the pump caller returns immediately and shards genuinely
-overlap their flush windows; `drain()` waits for workers to go idle
+overlap their flush windows; a shard whose worker still has a batch is
+left in the queue, where its documents go on coalescing, so a worker
+never holds a backlog of batches that an earlier one made stale, and a
+worker paces its host work (`FLUSH_HOST_SHARE`); `drain()` waits for
+workers to go idle
 and `stop_workers()`/`stop_pump()` join them deterministically. The
 fencing recheck runs INSIDE the worker (see `_flush_items`), so lease
 epochs are validated at actual merge time, not dispatch time.
@@ -63,6 +67,16 @@ from .admission import AdmissionQueue, Backpressure
 from .bank import SessionBank
 from .metrics import ServeMetrics
 from .router import ShardRouter
+
+# Merging is background work: no acknowledgement waits for it, and its
+# worker shares the store's lock and the interpreter with the handlers
+# that acknowledge. A shard's flush worker keeps its host part (what a
+# flush takes besides the wait for the device: resolve, plan, the
+# dispatches, adopt, and its own waits for the lock) to one part in
+# this many of its time, and sits out the rest (`_worker_loop`). A
+# flush that mostly waits for the device is never held back, and a
+# forced one (drain, shutdown: somebody waits for it) is not either.
+FLUSH_HOST_SHARE = 8
 
 
 class MergeScheduler:
@@ -198,6 +212,8 @@ class MergeScheduler:
         self._workers: List[Optional[threading.Thread]] = \
             [None] * n_shards
         self._inflight = 0
+        # batches handed to each shard's worker and not yet flushed
+        self._busy: List[int] = [0] * n_shards
         self._idle_cv = threading.Condition()
 
     def attach_obs(self, obs) -> None:
@@ -368,6 +384,10 @@ class MergeScheduler:
         taken = []      # (shard, reason, items)
         with self.lock:
             for shard, bucket, reason in self.queue.due(now, force=force):
+                if self._busy[shard] and not force:
+                    # its worker still has a batch: the bucket stays
+                    # queued and coalesces until the worker is free
+                    continue
                 items = self.queue.take(shard, bucket)
                 if items:
                     taken.append((shard, reason, items))
@@ -418,6 +438,7 @@ class MergeScheduler:
         threads)."""
         with self._idle_cv:
             self._inflight += 1
+            self._busy[shard] += 1
         if self._workers[shard] is None:
             t = threading.Thread(target=self._worker_loop, args=(shard,),
                                  name=f"flush-worker-{shard}",
@@ -434,12 +455,23 @@ class MergeScheduler:
                 return
             reason, items = job
             try:
-                self._flush_items(shard, reason, items)
+                wall_s, device_s = self._flush_items(shard, reason, items)
+                if reason != "force":
+                    # FLUSH_HOST_SHARE: the wait for the device counts
+                    # towards the rest; the host part counts up to one
+                    # flush deadline, so that a slow flush (a session
+                    # built, a class compiled) is not sat out for
+                    # seconds; a stop ends the pause
+                    host_s = min(wall_s - device_s,
+                                 self.queue.flush_deadline_s)
+                    self._pump_stop.wait(
+                        (FLUSH_HOST_SHARE - 1) * host_s - device_s)
             except Exception as e:      # keep the shard alive, loudly
                 self._loop_error("flush_worker", shard, e)
             finally:
                 with self._idle_cv:
                     self._inflight -= 1
+                    self._busy[shard] -= 1
                     self._idle_cv.notify_all()
 
     def _loop_error(self, where: str, shard: int,
@@ -568,12 +600,15 @@ class MergeScheduler:
         with it — `record_flush`, queue waits, read invalidation (cache
         hygiene only: the read cache is frontier-keyed). Groups of the
         batch that committed before the raise keep their device state;
-        the rest stay behind their oplogs until their next flush."""
+        the rest stay behind their oplogs until their next flush.
+
+        Returns the flush's seconds and, of them, those its fused calls
+        waited for the device (what the worker's pacing reads)."""
         obs = self.obs
         items = self._fence(shard, items)
         items = self._hydration_gate(shard, items)
         if not items:
-            return
+            return 0.0, 0.0
         fspan = NOOP_SPAN
         if obs is not None:
             parent = next(
@@ -630,6 +665,7 @@ class MergeScheduler:
         if self.read_invalidate is not None:
             for it in items:
                 self.read_invalidate(it.doc_id)
+        return dur, res["device_s"]
 
     # ---- mesh flush window -----------------------------------------------
 

@@ -22,7 +22,10 @@ RESIDENT device state instead of replayed from scratch:
   * `fused_replay(sessions, plans)` stacks every doc in the bucket into
     `[b, n, max_ins]` arrays — `n` padded to the bucket's power-of-two
     shape class, `b` rounded to a power of two so the jit cache stays
-    O(log^2) — and runs ONE jitted scan for the whole bucket.
+    O(log^2) — and runs ONE jitted scan for the whole bucket. The
+    resident rows go into the `[b, cap]` batch and come back out of it
+    by one jitted program each way (`_row_programs`): three device
+    programs a call, whatever `b`.
 
 Contract violations (an op longer than `max_ins` reaching the kernel)
 poison that DOCUMENT's length to -1 — per-doc, not per-batch, so one
@@ -126,6 +129,38 @@ def _fused_fn(b: int, n: int, mi: int, cap: int):
     return fn
 
 
+_row_fns = None
+
+
+def _row_programs():
+    """The two programs that take resident `[cap]` rows into the
+    replay's `[bp, cap]` batch and back, one dispatch each where eager
+    `jnp.stack` / `out_docs[i]` made some four a row; `jax.jit` keeps an
+    executable a `(bp, cap)`. `dt_stack_rows` donates nothing: a
+    session that fails the length fence keeps its row. `dt_unstack_rows`
+    returns every row and length as a buffer of its own, so the next
+    call may donate its batch. Their names (`jit_dt_stack_rows`,
+    `jit_dt_unstack_rows`) are what a profiler trace shows of them."""
+    global _row_fns
+    with _fused_jit_lock:
+        if _row_fns is None:
+            import jax
+            import jax.numpy as jnp
+
+            def dt_stack_rows(rows, lens):
+                with jax.named_scope("dt.replay.stack"):
+                    return jnp.stack(rows), jnp.stack(lens)
+
+            def dt_unstack_rows(docs, lens):
+                with jax.named_scope("dt.replay.unstack"):
+                    lanes = range(docs.shape[0])
+                    return (tuple(docs[i] for i in lanes),
+                            tuple(lens[i] for i in lanes))
+
+            _row_fns = (jax.jit(dt_stack_rows), jax.jit(dt_unstack_rows))
+    return _row_fns
+
+
 _grow_fns = {}
 
 
@@ -197,15 +232,19 @@ def warmup_fused_cache(flush_docs: int = 8, cap: int = DEFAULT_CAP,
     compiled = 0
     batches = warmup_batches(flush_docs)
     for b in batches:
+        # the batch goes in and comes out through the two row
+        # programs, on rows as a build leaves them (not committed to a
+        # chip), which is how a flush meets them
+        stack, unstack = _row_programs()
+        rows = (jnp.zeros((cap,), jnp.int32),) * b
+        row_lens = (jnp.zeros((), jnp.int32),) * b
         for ncls in shape_classes:
             n = _pow2(ncls)
             fn = _fused_fn(b, n, max_ins, cap)
-            docs = jnp.zeros((b, cap), jnp.int32)
-            lens = jnp.zeros((b,), jnp.int32)
+            docs, lens = stack(rows, row_lens)
             z = jnp.zeros((b, n), jnp.int32)
             ch = jnp.zeros((b, n, max_ins), jnp.int32)
-            out_docs, out_lens = fn(docs, lens, z, z, z, ch)
-            jax.block_until_ready(out_lens)
+            jax.block_until_ready(unstack(*fn(docs, lens, z, z, z, ch)))
             compiled += 1
     if mesh_shards > 0:
         from ..parallel.mesh import (mesh_flush_fn, pad_batch_count,
@@ -626,8 +665,10 @@ def fused_replay(sessions: List[FusedDocSession],
 
 def _fused_replay(sessions, plans, ph) -> Tuple[List[bool], float]:
     """`fused_replay` under its `replay` phase `ph`; the steps are the
-    host pack, the two stacks, dispatch (jit lookup, plan upload, the
-    call), the length fence and adoption."""
+    host pack, the stack (one program for the batch's rows and lengths),
+    dispatch (jit lookup, plan upload, the call, and the program that
+    cuts the rows back out, queued behind it), the length fence and
+    adoption (the fence's verdict a session, over Python tuples)."""
     import jax.numpy as jnp
 
     b = len(sessions)
@@ -645,15 +686,18 @@ def _fused_replay(sessions, plans, ph) -> Tuple[List[bool], float]:
     note_transfer(pos.nbytes + dlen.nbytes + ilen.nbytes + chars.nbytes,
                   rung="fused", purpose="plan")
     ph.step("replay.stack")
-    docs = jnp.stack([s.docs for s in sessions]
-                     + [sessions[0].docs] * (bp - b))
-    lens = jnp.stack([s.lens for s in sessions]
-                     + [sessions[0].lens] * (bp - b))
+    stack, unstack = _row_programs()
+    lanes = sessions + sessions[:1] * (bp - b)  # inert ones repeat the first
+    docs, lens = stack(tuple(s.docs for s in lanes),
+                       tuple(s.lens for s in lanes))
     ph.step("replay.dispatch")
     fn = _fused_fn(bp, n, mi, cap)
     out_docs, out_lens = fn(docs, lens, jnp.asarray(pos),
                             jnp.asarray(dlen), jnp.asarray(ilen),
                             jnp.asarray(chars))
+    # queued behind the replay: the rows are cut out on the device
+    # while this thread waits at the fence, without the interpreter
+    rows, row_lens = unstack(out_docs, out_lens)
     # the length fetch is the completion fence AND the parity
     # cross-check: poison (-1) or host-projection drift fails the doc
     ph.step("replay.fence")
@@ -661,5 +705,4 @@ def _fused_replay(sessions, plans, ph) -> Tuple[List[bool], float]:
     got = np.asarray(out_lens)
     device_s = time.perf_counter() - t_fence
     ph.step("replay.adopt")
-    return adopt_results(sessions, plans, out_docs, out_lens, got), \
-        device_s
+    return adopt_results(sessions, plans, rows, row_lens, got), device_s

@@ -413,6 +413,145 @@ def test_bank_background_warmup_thread_joins():
     assert not bank._warmup_thread.is_alive()
 
 
+# ---- rows into the batch and out of it, one program each way --------------
+#
+# A `fused_replay` call is three device programs whatever the batch:
+# `jit_dt_stack_rows`, `jit_dt_fused_replay`, `jit_dt_unstack_rows`
+# (`flush_fuse._row_programs`). Counts and bytes on the CPU, never a
+# time.
+
+ROW_BATCHES = (1, 3, 8)
+ROW_PROGRAMS = ["jit(dt_fused_replay)", "jit(dt_stack_rows)",
+                "jit(dt_unstack_rows)"]
+
+
+@pytest.fixture
+def cold_programs(monkeypatch):
+    """No jitted program of this module yet and no class noted warm,
+    whatever the process ran before: a first call of a class is the
+    first one to compile it."""
+    from diamond_types_tpu.tpu import steer
+    monkeypatch.setattr(ff, "_fused_jit_cache", {})
+    monkeypatch.setattr(ff, "_row_fns", None)
+    monkeypatch.setattr(steer, "STEER", steer.ShapeSteer())
+
+
+def _row_fleet(n: int, cap: int, tag: str):
+    """`n` sessions of one capacity class over short typed documents."""
+    ols = [_mk_oplog(f"{tag}{i}") for i in range(n)]
+    for i, ol in enumerate(ols):
+        ol.add_insert(ol.get_or_create_agent_id("a"), 0, f"doc {i}: ")
+    return ols, [ff.FusedDocSession(ol, cap=cap, max_ins=4) for ol in ols]
+
+
+def _type(ols, text="ab") -> None:
+    """One plan row a document: every call pads to the same class
+    (two scan steps, the smallest)."""
+    for ol in ols:
+        ol.add_insert(ol.get_or_create_agent_id("a"), 2, text)
+
+
+def _replay_logged(sess, caplog):
+    """`fused_replay` of every session's tail: (ok, the modules JAX
+    compiled for it)."""
+    import jax
+
+    plans = [s.plan_tail() for s in sess]
+    caplog.clear()
+    with caplog.at_level("WARNING", logger="jax._src.interpreters.pxla"), \
+            jax.log_compiles():
+        ok, _dev = ff.fused_replay(sess, plans)
+    return ok, sorted(r.args[0] for r in caplog.records
+                      if r.msg.startswith("Compiling %s with global"))
+
+
+@pytest.mark.parametrize("b", ROW_BATCHES)
+def test_a_call_is_three_programs_whatever_the_batch(b, caplog,
+                                                     cold_programs):
+    """A first call of a class compiles the three programs and nothing
+    else (no eager program a row), a second compiles none although its
+    rows now come out of `dt_unstack_rows` (and one straight from a
+    build) where the first call's all came from builds; every session
+    reads as the host checks it out."""
+    cap = 1024
+    ols, sess = _row_fleet(b + 1, cap, f"three{b}-")
+    _type(ols)
+    ok, compiled = _replay_logged(sess[:b], caplog)
+    assert ok == [True] * b
+    assert compiled == ROW_PROGRAMS
+    # the spare session takes the last one's lane: the same class
+    _type(ols)
+    again = sess[:b - 1] + sess[b:]
+    ok, compiled = _replay_logged(again, caplog)
+    assert ok == [True] * b and compiled == []
+    for s, ol in zip(again, ols[:b - 1] + ols[b:]):
+        assert s.text() == ol.checkout_tip().snapshot()
+        assert s.docs.shape == (cap,) and s.lens.shape == ()
+
+
+@pytest.mark.parametrize("b", ROW_BATCHES)
+def test_a_poisoned_lane_keeps_its_old_row(b):
+    """The stack donates nothing: a lane that fails the length fence
+    is not committed and its session still owns a live row, the text
+    it had, and replays the same tail the next time."""
+    import numpy as np
+    ols, sess = _row_fleet(b, 512, f"poison{b}-")
+    _type(ols)
+    assert all(ff.fused_replay(sess, [s.plan_tail() for s in sess])[0])
+    before = [s.text() for s in sess]
+    _type(ols, "wxyz")
+    plans = [s.plan_tail() for s in sess]
+    bad = b // 2
+    plans[bad].ilen = plans[bad].ilen + 4       # over `max_ins`: -1
+    ok, _dev = ff.fused_replay(sess, plans)
+    assert ok == [i != bad for i in range(b)]
+    assert sess[bad].text() == before[bad]
+    assert int(np.asarray(sess[bad].lens)) == len(before[bad])
+    assert sess[bad].synced_to < len(ols[bad])
+    assert ff.fused_replay([sess[bad]], [sess[bad].plan_tail()])[0] == [True]
+    for s, ol in zip(sess, ols):
+        assert s.text() == ol.checkout_tip().snapshot()
+
+
+@pytest.mark.parametrize("b", ROW_BATCHES)
+def test_rows_are_buffers_of_their_own(b):
+    """A session committed in one call and absent from the next still
+    reads right after that call donated its batch: `dt_unstack_rows`
+    hands every row and length out as its own buffer."""
+    ols, sess = _row_fleet(b + 1, 512, f"own{b}-")
+    _type(ols)
+    assert all(ff.fused_replay(sess[:b], [s.plan_tail()
+                                          for s in sess[:b]])[0])
+    rows = {s.docs.unsafe_buffer_pointer() for s in sess}
+    lens = {s.lens.unsafe_buffer_pointer() for s in sess}
+    assert len(rows) == len(lens) == b + 1
+    absent, absent_text = sess[b - 1], sess[b - 1].text()
+    _type(ols)
+    again = sess[:b - 1] + sess[b:]
+    for _ in range(2):      # the second call's batch is built of the first's rows
+        assert all(ff.fused_replay(again, [s.plan_tail()
+                                           for s in again])[0])
+        _type(ols[:b - 1] + ols[b:])
+    assert absent.text() == absent_text
+    assert all(ff.fused_replay([absent], [absent.plan_tail()])[0])
+    assert absent.text() == ols[b - 1].checkout_tip().snapshot()
+
+
+def test_the_warm_up_compiles_the_row_programs_a_flush_meets(
+        caplog, cold_programs):
+    """`warmup_fused_cache` runs a class's batch through the two row
+    programs, on rows as a build leaves them: the first real flush of
+    a warmed class compiles nothing."""
+    cap = 2048
+    ff.warmup_fused_cache(flush_docs=2, cap=cap, max_ins=4,
+                          shape_classes=(1,))
+    for b in (1, 2):
+        ols, sess = _row_fleet(b, cap, f"warm{b}-")
+        _type(ols)
+        ok, compiled = _replay_logged(sess, caplog)
+        assert ok == [True] * b and compiled == []
+
+
 # ---- prom rendering of the fused block -----------------------------------
 
 def test_prom_renders_fused_block():

@@ -219,3 +219,56 @@ def test_a_column_that_does_not_continue_is_built_whole_and_counted(other):
     assert columns(ctx, [], ol2.version) \
         == columns(NativeContext(ol2), [], ol2.version)
     assert (ctx.appended, ctx.rebuilt) == (1, 2)
+
+
+# ---- which calls keep the interpreter ------------------------------------
+
+class _Named:
+    """A library handle that notes the functions looked up on it."""
+
+    def __init__(self, lib) -> None:
+        self.lib, self.calls = lib, []
+
+    def __getattr__(self, name):
+        self.calls.append(name)
+        return getattr(self.lib, name)
+
+
+TAIL_LOADERS = ["dt_load_graph_tail", "dt_load_agent_runs_tail",
+                "dt_load_ops_tail", "dt_load_ins_arena_tail"]
+
+
+@pytest.mark.parametrize("build", ["tail", "whole"])
+def test_a_plan_walk_lets_the_interpreter_go_once(build):
+    """A walk as `plan_tail` makes it (sync, transform, release): where
+    the mirror follows by appending, only the transform itself goes
+    through the handle that lets the interpreter go (`ct.CDLL`); the
+    tail's loaders, the copies out of the result and the release go
+    through the one that keeps it (`ct.PyDLL`): one hand-off a walk
+    under the store's lock, not eight. A whole build, milliseconds on a
+    long document, lets it go."""
+    import ctypes
+
+    from diamond_types_tpu.native import core
+    assert isinstance(core._lib_kept, ctypes.PyDLL)
+    assert not isinstance(core._lib, ctypes.PyDLL)
+    ol = _typed("a", 12)
+    ctx = NativeContext(ol)
+    if build == "tail":
+        ctx.sync()
+        ol.add_insert(0, 0, "tail")
+    ctx._lib, ctx._kept = _Named(ctx._lib), _Named(ctx._kept)
+    got = columns(ctx, [], ol.version)
+    ctx.release_tracker()
+    fetch = ["dt_get_out", "dt_get_out_frontier", "dt_release_tracker"]
+    if build == "tail":
+        assert ctx._lib.calls == ["dt_transform"]
+        assert ctx._kept.calls == TAIL_LOADERS + fetch
+        assert (ctx.appended, ctx.rebuilt) == (1, 1)
+    else:
+        assert ctx._lib.calls == ["dt_ctx_free", "dt_ctx_new",
+                                  "dt_add_agent"] + TAIL_LOADERS \
+            + ["dt_transform"]
+        assert ctx._kept.calls == fetch
+        assert (ctx.appended, ctx.rebuilt) == (0, 1)
+    assert got == columns(NativeContext(ol), [], ol.version)

@@ -402,6 +402,67 @@ def test_scheduler_read_flushes_pending():
     assert snap["totals"]["flushed_docs"] == 1
 
 
+# ---- one batch a shard, and a worker that paces its host work -------------
+
+def test_a_busy_shard_keeps_its_bucket_queued_and_coalescing():
+    """While a shard's worker has a batch, `pump()` leaves that shard's
+    due buckets in the queue (re-submits coalesce there, no second
+    batch goes stale behind the first); it takes them once the worker
+    is free, and a forced pump takes them at once."""
+    ols = {f"d{i}": _mk_oplog(f"d{i}") for i in range(3)}
+    sched = MergeScheduler(1, resolve=ols.__getitem__, engine="host",
+                           flush_docs=1, flush_deadline_s=60.0)
+    held, entered = threading.Event(), threading.Event()
+    flush_items = sched._flush_items
+
+    def slow_flush(shard, reason, items):
+        entered.set()
+        assert held.wait(10)
+        return flush_items(shard, reason, items)
+    sched._flush_items = slow_flush
+    assert sched.submit("d0")["accepted"] and sched.pump() == 1
+    assert entered.wait(10) and sched._busy == [1]
+    for _ in range(3):
+        assert sched.submit("d1")["accepted"]
+    assert sched.pump() == 0 and sched.queue.depth(0) == 1
+    assert sched.metrics_json()["totals"]["coalesced"] == 2
+    assert sched.submit("d2")["accepted"]
+    assert sched.pump(force=True) == 2 and sched.queue.depth(0) == 0
+    held.set()
+    sched.drain()
+    assert sched._busy == [0]
+    assert sched.submit("d1")["accepted"] and sched.pump() == 1
+    sched.drain()
+    sched.stop_workers()
+    assert sched.metrics_json()["totals"]["flushed_docs"] == 4
+
+
+@pytest.mark.parametrize("reason,wall_s,device_s,pause", [
+    ("size", 0.020, 0.0, 0.140),        # all host work: seven parts out
+    ("deadline", 0.030, 0.010, 0.130),  # the device's wait counts as out
+    ("size", 0.300, 0.270, -0.060),     # device-bound: never held back
+    ("size", 2.000, 0.0, 0.350),        # a build or a compile: capped
+    ("force", 0.020, 0.0, None),        # somebody waits for it: no pause
+])
+def test_a_flush_worker_paces_its_host_work(reason, wall_s, device_s,
+                                            pause):
+    from diamond_types_tpu.serve import scheduler as sched_mod
+    assert sched_mod.FLUSH_HOST_SHARE == 8
+    sched = MergeScheduler(1, resolve=lambda d: _mk_oplog(d),
+                           engine="host")
+    waits = []
+
+    class Stop:
+        def wait(self, timeout):
+            waits.append(timeout)
+    sched._pump_stop = Stop()
+    sched._flush_items = lambda shard, why, items: (wall_s, device_s)
+    sched._dispatch(0, reason, ["item"])
+    sched._wait_idle()
+    sched.stop_workers()
+    assert waits == ([] if pause is None else [pytest.approx(pause)])
+
+
 # ---- e2e parity on simulated shards (the acceptance gate) -----------------
 
 def test_serve_bench_device_parity_4_shards():

@@ -202,6 +202,63 @@ def test_fused_batch_of_two_classes_one_growing_equals_the_ladder():
     assert totals["host_fallbacks"] == totals["resyncs"] == 0
 
 
+def test_a_grown_session_joins_its_new_class_through_the_row_programs():
+    """The row a growth leaves (`jit_dt_grow`'s output) goes into the
+    batch of its new class by `dt_stack_rows` beside a row that was
+    there all along, and comes back out of `dt_unstack_rows` at the new
+    capacity."""
+    fleet = _fleet(41)
+    bank = SessionBank(0, max_sessions=8, fused_opts=FUSED_OPTS)
+    bank.sync_docs(_items(fleet), lambda d: fleet[d].ol)
+    fleet["s0"].hunks_until(2048)
+    for d in ("s1", "l0"):
+        fleet[d].hunk()
+    table = PhaseTable()
+    with table.phase("sched.flush"):
+        out = bank.sync_docs(_items(fleet), lambda d: fleet[d].ol)
+    assert out["fused_calls"] == 2 and out["fallback_docs"] == 0
+    assert _counts(table, "bank.grow")["grown"] == 1
+    grown = bank.sessions["s0"]
+    assert grown.cap == 4096 and grown.resyncs == 0
+    for d, src in fleet.items():
+        sess = bank.sessions[d]
+        assert sess.docs.shape == (sess.cap,) and sess.lens.shape == ()
+        assert _row_text(sess) == src.text()
+
+
+@pytest.mark.mesh
+def test_the_per_shard_rung_leaves_rows_on_the_banks_chip():
+    """Four shards, a device each, no mesh window: a bank's fused call
+    runs its two row programs on the bank's chip, so a committed row
+    and its length lie there after a first flush (rows from builds) and
+    after a second (rows out of `dt_unstack_rows`)."""
+    from diamond_types_tpu.obs import Observability
+    fleet = {f"p{i}": _Source(90 + i, 2, doc_id=f"p{i}") for i in range(12)}
+    sched = MergeScheduler(4, resolve=lambda d: fleet[d].ol,
+                           engine="device", fused_opts=FUSED_OPTS,
+                           flush_docs=8, flush_deadline_s=10.0,
+                           flush_workers=False, place_on_devices=True)
+    sched.attach_obs(Observability())
+    assert len({bank.device for bank in sched.banks}) == 4
+    for rnd in range(3):
+        for d, src in fleet.items():
+            if rnd:
+                src.type()
+            assert sched.submit(d, n_ops=1)["accepted"]
+        sched.pump(force=True)
+        sched.drain()
+        for bank in sched.banks:
+            for d, sess in bank.sessions.items():
+                assert sess.docs.devices() == {bank.device}, (rnd, d)
+                assert sess.lens.devices() == {bank.device}, (rnd, d)
+                assert _row_text(sess) == fleet[d].text()
+    ph = sched.metrics_json()["phases"]["phases"]
+    assert ph["replay"]["count"] >= 2       # rounds 1 and 2 both replayed
+    totals = sched.metrics_json()["totals"]
+    assert totals["host_fallbacks"] == totals["device_errors"] == 0
+    sched.stop_workers()
+
+
 # ---- no checkout, no upload ----------------------------------------------
 
 @pytest.mark.parametrize("through", ["grow", "sync"])
