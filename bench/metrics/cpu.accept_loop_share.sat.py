@@ -1,0 +1,7 @@
+"""`b4-papers.edit-sat`: the accept loop's CPU as a percentage of one core
+over the traffic's seconds (bench/inside.py)."""
+from bench import inside
+
+
+def read(ctx):
+    return inside.cpu_share(ctx, "accept_loop_s")

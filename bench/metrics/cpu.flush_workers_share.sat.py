@@ -1,0 +1,7 @@
+"""`b4-papers.edit-sat`: the flush workers' CPU as a percentage of one core
+over the traffic's seconds (bench/inside.py)."""
+from bench import inside
+
+
+def read(ctx):
+    return inside.cpu_share(ctx, "flush_workers_s")

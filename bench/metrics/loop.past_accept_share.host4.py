@@ -1,0 +1,3 @@
+"""`host4-mixed.edit-sat128`: share of the closed loop's clients that the
+server holds past `accept()` at an instant (bench/inside.py)."""
+from bench.inside import past_accept_share as read  # noqa: F401

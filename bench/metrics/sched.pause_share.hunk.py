@@ -1,0 +1,3 @@
+"""`a2-sources.hunk-sat`: share of the traffic's seconds the flush worker
+sat out its pause (bench/inside.py)."""
+from bench.inside import pause_share as read  # noqa: F401

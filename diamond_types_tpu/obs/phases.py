@@ -37,11 +37,21 @@ every wait and hold here. Both are charged to the innermost step or
 phase open on the acquiring thread (`"other"` with none): in that
 row — and in every enclosing phase's — and in
 `locks[<lock name>][<site>]`.
+
+What a push meets outside any phase has rows here too, written by
+`observe_all` (several rows under one take of the table's lock) or
+as a root's notes: the kernel's listen queue and the handler thread's
+start, CPU and end (tools/server.py), and
+`gil.wait`, the table's own probe thread
+(`start_probe`): what a thread that becomes runnable waits for the
+interpreter. `snapshot()["cpu"]` is the process's CPU seconds by
+thread class, read from `/proc` at the scrape and at no other time.
 """
 
 from __future__ import annotations
 
 import math
+import os
 import sys
 import threading
 import time
@@ -58,6 +68,15 @@ from .hist import _FIRST_BOUND_S, _N_BUCKETS, Histogram
 SLOW_REQUEST_S = 0.25
 SLOW_EVENT_GAP_S = 1.0
 NO_SITE = "other"
+# the probe's sleep: 10 wakes a second (each is a system call and a
+# turn in the queue it measures: 50 a second cost 2-4 % of a core on
+# the chip's host)
+GIL_PROBE_S = 0.1
+# `snapshot()["cpu"]`: a live Python thread's class by the start of its
+# name (the server names its long-lived threads); the accept loop is
+# whatever thread called `claim_thread("accept_loop_s")`
+CPU_CLASSES = (("merge-pump", "pump_s"), ("flush-worker-", "flush_workers_s"),
+               ("autosave", "autosave_s"), ("gil-probe", "gil_probe_s"))
 
 _clock = time.perf_counter
 _tls = threading.local()
@@ -129,6 +148,7 @@ class _NoPhase:
     root open on this thread."""
 
     __slots__ = ()
+    done = False            # it never closes: nothing to run after it
 
     def __enter__(self):
         return self
@@ -205,6 +225,7 @@ class _Phase:
     slow = None
     done = False
     t0 = 0.0
+    t1 = 0.0                # when it closed
 
     def __init__(self, table: "PhaseTable", name: str) -> None:
         self.table = table
@@ -251,7 +272,7 @@ class _Phase:
         elif self in stack:     # never leave a closed phase as a site
             stack.remove(self)
         self.done = True
-        now = _clock()
+        now = self.t1 = _clock()
         if self.cur is not None:
             self._end_step(now)
         dt = now - self.t0
@@ -369,6 +390,9 @@ class PhaseTable:
         self._slow_event_at = float("-inf")
         self._slow_event_ms = 0.0
         self._slow_unwritten = 0
+        self._probe = None      # (thread, its stop event)
+        # native thread id -> class of `snapshot()["cpu"]`
+        self._claimed: Dict[int, str] = {}
 
     def phase(self, name: str, span=None,
               slow: Optional[dict] = None) -> _Phase:
@@ -387,8 +411,29 @@ class PhaseTable:
     def observe(self, name: str, seconds: float) -> None:
         """A phase known only when it is over, with no root to write
         it with."""
+        self.observe_all(((name, seconds),))
+
+    def observe_all(self, rows) -> None:
+        """`observe` for several (name, seconds) under one take of the
+        lock: what a handler thread knows of itself at its last line."""
         with self._lock:
-            self._row(name).add(seconds, 0.0, 0.0)
+            for name, seconds in rows:
+                self._row(name).add(seconds, 0.0, 0.0)
+
+    def tally(self, name: str, adds: dict, maxima: dict) -> None:
+        """A sample taken outside any phase, on the row's own counts:
+        `adds` are summed, `maxima` keep the largest value seen (a
+        difference of two snapshots means nothing for those)."""
+        with self._lock:
+            r = self._row(name)
+            mine = r.tallies
+            if mine is None:
+                mine = r.tallies = {}
+            for k, v in adds.items():
+                mine[k] = mine.get(k, 0) + v
+            for k, v in maxima.items():
+                if v > mine.get(k, 0):
+                    mine[k] = v
 
     def adopt(self, name: str, hist: Histogram) -> None:
         """Export a histogram kept elsewhere under a phase's name; no
@@ -545,6 +590,97 @@ class PhaseTable:
             lock_wait_ms=round(ph.wait * 1e3, 3),
             parts=parts, unwritten_before=unwritten, **fields)
 
+    # ---- the interpreter's queue ------------------------------------------------
+
+    def start_probe(self) -> None:
+        """`gil.wait`: a daemon thread that sleeps GIL_PROBE_S and
+        writes by how much it overslept: the wait to take the
+        interpreter back (and the kernel's wake-up latency, which an
+        idle server prices). One more waiter in the interpreter's
+        queue, 10 times a second."""
+        if self._probe is not None:
+            return
+        stop = threading.Event()
+
+        def loop():
+            wait, observe = stop.wait, self.observe
+            while True:
+                t0 = _clock()
+                if wait(GIL_PROBE_S):
+                    return
+                over = _clock() - t0 - GIL_PROBE_S
+                observe("gil.wait", over if over > 0.0 else 0.0)
+
+        t = threading.Thread(target=loop, name="gil-probe", daemon=True)
+        self._probe = (t, stop)
+        t.start()
+
+    def stop_probe(self) -> None:
+        probe, self._probe = self._probe, None
+        if probe is not None:
+            probe[1].set()
+            probe[0].join(timeout=2)
+
+    # ---- CPU by thread class ----------------------------------------------------
+
+    def claim_thread(self, cls: Optional[str]) -> None:
+        """File the calling thread's CPU under `cls` in the `cpu`
+        block whatever its name (the accept loop runs on a thread the
+        server did not make); None gives the claim up."""
+        if cls is None:
+            self._claimed.pop(threading.get_native_id(), None)
+        else:
+            self._claimed[threading.get_native_id()] = cls
+
+    def _cpu(self) -> Optional[dict]:
+        """Cumulative CPU seconds (user + system) of the process and of
+        its live threads by class, from `/proc/self/stat` and
+        `/proc/self/task/<tid>/stat`; `exited_s` is the process less
+        every live thread: the threads that came and went, which for a
+        server are its handler threads. None where `/proc` is not."""
+        try:
+            tids = os.listdir("/proc/self/task")
+            tck = os.sysconf("SC_CLK_TCK")
+        except (OSError, ValueError, AttributeError):
+            return None
+
+        def cpu_s(path: str) -> float:
+            with open(path, "rb") as f:
+                # the name may hold blanks and brackets: count from
+                # its closing one
+                fields = f.read().rsplit(b")", 1)[1].split()
+            return (int(fields[11]) + int(fields[12])) / tck
+
+        names = {t.native_id: t.name for t in threading.enumerate()}
+        out = dict.fromkeys(
+            ("process_s", "accept_loop_s", "pump_s", "flush_workers_s",
+             "autosave_s", "gil_probe_s", "live_handlers_s", "native_s"),
+            0.0)
+        for tid in tids:
+            try:
+                s = cpu_s(f"/proc/self/task/{tid}/stat")
+            except (OSError, IndexError, ValueError):
+                continue        # gone since the listing
+            tid = int(tid)
+            cls = self._claimed.get(tid)
+            if cls is None:
+                name = names.get(tid)
+                if name is None:
+                    cls = "native_s"    # XLA's threads, the runtime's
+                else:
+                    cls = next((c for prefix, c in CPU_CLASSES
+                                if name.startswith(prefix)),
+                               "live_handlers_s")
+            out[cls] += s
+        live = sum(out.values())
+        try:
+            # last, so that no live thread has seconds the process lacks
+            out["process_s"] = cpu_s("/proc/self/stat")
+        except (OSError, IndexError, ValueError):
+            return None
+        out["exited_s"] = max(0.0, out["process_s"] - live)
+        return out
+
     # ---- export -----------------------------------------------------------------
 
     def snapshot(self) -> dict:
@@ -568,5 +704,9 @@ class PhaseTable:
             phases[name] = {"count": hs["count"], "sum_s": hs["sum"],
                             "max_s": hs["max"], "p50_s": hs["p50"],
                             "p99_s": hs["p99"]}
-        return {"version": 1, "phases": phases, "locks": locks,
-                "slow_requests": slow}
+        out = {"version": 1, "phases": phases, "locks": locks,
+               "slow_requests": slow}
+        cpu = self._cpu()
+        if cpu is not None:
+            out["cpu"] = cpu
+        return out

@@ -464,8 +464,15 @@ class MergeScheduler:
                     # seconds; a stop ends the pause
                     host_s = min(wall_s - device_s,
                                  self.queue.flush_deadline_s)
-                    self._pump_stop.wait(
-                        (FLUSH_HOST_SHARE - 1) * host_s - device_s)
+                    pause_s = (FLUSH_HOST_SHARE - 1) * host_s - device_s
+                    if pause_s > 0:
+                        # a root of its own (`sched.flush` has closed):
+                        # a row, and under a profiler session a span on
+                        # the device trace's clock; a flush that mostly
+                        # waited for the device writes none
+                        with self.obs.phases.phase("sched.pause") \
+                                if self.obs is not None else NOOP_PHASE:
+                            self._pump_stop.wait(pause_s)
             except Exception as e:      # keep the shard alive, loudly
                 self._loop_error("flush_worker", shard, e)
             finally:
@@ -584,7 +591,7 @@ class MergeScheduler:
                 "flush_gate_dropped", shard=shard, docs=len(dropped))
         return keep
 
-    def _flush_items(self, shard: int, reason: str, items) -> None:
+    def _flush_items(self, shard: int, reason: str, items) -> tuple:
         """Sync one taken batch into its shard's bank, under that
         shard's lock only (items are already off the queue, so a
         concurrent submit for the same doc simply queues fresh work).
@@ -603,7 +610,11 @@ class MergeScheduler:
         the rest stay behind their oplogs until their next flush.
 
         Returns the flush's seconds and, of them, those its fused calls
-        waited for the device (what the worker's pacing reads)."""
+        waited for the device (what the worker's pacing reads). The
+        `sched.flush` root counts the flush by kind: `paced` (a flush
+        worker's, held to FLUSH_HOST_SHARE), `forced` (drain, shutdown,
+        the warm rounds) or `inline` (the caller's own thread: a read's
+        sync, a scheduler without workers)."""
         obs = self.obs
         items = self._fence(shard, items)
         items = self._hydration_gate(shard, items)
@@ -624,6 +635,9 @@ class MergeScheduler:
         # (obs/phases.py: they find it on this thread)
         ph = obs.phases.phase("sched.flush", span=fspan) \
             if obs is not None else NOOP_PHASE
+        ph.count("forced" if reason == "force" else "paced"
+                 if threading.current_thread() is self._workers[shard]
+                 else "inline")
         # the spans are context managers so a raise out of the bank
         # (a device error) ends them with `error=<type>`, not never
         with fspan, ph:
@@ -849,6 +863,9 @@ class MergeScheduler:
                                     "device_replayed")
             # adoption + per-bucket flush accounting, per shard
             root.step("window.adopt")
+            # by kind, as `_flush_items`: a window is never paced
+            root.count("forced" if all(r == "force" for _s, r, _i in entries)
+                       else "inline")
             # where the window's documents went: the mesh rung, no
             # device work (an empty plan), or the per-doc ladder
             root.count("window_docs", n_docs)
@@ -992,7 +1009,8 @@ class MergeScheduler:
                 except Exception as e:      # keep pumping, loudly
                     self._loop_error("pump", 0, e)
 
-        self._pump_thread = threading.Thread(target=loop, daemon=True)
+        self._pump_thread = threading.Thread(target=loop, name="merge-pump",
+                                             daemon=True)
         self._pump_thread.start()
         if self.qos is not None:
             # the controller's loop lives and dies with the pump: no

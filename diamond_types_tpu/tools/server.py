@@ -123,6 +123,9 @@ import http.client
 import json
 import os
 import re
+import select
+import socket
+import struct
 import sys
 import threading
 import time
@@ -220,7 +223,8 @@ class DocStore:
                 except OSError:  # pragma: no cover - disk full etc.
                     pass
 
-        self._flusher = threading.Thread(target=loop, daemon=True)
+        self._flusher = threading.Thread(target=loop, name="autosave",
+                                         daemon=True)
         self._flusher.start()
 
     def stop_flusher(self) -> None:
@@ -905,30 +909,48 @@ class SyncHandler(BaseHTTPRequestHandler):
         """The root phase of a document request (`http.edit`,
         `http.get`, `http.<action>`), NOOP_PHASE for any other path or
         with no bundle; also closes `http.accept_wait`, which ends at
-        the handler's first line. A root that takes SLOW_REQUEST_S or
+        the handler's first line, and takes the connection's
+        `http.listen_wait` with it. A root that takes SLOW_REQUEST_S or
         longer writes one `slow_request` event with its parts — but for
         the `changes` long-poll, whose wait is the request."""
         if obs is None:
             return NOOP_PHASE
         at = getattr(self.server, "accepted_at", None)
-        t_acc = at.pop(self.request, None) if at is not None else None
-        wait = None if t_acc is None else time.perf_counter() - t_acc
+        stamp = at.pop(self.request, None) if at is not None else None
+        wait = listen = None
+        if stamp.__class__ is float:
+            wait = time.perf_counter() - stamp
+        elif stamp is not None:     # a clocked connection: its thread
+            wait = time.perf_counter() - stamp.at   # keeps the stamp
+            listen = stamp.listen_s
         doc_id, action = self._route()
         if doc_id is None:
-            if wait is not None:
-                obs.phases.observe("http.accept_wait", wait)
+            if wait is not None:        # no root to ride on
+                if listen is None:
+                    obs.phases.observe("http.accept_wait", wait)
+                else:
+                    obs.phases.observe_all((("http.accept_wait", wait),
+                                            ("http.listen_wait", listen)))
             return NOOP_PHASE
         if method == "GET":
             name = "http.get"
         else:
             name = "http." + (action if action in _POST_ACTIONS
                               else "other")
-        slow = None if action == "changes" else {
-            "doc": doc_id, "accept_wait_ms": round((wait or 0.0) * 1e3, 3)}
+        slow = None
+        if action != "changes":
+            slow = {"doc": doc_id,
+                    "accept_wait_ms": round((wait or 0.0) * 1e3, 3)}
+            if listen is not None:
+                slow["listen_wait_ms"] = round(listen * 1e3, 3)
         ph = obs.phases.phase(name, slow=slow)
         ph.count(self._parsed)
         if wait is not None:
             ph.note("http.accept_wait", wait)   # written with the root
+            if stamp.__class__ is not float:
+                stamp.root = ph
+                if listen is not None:
+                    ph.note("http.listen_wait", listen)
         return ph
 
     def do_GET(self):
@@ -1715,6 +1737,55 @@ class SyncHandler(BaseHTTPRequestHandler):
         return self._send(404, b"{}")
 
 
+# struct tcp_info (linux/tcp.h): where its 32-bit fields lie. On a
+# LISTENING socket `tcpi_unacked` is the accept queue's depth and
+# `tcpi_sacked` its limit; on a connection `tcpi_last_data_recv` is
+# the milliseconds since its last bytes arrived, or since the handshake
+# ended where none have, in jiffies' steps (4 ms at HZ 250): a mean
+# over many connections is what can be read, never one sample.
+_TCP_INFO = getattr(socket, "TCP_INFO", None)
+_TCPI_UNACKED = 24
+_TCPI_SACKED = 28
+_TCPI_LAST_DATA_RECV = 52
+_u32_at = struct.Struct("I").unpack_from
+# Every push pays for what the accept loop and its own thread do, and
+# on the chip's host a line of Python costs 2.5 times what it costs
+# elsewhere and a system call 6 us: so the listening socket is sampled
+# once in 32 accepts, and one connection in 8 is CLOCKED (its wait in
+# the kernel, its thread's start, CPU and end); the other seven pay a
+# counter and a type check more than before these clocks.
+LISTEN_SAMPLE_EVERY = 32
+CLOCKED_EVERY = 8
+
+
+def _tcp_info(sock, offset: int) -> Optional[int]:
+    """One field of the socket's `TCP_INFO`; None where the platform
+    has none (not Linux)."""
+    if _TCP_INFO is None:
+        return None
+    try:
+        return _u32_at(sock.getsockopt(socket.IPPROTO_TCP, _TCP_INFO, 64),
+                       offset)[0]
+    except (OSError, struct.error):
+        return None
+
+
+class _Clocked:
+    """What a clocked connection carries from `accept()` to its
+    thread's last line."""
+
+    __slots__ = ("at", "listen_s", "root")
+
+    def __init__(self, at: float, listen_s: Optional[float]) -> None:
+        self.at = at                # when accept() returned it
+        # `http.listen_wait`: the seconds its request had lain in the
+        # kernel by then; None where the kernel does not say
+        self.listen_s = listen_s
+        # its request's root phase (none: no document request), set
+        # at the handler's first line
+        self.root = None
+
+
 class _Server(ThreadingHTTPServer):
     store: DocStore = None
     # The listen queue. The stdlib's 5 overflows when a few dozen
@@ -1726,16 +1797,90 @@ class _Server(ThreadingHTTPServer):
 
     def __init__(self, *a, **kw) -> None:
         super().__init__(*a, **kw)
-        # connection -> when accept() returned it: `http.accept_wait`
-        # runs from there to the handler's first line (thread start,
-        # request line, headers)
+        # connection -> when accept() returned it (a `_Clocked` for one
+        # in CLOCKED_EVERY): `http.accept_wait` runs from there to the
+        # handler's first line (thread start, request line, headers)
         self.accepted_at: dict = {}
+        self._accepts = 0
+        # does the kernel fill `TCP_INFO`? One that only has the call
+        # (a sandbox kernel) reads 0 for a listening socket's limit,
+        # and is treated as one without it: no `http.listen_wait`
+        self._tcp_info = bool(_tcp_info(self.socket, _TCPI_SACKED))
+
+    def _phases(self):
+        """The bundle's phase table, None with no bundle."""
+        store = self.store
+        if store is None or store.obs is None:
+            return None
+        return store.obs.phases
+
+    def serve_forever(self, poll_interval=0.5):
+        phases = self._phases()
+        if phases is None:
+            return super().serve_forever(poll_interval)
+        # the accept loop runs on the caller's thread, whatever its name
+        phases.claim_thread("accept_loop_s")
+        try:
+            super().serve_forever(poll_interval)
+        finally:
+            phases.claim_thread(None)
+
+    def _sample_listen_queue(self, phases) -> None:
+        """Just after an accept: is a further connection waiting
+        already (a poll that does not block: any kernel answers it),
+        and how many (`tcpi_unacked`, where the kernel fills it)? On
+        the `http.accept_wait` row's own counts: `listen_samples`,
+        `listen_waiting`, `listen_depth` (a sum), `listen_depth_max`."""
+        adds, maxima = {"listen_samples": 1}, {}
+        try:
+            if select.select((self.socket,), (), (), 0)[0]:
+                adds["listen_waiting"] = 1
+        except (OSError, ValueError):
+            return
+        if self._tcp_info:
+            depth = _tcp_info(self.socket, _TCPI_UNACKED)
+            if depth is not None:
+                adds["listen_depth"] = maxima["listen_depth_max"] = depth
+        phases.tally("http.accept_wait", adds, maxima)
 
     def process_request(self, request, client_address):
         store = self.store
         if store is not None and store.obs is not None:
-            self.accepted_at[request] = time.perf_counter()
+            t = time.perf_counter()
+            n = self._accepts = self._accepts + 1
+            if n % CLOCKED_EVERY:
+                self.accepted_at[request] = t
+            else:
+                ms = _tcp_info(request, _TCPI_LAST_DATA_RECV) \
+                    if self._tcp_info else None
+                self.accepted_at[request] = _Clocked(
+                    t, None if ms is None else ms * 1e-3)
+                if n % LISTEN_SAMPLE_EVERY == 0:
+                    self._sample_listen_queue(store.obs.phases)
         super().process_request(request, client_address)
+
+    def process_request_thread(self, request, client_address):
+        """A clocked connection's thread from its first line to its
+        last: a thread is born with its connection, so ONE
+        `thread_time()` at its end is the CPU of the whole request:
+        start, `setup()`, parse, the handler, `finish()`, the close."""
+        stamp = self.accepted_at.get(request)
+        if stamp.__class__ is not _Clocked:
+            return super().process_request_thread(request, client_address)
+        t_start = time.perf_counter()
+        phases = self._phases()
+        try:
+            super().process_request_thread(request, client_address)
+        finally:
+            if phases is not None:
+                rows = [("http.thread_start", t_start - stamp.at)]
+                root = stamp.root
+                if root is not None and root.done:
+                    # `finish()` and the close: after the root's end
+                    rows.append(("http.thread_after",
+                                 time.perf_counter() - root.t1))
+                rows.append(("http.thread_cpu", time.thread_time()))
+                phases.observe_all(rows)
 
     def shutdown_request(self, request):
         self.accepted_at.pop(request, None)   # never reached a handler
@@ -1759,6 +1904,8 @@ class _Server(ThreadingHTTPServer):
                     store.stop_flusher()
                     store.flush(force=True)
             finally:
+                if store is not None and store.obs is not None:
+                    store.obs.phases.stop_probe()
                 super().server_close()
 
 
@@ -1857,6 +2004,8 @@ def serve(port: int = 8008, data_dir: Optional[str] = None,
         if join_addr:
             node.join_mesh(join_addr)
     store.start_flusher()
+    # `gil.wait`: lives and dies with the server (`server_close`)
+    store.obs.phases.start_probe()
     return httpd
 
 

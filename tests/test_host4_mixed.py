@@ -244,7 +244,9 @@ def test_a_scheduler_without_mesh_windows_writes_none_of_it(tmp_path):
             assert sched.text(doc.id).encode() == doc.text()
         snap = httpd.store.obs.phases.snapshot()
         assert snap["phases"]["sched.flush"]["count"] >= 1
-        assert "counts" not in snap["phases"]["sched.flush"]
+        # its flushes by kind, and none of a window's counts
+        assert set(snap["phases"]["sched.flush"]["counts"]) \
+            <= {"paced", "forced", "inline"}
         new = [n for n in snap["phases"]
                if n.startswith(("mesh.", "window."))]
         assert new == []
@@ -322,10 +324,12 @@ def test_rows_off_home_interconnect_bytes_and_the_arena():
     # with no root open on the thread the rung records nowhere
     for ol, _s in pairs:
         _type(ol, 0, "y")
-    before = table.snapshot()
+    def rows():     # the `cpu` block is read anew at every snapshot
+        return {k: v for k, v in table.snapshot().items() if k != "cpu"}
+    before = rows()
     ok, *_ = pm.mesh_fused_replay(mesh, sessions,
                                   [s.plan_tail() for s in sessions])
-    assert all(ok) and table.snapshot() == before
+    assert all(ok) and rows() == before
 
 
 # ---- several buckets of a shard due at once --------------------------------------

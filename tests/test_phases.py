@@ -308,6 +308,61 @@ def test_an_adopted_histogram_is_exported_not_copied():
     assert (row["count"], row["sum_s"], row["max_s"]) == (2, 0.75, 0.5)
 
 
+def test_observe_all_writes_several_rows_under_one_take_of_the_lock():
+    table = PhaseTable()
+    takes = []
+
+    class Counted:
+        def __init__(self, inner):
+            self.inner = inner
+
+        def __enter__(self):
+            takes.append(1)
+            return self.inner.__enter__()
+
+        def __exit__(self, *exc):
+            return self.inner.__exit__(*exc)
+    table._lock = Counted(table._lock)
+    table.observe_all([("http.thread_start", 0.001),
+                       ("http.thread_after", 0.002),
+                       ("http.thread_cpu", 0.003)])
+    table.observe_all(iter([("http.thread_cpu", 0.005)]))
+    assert len(takes) == 2
+    ph = table.snapshot()["phases"]
+    assert {k: (r["count"], r["sum_s"]) for k, r in ph.items()} == {
+        "http.thread_start": (1, 0.001), "http.thread_after": (1, 0.002),
+        "http.thread_cpu": (2, 0.008)}
+    assert ph["http.thread_cpu"]["max_s"] == 0.005
+
+
+def test_a_tally_sums_its_samples_and_keeps_its_maxima():
+    table = PhaseTable()
+    for depth in (3, 17, 5):
+        table.tally("http.listen_wait",
+                    {"listen_depth": depth, "listen_samples": 1},
+                    {"listen_depth_max": depth})
+    row = table.snapshot()["phases"]["http.listen_wait"]
+    assert row["counts"] == {"listen_depth": 25, "listen_samples": 3,
+                             "listen_depth_max": 17}
+    # samples are no closes of the phase: the row's own count is what
+    # `note` / `observe_all` wrote, and a root's counts land beside them
+    assert row["count"] == 0 and row["sum_s"] == 0.0
+    with table.phase("http.edit") as ph:
+        ph.note("http.listen_wait", 0.004)
+    row = table.snapshot()["phases"]["http.listen_wait"]
+    assert (row["count"], row["sum_s"]) == (1, 0.004)
+    assert row["counts"]["listen_depth_max"] == 17
+
+
+def test_a_closed_phase_knows_when_it_closed():
+    table = PhaseTable()
+    before = time.perf_counter()
+    with table.phase("r") as root:
+        assert not root.done and root.t1 == 0.0
+    assert root.done and before <= root.t0 <= root.t1 <= time.perf_counter()
+    assert NOOP_PHASE.done is False
+
+
 # ---- through a live server ---------------------------------------------------
 
 def _serve(**kw):
@@ -449,7 +504,9 @@ def test_an_autosave_pass_holds_the_store_lock_once_at_its_encode(tmp_path):
         _stop(httpd)
 
 
-def test_exports_metrics_json_obs_snapshot_and_prometheus():
+def test_exports_metrics_json_obs_snapshot_and_prometheus(monkeypatch):
+    from diamond_types_tpu.tools import server as server_mod
+    monkeypatch.setattr(server_mod, "CLOCKED_EVERY", 1)
     httpd, addr = _serve()
     try:
         _edit(addr, "p")
@@ -477,6 +534,21 @@ def test_exports_metrics_json_obs_snapshot_and_prometheus():
             assert want in text, want
         assert text.count("# TYPE dt_phase_total ") == 1
         assert render_metrics({"obs": {"phases": {}}}) == "\n"
+        # a push's rows outside any phase go out the same three ways,
+        # once its thread's last line has written them
+        assert _wait_for(lambda: "http.thread_cpu"
+                         in table.snapshot()["phases"]
+                         and "gil.wait" in table.snapshot()["phases"])
+        mj = httpd.store.scheduler.metrics_json()["phases"]
+        doc = json.loads(_get(addr, "/metrics"))["obs"]["phases"]
+        text = _get(addr, "/metrics?format=prom").decode("utf8")
+        for name in ("http.thread_start", "http.thread_cpu",
+                     "http.thread_after", "gil.wait"):
+            assert name in mj["phases"] and name in doc["phases"], name
+            assert 'dt_phase_seconds_total{phase="%s"} ' % name in text
+        if os.path.isdir("/proc/self/task"):
+            assert mj["cpu"].keys() == doc["cpu"].keys() >= {
+                "process_s", "exited_s", "accept_loop_s", "native_s"}
     finally:
         _stop(httpd)
 
