@@ -1,0 +1,3 @@
+"""`b1-notes.edit-steady`: share of the window's connections that
+were handed to a parked resident handler thread (bench/pool.py)."""
+from bench.pool import pooled_share as read  # noqa: F401

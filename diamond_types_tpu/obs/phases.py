@@ -76,7 +76,8 @@ GIL_PROBE_S = 0.1
 # name (the server names its long-lived threads); the accept loop is
 # whatever thread called `claim_thread("accept_loop_s")`
 CPU_CLASSES = (("merge-pump", "pump_s"), ("flush-worker-", "flush_workers_s"),
-               ("autosave", "autosave_s"), ("gil-probe", "gil_probe_s"))
+               ("autosave", "autosave_s"), ("gil-probe", "gil_probe_s"),
+               ("http-worker-", "http_workers_s"))
 
 _clock = time.perf_counter
 _tls = threading.local()
@@ -637,7 +638,9 @@ class PhaseTable:
         its live threads by class, from `/proc/self/stat` and
         `/proc/self/task/<tid>/stat`; `exited_s` is the process less
         every live thread: the threads that came and went, which for a
-        server are its handler threads. None where `/proc` is not."""
+        server are the handler threads born for one connection (its
+        resident ones are `http_workers_s`). None where `/proc` is
+        not."""
         try:
             tids = os.listdir("/proc/self/task")
             tck = os.sysconf("SC_CLK_TCK")
@@ -653,9 +656,8 @@ class PhaseTable:
 
         names = {t.native_id: t.name for t in threading.enumerate()}
         out = dict.fromkeys(
-            ("process_s", "accept_loop_s", "pump_s", "flush_workers_s",
-             "autosave_s", "gil_probe_s", "live_handlers_s", "native_s"),
-            0.0)
+            ("process_s", "accept_loop_s", "live_handlers_s", "native_s",
+             *(c for _, c in CPU_CLASSES)), 0.0)
         for tid in tids:
             try:
                 s = cpu_s(f"/proc/self/task/{tid}/stat")

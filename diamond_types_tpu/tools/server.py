@@ -122,6 +122,7 @@ import email.parser
 import http.client
 import json
 import os
+import queue
 import re
 import select
 import socket
@@ -129,8 +130,10 @@ import struct
 import sys
 import threading
 import time
+import traceback
 import urllib.parse
 import urllib.request
+from collections import deque
 from http import HTTPStatus
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Dict, Optional
@@ -1756,6 +1759,19 @@ _u32_at = struct.Struct("I").unpack_from
 # counter and a type check more than before these clocks.
 LISTEN_SAMPLE_EVERY = 32
 CLOCKED_EVERY = 8
+# Resident handler threads (`http-worker-<n>`). On the chip's host a
+# thread's birth, and the accept loop's wait for its first breath, is
+# 0.93 of the loop's 1.385 ms a connection, and that cycle is the
+# saturated cells' rate: so the loop hands a connection to a thread
+# that is already there, where one is parked, and gives it a thread of
+# its own only where none is. Small on purpose: the pool is also how
+# many handlers the cheap path can set against the store lock at once
+# (PERF.md section 6 has the chip pairs of 2, 4 and 8).
+HANDLER_THREADS = 4
+# `server_close()` waits this long in all for workers that are inside
+# a connection (one parked ends at once); a worker still inside a
+# long-poll by then ends with it, as a thread born for it would
+WORKERS_JOIN_S = 2.0
 
 
 def _tcp_info(sock, offset: int) -> Optional[int]:
@@ -1802,6 +1818,23 @@ class _Server(ThreadingHTTPServer):
         # handler's first line (thread start, request line, headers)
         self.accepted_at: dict = {}
         self._accepts = 0
+        # the resident handler threads: None until the first
+        # connection, () once closed. A worker that is back from a
+        # connection puts a token on `_parked` BEFORE it blocks on
+        # `_handoff`; the accept loop alone takes tokens, one a
+        # connection it hands over, so a connection on `_handoff` always
+        # has a worker on its way to it. Both are C-level: neither side
+        # waits for the other or takes a Python lock.
+        self._workers = None
+        self._parked = deque()
+        self._handoff = queue.SimpleQueue()
+        self._pool_lock = threading.Lock()    # start and close only
+        # connections handed to a parked worker / given a born thread:
+        # plain integers, the accept loop's own; folded into the
+        # `http.accept_wait` row's counts with the listen queue's
+        # sample and at `server_close()`
+        self.pooled = self.born = 0
+        self._folded = (0, 0)
         # does the kernel fill `TCP_INFO`? One that only has the call
         # (a sandbox kernel) reads 0 for a listening socket's limit,
         # and is treated as one without it: no `http.listen_wait`
@@ -1830,18 +1863,31 @@ class _Server(ThreadingHTTPServer):
         already (a poll that does not block: any kernel answers it),
         and how many (`tcpi_unacked`, where the kernel fills it)? On
         the `http.accept_wait` row's own counts: `listen_samples`,
-        `listen_waiting`, `listen_depth` (a sum), `listen_depth_max`."""
-        adds, maxima = {"listen_samples": 1}, {}
+        `listen_waiting`, `listen_depth` (a sum), `listen_depth_max`;
+        `pooled` / `born` ride with it."""
+        adds, maxima = self._unfolded(), {}
         try:
-            if select.select((self.socket,), (), (), 0)[0]:
-                adds["listen_waiting"] = 1
+            waiting = select.select((self.socket,), (), (), 0)[0]
         except (OSError, ValueError):
-            return
-        if self._tcp_info:
-            depth = _tcp_info(self.socket, _TCPI_UNACKED)
-            if depth is not None:
-                adds["listen_depth"] = maxima["listen_depth_max"] = depth
-        phases.tally("http.accept_wait", adds, maxima)
+            waiting = None
+        if waiting is not None:
+            adds["listen_samples"] = 1
+            if waiting:
+                adds["listen_waiting"] = 1
+            if self._tcp_info:
+                depth = _tcp_info(self.socket, _TCPI_UNACKED)
+                if depth is not None:
+                    adds["listen_depth"] = maxima["listen_depth_max"] = depth
+        if adds:
+            phases.tally("http.accept_wait", adds, maxima)
+
+    def _unfolded(self) -> dict:
+        """`pooled` / `born` since they were last folded into the
+        table, as the adds of a tally."""
+        now = (self.pooled, self.born)
+        was, self._folded = self._folded, now
+        return {k: v - v0 for k, v, v0 in zip(("pooled", "born"), now, was)
+                if v != v0}
 
     def process_request(self, request, client_address):
         store = self.store
@@ -1857,17 +1903,82 @@ class _Server(ThreadingHTTPServer):
                     t, None if ms is None else ms * 1e-3)
                 if n % LISTEN_SAMPLE_EVERY == 0:
                     self._sample_listen_queue(store.obs.phases)
-        super().process_request(request, client_address)
+        parked = self._parked
+        if not parked and self._workers is None:
+            self._start_workers()
+        if parked:
+            parked.pop()
+            self.pooled += 1
+            self._handoff.put((request, client_address))
+        else:
+            # liveness, not speed: a `changes` long-poll or a silent
+            # client holds its thread, so with every worker inside a
+            # connection this one gets a thread of its own, as before
+            self.born += 1
+            super().process_request(request, client_address)
 
-    def process_request_thread(self, request, client_address):
-        """A clocked connection's thread from its first line to its
-        last: a thread is born with its connection, so ONE
-        `thread_time()` at its end is the CPU of the whole request:
-        start, `setup()`, parse, the handler, `finish()`, the close."""
+    def _start_workers(self) -> None:
+        """The first connection starts the pool. A new worker goes
+        straight to `_handoff`, so its first token is put here."""
+        with self._pool_lock:
+            if self._workers is not None:       # closed meanwhile
+                return
+            self._workers = workers = [
+                threading.Thread(target=self._worker_loop,
+                                 args=(self._parked, self._handoff),
+                                 name=f"http-worker-{i}", daemon=True)
+                for i in range(HANDLER_THREADS)]
+            for w in workers:
+                w.start()
+                self._parked.append(None)
+
+    def _worker_loop(self, parked, handoff) -> None:
+        """A resident handler thread: a connection at a time through
+        `process_request_thread`, the path of a born thread, until
+        `server_close()`'s sentinel."""
+        while True:
+            item = handoff.get()
+            if item is None:
+                return
+            try:
+                self.process_request_thread(*item, resident=True)
+            except Exception:
+                # what `process_request_thread` could not handle itself
+                # (its own `handle_error` failing): the connection is
+                # lost, never the worker
+                traceback.print_exc()
+            item = None
+            parked.append(None)
+
+    def _stop_workers(self) -> None:
+        """A sentinel a worker, then wait for them (`WORKERS_JOIN_S`
+        in all). No connection is taken off a worker: one inside a
+        request ends it first."""
+        with self._pool_lock:
+            workers, self._workers = self._workers or (), ()
+            # falsy for good: a connection accepted from here on finds
+            # nobody parked (the workers keep the old deque)
+            self._parked = ()
+        for _ in workers:
+            self._handoff.put(None)
+        deadline = time.monotonic() + WORKERS_JOIN_S
+        for w in workers:
+            w.join(max(0.0, deadline - time.monotonic()))
+
+    def process_request_thread(self, request, client_address,
+                               resident=False):
+        """A clocked connection on its thread, from the thread's first
+        line ON THIS CONNECTION (a born thread's first breath, a parked
+        worker's wake) to its last. `http.thread_cpu` is the CPU of
+        this connection: a born thread's whole life (start, `setup()`,
+        parse, the handler, `finish()`, the close) in ONE
+        `thread_time()` at its end, a resident thread's difference of
+        two."""
         stamp = self.accepted_at.get(request)
         if stamp.__class__ is not _Clocked:
             return super().process_request_thread(request, client_address)
         t_start = time.perf_counter()
+        cpu0 = time.thread_time() if resident else 0.0
         phases = self._phases()
         try:
             super().process_request_thread(request, client_address)
@@ -1879,7 +1990,7 @@ class _Server(ThreadingHTTPServer):
                     # `finish()` and the close: after the root's end
                     rows.append(("http.thread_after",
                                  time.perf_counter() - root.t1))
-                rows.append(("http.thread_cpu", time.thread_time()))
+                rows.append(("http.thread_cpu", time.thread_time() - cpu0))
                 phases.observe_all(rows)
 
     def shutdown_request(self, request):
@@ -1907,6 +2018,11 @@ class _Server(ThreadingHTTPServer):
                 if store is not None and store.obs is not None:
                     store.obs.phases.stop_probe()
                 super().server_close()
+                self._stop_workers()
+                phases = self._phases()
+                counts = self._unfolded()
+                if phases is not None and counts:
+                    phases.tally("http.accept_wait", counts, {})
 
 
 def serve(port: int = 8008, data_dir: Optional[str] = None,

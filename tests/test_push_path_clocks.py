@@ -30,7 +30,7 @@ pytestmark = pytest.mark.obs
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CPU_LIVE = ("accept_loop_s", "pump_s", "flush_workers_s", "autosave_s",
-            "gil_probe_s", "live_handlers_s", "native_s")
+            "gil_probe_s", "http_workers_s", "live_handlers_s", "native_s")
 needs_tcp_info = pytest.mark.skipif(
     getattr(socket, "TCP_INFO", None) is None,
     reason="no TCP_INFO on this platform")
@@ -102,6 +102,13 @@ def _wait_for(cond, timeout=10.0):
 
 def _count(httpd, name: str) -> int:
     return _rows(httpd).get(name, {}).get("count", 0)
+
+
+def _listen_counts(httpd) -> dict:
+    """The listening socket's samples on `http.accept_wait`'s counts
+    (`pooled` / `born` ride on the same row: test_handler_pool.py)."""
+    return {k: v for k, v in _rows(httpd)["http.accept_wait"]
+            .get("counts", {}).items() if k.startswith("listen")}
 
 
 # ---- the kernel's queue --------------------------------------------------------
@@ -205,13 +212,13 @@ def test_without_tcp_info_the_queue_is_polled_and_no_row_is_written(
         rows = _rows(httpd)
         assert "http.listen_wait" not in rows
         assert rows["http.accept_wait"]["count"] == 4
-        assert rows["http.accept_wait"]["counts"] == {
+        assert _listen_counts(httpd) == {
             "listen_samples": 1, "listen_waiting": 1}
         # an idle server's sample finds nobody waiting
         httpd._accepts = server_mod.LISTEN_SAMPLE_EVERY - 1
         assert b"200" in _edit(addr, "n")
         assert _wait_for(lambda: _count(httpd, "http.edit") == 5)
-        assert _rows(httpd)["http.accept_wait"]["counts"] == {
+        assert _listen_counts(httpd) == {
             "listen_samples": 2, "listen_waiting": 1}
     finally:
         _stop(httpd)
@@ -335,9 +342,14 @@ def test_a_slow_request_says_what_it_waited_in_the_listen_queue(
 
 # ---- CPU by thread class -----------------------------------------------------------
 
-def test_the_cpu_block_adds_up_and_a_burst_raises_exited(every_thread):
+@pytest.mark.parametrize("threads,burnt_in", [
+    ("resident", "http_workers_s"), ("born", "exited_s")])
+def test_the_cpu_block_adds_up_and_a_burst_raises_its_threads_class(
+        every_thread, monkeypatch, threads, burnt_in):
     if not os.path.isdir("/proc/self/task"):
         pytest.skip("no /proc here")
+    if threads == "born":       # nobody is ever parked: a thread each
+        monkeypatch.setattr(server_mod, "HANDLER_THREADS", 0)
     httpd, addr = _serve()
     try:
         _edit(addr, "b")
@@ -356,14 +368,19 @@ def test_the_cpu_block_adds_up_and_a_burst_raises_exited(every_thread):
             assert live + cpu["exited_s"] == pytest.approx(
                 cpu["process_s"], rel=0.02)
         assert all(cpu1[k] >= cpu0[k] for k in CPU_LIVE)
-        # the 200 handler threads came and went: what they burnt is in
-        # no live thread's seconds, and `http.thread_cpu` summed it
-        # from inside (ticks of 10 ms on the one side)
+        # what the 200 connections' threads burnt is in the resident
+        # workers' seconds, or, where the threads came and went, in no
+        # live thread's; `http.thread_cpu` summed it from inside, a
+        # connection at a time (ticks of 10 ms on the one side)
         inside = after["phases"]["http.thread_cpu"]["sum_s"] \
             - before["phases"]["http.thread_cpu"]["sum_s"]
-        gone = cpu1["exited_s"] - cpu0["exited_s"]
+        burnt = cpu1[burnt_in] - cpu0[burnt_in]
         assert inside > 0.02
-        assert 0.5 * inside - 0.03 <= gone <= 2.0 * inside + 0.1
+        assert 0.5 * inside - 0.03 <= burnt <= 2.0 * inside + 0.1
+        if threads == "born":
+            assert cpu1["http_workers_s"] == 0.0
+        assert (httpd.pooled, httpd.born) == (
+            (201, 0) if threads == "resident" else (0, 201))
         # the same block through the scheduler's export
         assert set(httpd.store.scheduler.metrics_json()["phases"]["cpu"]) \
             == set(cpu0)
