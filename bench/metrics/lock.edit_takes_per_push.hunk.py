@@ -1,0 +1,3 @@
+"""`a2-sources.hunk-sat`: acquisitions of `DocStore.lock` a push
+makes on the edit path (bench/takes.py)."""
+from bench.takes import edit_takes_per_push as read  # noqa: F401

@@ -267,9 +267,17 @@ class DocStore:
             return c
 
     def notify(self, doc_id: str) -> None:
-        c = self.cond(doc_id)
-        with c:
-            c.notify_all()
+        """Wake the document's long-polls, where it has any. Called
+        after the mutation's hold of the store lock and without it: a
+        `changes` long-poll makes the document's condition (`cond`)
+        BEFORE it checks the oplog under the store lock, so a mutation
+        that finds no condition here has no waiter to wake, and a poll
+        that arrives later reads the mutation in its own check. No
+        condition is made for a document nobody polls."""
+        c = self._conds.get(doc_id)
+        if c is not None:
+            with c:
+                c.notify_all()
 
     def _path(self, doc_id: str) -> Optional[str]:
         if self.data_dir is None:
@@ -289,6 +297,14 @@ class DocStore:
         return sorted(ids)
 
     def get(self, doc_id: str) -> OpLog:
+        """The document's oplog. A resident one is one `dict.get`,
+        without the store lock: nothing is ever taken out of
+        `self.docs`. A miss loads or creates under the lock and looks
+        again there first, so two first requests for an unknown
+        document still make one `OpLog`."""
+        ol = self.docs.get(doc_id)
+        if ol is not None:
+            return ol
         with self.lock:
             ol = self.docs.get(doc_id)
             if ol is None:
@@ -304,12 +320,18 @@ class DocStore:
 
     def mark_dirty(self, doc_id: str) -> None:
         with self.lock:
-            now = time.monotonic()
-            t = self.dirty.setdefault(doc_id, now)
-            if t > now:
-                # the doc was in encode-failure backoff; a new edit
-                # changed its content, so a prompt retry is worth it
-                self.dirty[doc_id] = now
+            self.mark_dirty_locked(doc_id)
+
+    def mark_dirty_locked(self, doc_id: str) -> None:
+        """`mark_dirty` for a caller that holds self.lock (which is not
+        reentrant): an edit marks its document in the hold that put it
+        into the oplog, so no autosave pass falls between the two."""
+        now = time.monotonic()
+        t = self.dirty.setdefault(doc_id, now)
+        if t > now:
+            # the doc was in encode-failure backoff; a new edit
+            # changed its content, so a prompt retry is worth it
+            self.dirty[doc_id] = now
 
     def flush(self, force: bool = False) -> None:
         if self.data_dir is None:
@@ -1602,8 +1624,11 @@ class SyncHandler(BaseHTTPRequestHandler):
                     frontier = [lv]
                 ol.remember_length(frontier, blen)
                 out = ol.cg.local_to_remote_frontier(frontier)
+                self.store.mark_dirty_locked(doc_id)
+            # the push's ONE wait for the store lock is behind it: the
+            # document came without it (`store.get`) and `notify` takes
+            # the document's condition alone, where it has one
             ph.step("edit.publish")
-            self.store.mark_dirty(doc_id)
             self.store.notify(doc_id)
             if self.store.reads is not None:
                 self.store.reads.on_local_mutation(doc_id)
@@ -1632,7 +1657,8 @@ class SyncHandler(BaseHTTPRequestHandler):
             c = self.store.cond(doc_id)
             # The condition is held around BOTH the emptiness check and the
             # wait (notify_all also runs under it), so a notify can never
-            # land in between and be lost.
+            # land in between and be lost. It is made BEFORE the check:
+            # `DocStore.notify` wakes only a condition that exists.
             with c:
                 while True:
                     with self.store.lock:
@@ -1681,7 +1707,7 @@ class SyncHandler(BaseHTTPRequestHandler):
                 if applied:
                     # ops before a mid-batch failure ARE in the log;
                     # flusher + long-pollers must see them either way
-                    # (both helpers take store.lock themselves)
+                    # (mark_dirty takes store.lock itself)
                     self.store.mark_dirty(doc_id)
                     self.store.notify(doc_id)
                     if self.store.reads is not None:
