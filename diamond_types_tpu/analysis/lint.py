@@ -22,7 +22,7 @@ serve/README.md documents under "Concurrency invariants":
 
 The engine is two-pass: pass 1 builds a cross-file call summary (which
 function names transitively dispatch to the device, which contain a
-fencing check) so one-hop indirection like `bank.text -> sync_doc`
+fencing check) so one-hop indirection like `read_tip -> sync_docs`
 is visible; pass 2 runs the rules per file.
 
 Suppressions (documented in serve/README.md):

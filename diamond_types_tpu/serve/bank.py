@@ -73,7 +73,7 @@ from typing import Dict, List, Optional
 
 from ..obs.devprof import PROFILER
 from ..obs.phases import phase
-from ..tpu.flush_fuse import FenceFailure
+from ..tpu.flush_fuse import FenceFailure, decode_row
 from ..tpu.runtime import first_touch
 from .metrics import ServeMetrics
 
@@ -489,7 +489,8 @@ class SessionBank:
         return out
 
     def sync_docs(self, items, resolve,
-                  oplog_lock=None, device_lock=None) -> dict:
+                  oplog_lock=None, device_lock=None,
+                  min_fuse: int = 2) -> dict:
         """Flush one taken bucket: plan, replay, adopt (module
         docstring). `items` are admission
         PendingMerge rows; `resolve(doc_id) -> OpLog` is called OUTSIDE
@@ -497,7 +498,10 @@ class SessionBank:
 
         Lock discipline: `oplog_lock` around host-side phases (build,
         plan, fallback bookkeeping), `device_lock` around the fused
-        device replay only — see the module docstring.
+        device replay only — see the module docstring. `min_fuse=1`
+        (a read's own flush) replays a lone document as a group of
+        one, so that it does not take the per-doc ladder, which waits
+        for the device under `oplog_lock`.
 
         Returns {"docs", "fused_calls", "fused_docs", "fallback_docs",
         "device_s"}: the last the seconds the fused calls waited for
@@ -505,7 +509,8 @@ class SessionBank:
         """
         dlock = device_lock if device_lock is not None \
             else contextlib.nullcontext()
-        win = self.plan_window(items, resolve, oplog_lock=oplog_lock)
+        win = self.plan_window(items, resolve, oplog_lock=oplog_lock,
+                               min_fuse=min_fuse)
         fused_calls = fused_docs = 0
         device_total = 0.0
         # ---- device phase: one jitted call per fused group, under the
@@ -622,32 +627,19 @@ class SessionBank:
         ) for grp in by_shape.values()]
         return serial, groups
 
-    def text(self, doc_id: str, oplog, oplog_lock=None,
-             device_lock=None) -> str:
-        """Merged text for the doc — from the resident session when it
-        is caught up with the durable oplog (device parity surface),
-        host checkout otherwise; `reads_from_device` / `reads_from_host`
-        count which, so a parity check can tell a device answer from
-        the oracle answering for it. Lock discipline matches the flush
-        phases: host-side reads (session table, oplog checkout) under
-        `oplog_lock`; the device fetch under `device_lock` only. A read
-        never issues device work while holding the oplog guard — a
-        stale session serves the durable tip and the flush pipeline
-        catches it up off the read path."""
-        olock = oplog_lock if oplog_lock is not None \
-            else contextlib.nullcontext()
+    def read_row(self, sess, device_lock=None):
+        """A resident session's text and frontier (local versions), as
+        ONE commit left them: the fetch of `MergeScheduler.read_tip`,
+        for a session the scheduler has brought to the tip. Every
+        commit that moves the row runs under `device_lock` (the
+        replay's adoption, the per-doc ladder), so the row, its length
+        and its frontier are read under it and belong together; the
+        transfer of the whole row is waited for there too, and no
+        other lock is held. Counted `reads_from_device`."""
         dlock = device_lock if device_lock is not None \
             else contextlib.nullcontext()
-        with olock:
-            sess = self.sessions.get(doc_id)
-            if sess is None \
-                    or getattr(sess, "synced_to", 0) < len(oplog):
-                self._bump("reads_from_host")
-                return oplog.checkout_tip().snapshot()
-            if self.engine == "host":
-                # host sessions read the oplog itself; stay guarded
-                self._bump("reads_from_host")
-                return sess.text()
-        self._bump("reads_from_device")
         with dlock:
-            return sess.text()
+            frontier = sess.frontier
+            text = decode_row(sess.docs, sess.doc_len)
+        self._bump("reads_from_device")
+        return text, frontier

@@ -64,9 +64,9 @@ _SHARD_KEYS = ("submits", "coalesced", "rejects", "denied", "fenced",
                # flight-recorder event carrying the error text; all three
                # are 0 on a healthy server.
                "device_errors", "warmup_errors", "pump_errors",
-               # bank.text() answers by source: the resident device
-               # session, or the host checkout (host engine, session
-               # missing or behind the oplog, not the doc's owner)
+               # a read at the tip (`MergeScheduler.read_tip`) by source:
+               # the resident device session, or the host checkout (host
+               # engine, no session, not the doc's owner)
                "reads_from_device", "reads_from_host")
 
 # the residency tier's counter set (serve.hydrate.Hydrator feeds these
@@ -148,7 +148,7 @@ class ServeMetrics:
     # with the behaviour it counted (a rung that raises now propagates),
     # `device_errors` / `warmup_errors` / `pump_errors` count what used
     # to be swallowed, `reads_from_device` / `reads_from_host` say
-    # where bank.text() answered from, and `window.shape_classes`
+    # where a read at the tip was answered from, and `window.shape_classes`
     # counts the (cap, max_ins) classes the mesh windows held;
     # v15 = the `transform` block left with the device-plan transform
     # it counted: every tail is planned by

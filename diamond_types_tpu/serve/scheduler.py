@@ -78,6 +78,12 @@ from .router import ShardRouter
 # forced one (drain, shutdown: somebody waits for it) is not either.
 FLUSH_HOST_SHARE = 8
 
+# The longest a read waits for a flush in flight that carries its
+# document's items before it plans and replays the tail itself
+# (`_sync_for_read`): that flush takes the shard's lock too, so it is
+# a wait all the same, and one that ends where the other flush died.
+READ_WAIT_S = 1.0
+
 
 class MergeScheduler:
     def __init__(self, n_shards: int,
@@ -95,7 +101,8 @@ class MergeScheduler:
                  flush_workers: bool = True,
                  warmup: bool = False,
                  mesh_window: bool = False,
-                 mesh_window_rows: Optional[int] = None) -> None:
+                 mesh_window_rows: Optional[int] = None,
+                 reads: str = "host") -> None:
         """`resolve(doc_id) -> OpLog` is the document authority —
         DocStore.get fits directly. `sync_lock` (e.g. DocStore.lock) is
         the OPLOG guard: held around host-side oplog reads (session
@@ -117,11 +124,24 @@ class MergeScheduler:
         see `_flush_window`; `mesh_window_rows` is the most rows of one
         class that go out in one such program (default `n_shards x
         flush_docs`, one bucket a shard: a deployment states it when
-        its warm-up is built around it). What follows a replay
+        its warm-up is built around it). `reads` says where the served
+        `GET /doc/{id}` at the tip is answered, and is part of what a
+        deployment states: `"host"` (the default) checks the oplog out
+        under the store lock, so the body shares nothing with the
+        device row it is compared with; `"device"` answers from the
+        document's session, brought to the oplog's tip first
+        (`read_tip`), and leaves the host what has no session. It
+        changes nothing else: `text()` is `read_tip` under either,
+        the flush path and the guarantees are the same, and any other
+        value raises `ValueError`. What follows a replay
         (per-doc → host) answers DATA faults only — an overflowing
         tail, a poisoned or drifting length. A replay that raises is
         counted (`device_errors`), recorded and re-raised: see
         serve/bank.py."""
+        if reads not in ("host", "device"):
+            raise ValueError(
+                f"reads={reads!r}: \"host\" or \"device\"")
+        self.reads = reads
         self.resolve = resolve
         self._sync_lock = sync_lock if sync_lock is not None \
             else contextlib.nullcontext()
@@ -215,6 +235,9 @@ class MergeScheduler:
         # batches handed to each shard's worker and not yet flushed
         self._busy: List[int] = [0] * n_shards
         self._idle_cv = threading.Condition()
+        # doc_id -> (frontier, its agents' names) of the last served
+        # read (`read_tip`): a frontier is named once a commit
+        self._read_frontiers: Dict[str, tuple] = {}
 
     def attach_obs(self, obs) -> None:
         """Wire an obs.Observability bundle into the admit→flush path:
@@ -591,7 +614,8 @@ class MergeScheduler:
                 "flush_gate_dropped", shard=shard, docs=len(dropped))
         return keep
 
-    def _flush_items(self, shard: int, reason: str, items) -> tuple:
+    def _flush_items(self, shard: int, reason: str, items,
+                     min_fuse: int = 2) -> tuple:
         """Sync one taken batch into its shard's bank, under that
         shard's lock only (items are already off the queue, so a
         concurrent submit for the same doc simply queues fresh work).
@@ -653,7 +677,8 @@ class MergeScheduler:
                     res = bank.sync_docs(
                         items, self._flush_resolve,
                         oplog_lock=self._sync_lock,
-                        device_lock=self._device_locks[shard])
+                        device_lock=self._device_locks[shard],
+                        min_fuse=min_fuse)
                     dspan.end(fused_calls=res["fused_calls"],
                               fused_docs=res["fused_docs"])
             dur = time.perf_counter() - t0
@@ -935,38 +960,119 @@ class MergeScheduler:
     # ---- reads / control -------------------------------------------------
 
     def text(self, doc_id: str) -> str:
-        """Merged text from the doc's shard (device-resident state when
-        present). Pending queued work for the doc is flushed first so
-        the answer reflects every accepted submit. Reads never dispatch
-        device work under the oplog guard: a session behind the durable
-        oplog serves the oplog's tip snapshot instead, and the flush
-        pipeline catches it up off the read path."""
-        with self.lock:
-            shard = self.router.assign(doc_id)
-            bucket = self.queue.pending_bucket(shard, doc_id)
-            items = []
-            if bucket is not None:
-                # flush the doc's whole bucket (its neighbors share the
-                # shape anyway), counted as a read-triggered flush
-                items = self.queue.take(shard, bucket,
-                                        limit=self.queue.max_pending)
-        if items:
-            self._flush_items(shard, "read", items)
-            with self.lock:
-                self.metrics.observe_queue(shard,
-                                           self.queue.depth(shard))
+        """Merged text at the oplog's tip: `read_tip`'s answer, the
+        document's device session brought to the tip first, and the
+        host checkout (counted `reads_from_host` there) only where
+        that gives none."""
+        got = self.read_tip(doc_id)
+        if got is not None:
+            return got[0]
+        ol = self.resolve(doc_id)       # outside the oplog guard
+        with self._sync_lock:
+            return ol.checkout_tip().snapshot()
+
+    def read_tip(self, doc_id: str, ph=NOOP_PHASE):
+        """The document at its oplog's tip, from its device session:
+        `text()`, and the served `GET /doc/{id}` of a scheduler built
+        with `reads="device"`. Returns (text, the
+        session's frontier as [(agent, seq)]), or None where the host
+        has to answer: no resident device session (never built,
+        evicted, a poisoned row), a host engine, an owner that is not
+        admitted. That is counted `reads_from_host` here; the caller
+        checks the oplog out.
+
+        The oplog's length is noted first, so every edit acknowledged
+        before the read came is below it. A session behind that length
+        is brought to it on this thread (step `get.sync` of `ph`, the
+        request's root): the document's pending bucket is taken and
+        flushed inline with reason "read", a lone document as a group
+        of one (`min_fuse=1`: not the per-doc ladder, which waits for
+        the device under the oplog guard); where the shard's worker or
+        a mesh window already carries the items, that flush is waited
+        for; and where nothing is queued or in flight (the edit's own
+        submit is still to come, or was refused) the tail is planned
+        and replayed here all the same. A flush that raises raises out
+        of the read: a device that fails is never answered by a stale
+        row, nor quietly by the host. The fetch (step `get.fetch`) is
+        `SessionBank.read_row`, under the shard's device lock alone.
+        The oplog guard is held only to name the frontier's agents,
+        once a commit of the session. `ph` counts `at_tip` where no
+        sync was needed."""
         ol = self.resolve(doc_id)
-        # cross-host ownership gate: a deposed or never-owner host must
-        # not serve (or refresh) its device session for the doc — the
-        # durable oplog is the only truth it still holds
-        if self.admit is not None and not self.admit(doc_id):
+        shard = self.router.assignments.get(doc_id)
+        if shard is None:
+            with self.lock:
+                shard = self.router.assign(doc_id)
+        bank = self.banks[shard]
+        n = len(ol)
+
+        def at_tip():
+            s = bank.sessions.get(doc_id)
+            return s if s is not None and s.oplog is ol \
+                and s.synced_to >= n else None
+
+        if bank.engine == "host" or doc_id not in bank.sessions \
+                or (self.admit is not None and not self.admit(doc_id)):
             self.metrics.bump(shard, "reads_from_host")
+            return None
+        sess = at_tip()
+        if sess is not None:
+            ph.count("at_tip")
+        else:
+            ph.step("get.sync")
+            self._sync_for_read(shard, doc_id, at_tip)
+            sess = at_tip()
+            if sess is None:
+                # evicted by the flush (its row came back poisoned, the
+                # bank ran out of room) or dropped from it (a fenced
+                # lease, a cold document): the oplog answers
+                self.metrics.bump(shard, "reads_from_host")
+                return None
+        ph.step("get.fetch")
+        text, frontier = bank.read_row(sess, self._device_locks[shard])
+        named = self._read_frontiers.get(doc_id)
+        if named is None or named[0] != frontier:
             with self._sync_lock:
-                return ol.checkout_tip().snapshot()
-        with self._shard_locks[shard]:
-            return self.banks[shard].text(
-                doc_id, ol, oplog_lock=self._sync_lock,
-                device_lock=self._device_locks[shard])
+                named = self._read_frontiers[doc_id] = (
+                    frontier, ol.cg.local_to_remote_frontier(frontier))
+        return text, named[1]
+
+    def _sync_for_read(self, shard: int, doc_id: str, at_tip) -> None:
+        """Bring `doc_id`'s session to the length `at_tip` checks, on
+        the reading thread (`read_tip`)."""
+        def take():
+            with self.lock:
+                bucket = self.queue.pending_bucket(shard, doc_id)
+                return [] if bucket is None else self.queue.take(
+                    shard, bucket, limit=self.queue.max_pending)
+
+        items = take()
+        if not items:
+            # taken already: by the shard's worker, or by the pump for
+            # a mesh window. Its flush is waited for, not raced
+            deadline = time.monotonic() + READ_WAIT_S
+            with self._idle_cv:
+                while at_tip() is None and time.monotonic() < deadline \
+                        and (self._inflight if self.mesh_window
+                             else self._busy[shard]):
+                    self._idle_cv.wait(timeout=0.05)
+            if at_tip() is not None:
+                return
+            # nothing queued and nothing in flight (or the wait was
+            # long): whatever has been queued since, or the tail alone
+            from .admission import PendingMerge
+            items = take() or [PendingMerge(
+                doc_id, 0, time.monotonic(),
+                epoch=self.epoch_of(doc_id)
+                if self.epoch_of is not None else -1)]
+        step = self.queue.flush_docs
+        for lo in range(0, len(items), step):
+            # a bucket's neighbours share the shape; no batch larger
+            # than a paced flush's, so no class the warm-up did not see
+            self._flush_items(shard, "read", items[lo:lo + step],
+                              min_fuse=1)
+        with self.lock:
+            self.metrics.observe_queue(shard, self.queue.depth(shard))
 
     def rebalance(self, n_shards: int) -> Dict[str, tuple]:
         """Shrink (or restore) the live shard count: drain pending work,

@@ -1178,6 +1178,31 @@ class SyncHandler(BaseHTTPRequestHandler):
             # materialized here must not mint an empty oplog for it
             return self._doc_snapshot(doc_id, no_store)
         ph = self._phase
+        sched = self.store.scheduler
+        if action == "" and sched is not None \
+                and sched.reads == "device":
+            # the tip, from the document's device session: its row
+            # and its frontier (steps `get.sync`, `get.fetch`); None
+            # where the host has to answer
+            try:
+                got = sched.read_tip(doc_id, ph)
+            except Exception as e:
+                # a session that cannot be brought to the tip (the
+                # bank has counted and recorded why): an error, never
+                # a stale row and never a quiet host answer
+                self._send(500, json.dumps(
+                    {"error": "device read failed", "detail":
+                     f"{e.__class__.__name__}: {e}"[:400]}).encode())
+                raise
+            if got is not None:
+                ph.step("get.respond")
+                body = got[0].encode("utf8")
+                ph.count("device")
+                return self._send(200, body, "text/plain; charset=utf-8",
+                                  extra={**no_store,
+                                         "X-DT-Frontier":
+                                         json.dumps(got[1])})
+            ph.count("host")
         ph.step("get.checkout")
         ol = self.store.get(doc_id)
         if action == "":
@@ -2086,8 +2111,19 @@ def serve(port: int = 8008, data_dir: Optional[str] = None,
     host core: a failed build or load raises (native.require_native)
     unless DT_TPU_NO_NATIVE=1 asked for the pure-Python engine.
 
-    GET /doc/{id} answers from the host checkout under either engine;
-    the device state is read through `scheduler.text()`."""
+    `GET /doc/{id}` at the tip is answered where the scheduler was
+    told to (`sched_opts["reads"]`, a `MergeScheduler` argument).
+    `"host"`, the default: the host checkout under the store lock
+    (step `get.checkout`) under either engine; the device state is
+    read through `scheduler.text()`. `"device"`: the document's device
+    session, brought to the oplog's tip first
+    (`MergeScheduler.read_tip`: root `http.get`, steps `get.sync`,
+    `get.fetch`, `get.respond`; `X-DT-Frontier` is the session's), and
+    the host checkout (counted `reads_from_host`) only where the
+    document has no device session: a host engine, a document never
+    merged or evicted, an owner not admitted. `/state`, `/summary` and
+    the read contract's path (`follower_reads`, which stays in front)
+    are the host's under both."""
     from ..native import require_native
     from ..obs import Observability
     require_native()

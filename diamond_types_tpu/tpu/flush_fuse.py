@@ -591,9 +591,7 @@ class FusedDocSession:
         """Fetch and decode the merged document (device parity
         surface: the answer comes from the replay kernel's state, not
         the host tracker)."""
-        n = self.doc_len
-        return np.asarray(self.docs[:n]).astype(np.int32).tobytes() \
-            .decode("utf-32-le")
+        return decode_row(self.docs, self.doc_len)
 
     def touch(self):
         return np.asarray(self.lens)
@@ -601,6 +599,14 @@ class FusedDocSession:
     def footprint_slots(self) -> int:
         """Device residency in int32 slots: the doc buffer dominates."""
         return int(self.cap)
+
+
+def decode_row(docs, n: int) -> str:
+    """The first `n` characters of a resident `[cap]` row: the whole
+    row is fetched and cut on the host, a plain transfer whatever the
+    length. (A slice on the device, `docs[:n]`, is one executable a
+    document length.)"""
+    return np.asarray(docs)[:n].tobytes().decode("utf-32-le")
 
 
 def pack_plans(plans: Sequence[TailPlan], n: int, mi: int,

@@ -195,9 +195,9 @@ def test_bank_lru_eviction_accounting():
     # the evicted doc rebuilds on its next merge
     bank.sync_doc("b", ols["b"])
     assert m.shard[0]["builds"] == 4 and m.shard[0]["evictions"] == 2
-    # text still correct for everything, resident or not
-    for d, ol in ols.items():
-        assert bank.text(d, ol) == ol.checkout_tip().snapshot()
+    # every resident session still holds its document
+    for d, sess in bank.sessions.items():
+        assert sess.text() == ols[d].checkout_tip().snapshot()
 
 
 def test_bank_slot_budget_eviction_device():
@@ -213,7 +213,7 @@ def test_bank_slot_budget_eviction_device():
     bank.sync_doc("b", ols["b"])
     assert set(bank.sessions) == {"b"}
     assert m.shard[0]["evictions"] == 1
-    assert bank.text("a", ols["a"]) == "hello"
+    assert bank.read_row(bank.sessions["b"])[0] == "hello"
 
 
 def test_bank_host_fallback_on_fence_failure(monkeypatch):
@@ -237,8 +237,6 @@ def test_bank_host_fallback_on_fence_failure(monkeypatch):
     assert m.shard[0]["host_fallbacks"] == 1
     assert m.shard[0]["device_errors"] == 0
     assert bank.sessions == {}       # broken session evicted
-    assert bank.text("a", ol) == "hello"
-    assert m.shard[0]["reads_from_host"] == 1
 
 
 def test_bank_device_failure_is_not_a_fallback(monkeypatch):
@@ -278,28 +276,32 @@ def test_bank_device_failure_is_not_a_fallback(monkeypatch):
             if e["kind"] == "device_error"] == ["per_doc", "build"]
 
 
-def test_bank_reads_counted_by_source():
-    """bank.text() says where it answered from: the resident device
-    session only when it is caught up with the oplog."""
-    m = ServeMetrics(1, flush_docs=4, max_pending=16)
-    bank = SessionBank(0, engine="device", metrics=m)
-    ol = _mk_oplog("a")
-    assert bank.text("a", ol) == "hello"             # no session yet
+def test_reads_counted_by_source():
+    """A read at the tip says where it was answered from: the resident
+    device session, brought to the oplog's tip where it was behind; the
+    host where there is no session, or a host engine."""
+    ols = {"a": _mk_oplog("a")}
+    sched = MergeScheduler(1, resolve=ols.__getitem__, engine="device",
+                           flush_workers=False)
+    m, ol = sched.metrics, ols["a"]
+    assert sched.text("a") == "hello"                # no session yet
     assert (m.shard[0]["reads_from_host"],
             m.shard[0]["reads_from_device"]) == (1, 0)
-    bank.sync_doc("a", ol)
-    assert bank.text("a", ol) == "hello"             # resident, synced
+    sched.banks[0].sync_doc("a", ol)
+    assert sched.text("a") == "hello"                # resident, synced
     assert (m.shard[0]["reads_from_host"],
             m.shard[0]["reads_from_device"]) == (1, 1)
     a = ol.get_or_create_agent_id("alice")
     ol.add_insert(a, 5, "!")
-    assert bank.text("a", ol) == "hello!"            # session behind
+    assert sched.read_tip("a")[0] == "hello!"        # behind: synced first
+    assert sched.banks[0].sessions["a"].synced_to == len(ol)
     assert (m.shard[0]["reads_from_host"],
-            m.shard[0]["reads_from_device"]) == (2, 1)
-    host = SessionBank(0, engine="host", metrics=m)
-    host.sync_doc("a", ol)
-    assert host.text("a", ol) == "hello!"
-    assert m.shard[0]["reads_from_host"] == 3
+            m.shard[0]["reads_from_device"]) == (1, 2)
+    host = MergeScheduler(1, resolve=ols.__getitem__, engine="host",
+                          flush_workers=False)
+    host.banks[0].sync_doc("a", ol)
+    assert host.read_tip("a") is None and host.text("a") == "hello!"
+    assert host.metrics.shard[0]["reads_from_host"] == 2
 
 
 # ---- one flush path: what selects the replay, what is refused -----------------
@@ -389,17 +391,20 @@ def test_scheduler_host_end_to_end_with_rebalance():
 
 
 def test_scheduler_read_flushes_pending():
-    ol = _mk_oplog("d0", "")
+    ol = _mk_oplog("d0", "abc")
     agent = ol.get_or_create_agent_id("w")
-    sched = MergeScheduler(2, resolve=lambda d: ol, engine="host",
+    sched = MergeScheduler(2, resolve=lambda d: ol, engine="device",
                            flush_docs=100, flush_deadline_s=60.0)
+    assert sched.submit("d0")["accepted"]
+    sched.drain()                       # resident, at the tip
     ol.add_insert_at(agent, list(ol.version), 0, "xyz")
     assert sched.submit("d0")["accepted"]
     # no pump ran — the read itself must flush the doc's bucket
-    assert sched.text("d0") == "xyz"
+    assert sched.text("d0") == "xyzabc"
     snap = sched.metrics_json()
     assert snap["flush_reasons"].get("read", 0) == 1
-    assert snap["totals"]["flushed_docs"] == 1
+    assert snap["totals"]["flushed_docs"] == 2
+    assert snap["totals"]["reads_from_device"] == 1
 
 
 # ---- one batch a shard, and a worker that paces its host work -------------
