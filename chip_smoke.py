@@ -23,7 +23,8 @@ scheduler's device engine flushing to the chip(s) this process owns:
             must read back, and the rounds repeat through the mesh rung
             — one length class per window first (one shape class
             each), then the mixed fleet (a dispatch a shape class, one
-            more for every `mesh_window_rows` rows of a class beyond)
+            more whenever a chip's block of a class would pass
+            `mesh_window_rows / chips` rows)
   kernels   again, at the largest batch shapes the traffic dispatched
 
 Every check is fatal: a non-zero exit with the reason on the last lines
@@ -476,11 +477,12 @@ def check_phase_end(name: str, fleet, sched, m: dict, mesh: bool,
 def check_round_windows(name: str, r: int, cfg, m0: dict, m1: dict,
                         mixed: bool, max_rows: int, row: dict) -> None:
     """(f) the mesh rung's dispatch count, per round: a window takes one
-    program a shape class it holds, and one more only for every
-    `mesh_window_rows` (`max_rows`) rows of a class beyond the first
-    (several buckets of a shard due at once: `_flush_window`). A
-    uniform-shape window holds exactly one class; a mixed wave must
-    produce windows that hold both."""
+    program a shape class it holds, and one more only when a chip's
+    block of the class passes its share of `mesh_window_rows`
+    (`max_rows`: the rows a chip at most in a dispatch; several
+    buckets of a shard due at once: `_flush_window`). A uniform-shape
+    window holds exactly one class; a mixed wave must produce windows
+    that hold both."""
     d = {k: m1["window"][k] - m0["window"][k]
          for k in ("device_windows", "dispatches", "shape_classes",
                    "mesh_docs")}
@@ -491,7 +493,7 @@ def check_round_windows(name: str, r: int, cfg, m0: dict, m1: dict,
             <= d["shape_classes"] + d["mesh_docs"] // max_rows,
             f"{name} round {r}: {d['dispatches']} dispatches for "
             f"{d['shape_classes']} shape classes and {d['mesh_docs']} "
-            f"rows at {max_rows} rows a dispatch")
+            f"rows at {max_rows} rows a chip a dispatch")
     if mixed:
         # (the CPU pre-flight's 16 documents can fall one bucket to a
         # window; at full size a mixed wave cannot avoid mixed windows)
@@ -630,8 +632,9 @@ def run_phase(name: str, cfg, fleet, data_dir: str, device: dict,
                 "three-way equality holds")
             check_counters(m1, store.obs.recorder, f"{name} round {r}")
             if mesh and r > 0:
-                check_round_windows(name, r, cfg, m0, m1, mixed,
-                                    sched.mesh_window_rows, row)
+                check_round_windows(
+                    name, r, cfg, m0, m1, mixed,
+                    max(sched.mesh_window_rows // n_shards, 1), row)
             require(row["reads_from_device"] == len(fleet),
                     f"{name} round {r}: {row['reads_from_device']} of "
                     f"{len(fleet)} reads came from the device")

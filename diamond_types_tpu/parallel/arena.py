@@ -8,24 +8,27 @@ for rows that already lived on-chip, and the donated `[B, cap]`
 output buffers of window k were simply dropped. This module keeps
 both on the device:
 
-  * **Device-side gather** (the `DEVICE_STAGE` default): sessions'
-    `docs`/`lens` rows are stacked with `jnp.stack` and placed with
-    `NamedSharding` directly — no host copy of resident state; only
-    the window's op PLAN arrays (host-built by construction) still
-    cross the host boundary.
+  * **Device-side stacking** (the `DEVICE_STAGE` default): a dispatch
+    is laid out by home chip (`parallel.mesh.home_blocks`) and each
+    chip stacks its own block from the `docs`/`lens` rows it already
+    holds, one program a chip — no host copy of resident state and no
+    row on the interconnect; only the window's op PLAN arrays
+    (host-built by construction) still cross the host boundary.
   * **Arena fast path** (donated-buffer reuse): after a window
     commits, its `[B, cap]` output arrays are parked as the arena of
     the `(mesh, cap, max_ins)` class and every committed session row
-    is tagged `(arena, generation, row)`. When the NEXT window
-    presents the same session list in the same shape class, the arena
+    is tagged `(arena, generation, slot)`, the slot being the row's
+    place in that layout. When the NEXT window presents the same
+    sessions in the same slots of the same shape class, the arena
     arrays are handed straight back to the donated kernel — zero
     staging, zero allocation. Donation is safe because sessions hold
-    independent per-row buffers (`out_docs[i]` is an eager gather),
-    never the stacked array itself.
+    independent per-row buffers (a chip's shard is cut by
+    `jit_dt_unstack_rows`, which donates nothing), never the stacked
+    array itself.
 
 Poison/fallback discipline: a row that fails the `adopt_results`
 length fence is NOT committed, so its session keeps a stale-generation
-tag (or none) — the next window's tag check misses, the gather path
+tag (or none) — the next window's tag check misses, the stacking path
 rebuilds from the sessions' own rows, and the poisoned slot can never
 leak stale bytes. Any session mutation outside the mesh commit
 (`FusedDocSession.commit` / `_materialize`) clears the tag for the
@@ -90,19 +93,19 @@ def arena_stats() -> dict:
                 "generations": sum(a.gen for a in _arenas.values())}
 
 
-def acquire(mesh, cap: int, mi: int, sessions, bp: int):
+def acquire(mesh, cap: int, mi: int, sessions, bp: int, slots):
     """Try the fast path: if the previous window of this shape class
-    committed EXACTLY these sessions in this order at this padded
+    committed EXACTLY these sessions in these `slots` at this padded
     batch, hand its parked `[bp, cap]` arrays back for donation.
-    Returns `(docs, lens)` or None (caller gathers instead)."""
+    Returns `(docs, lens)` or None (caller stacks instead)."""
     key = (mesh, int(cap), int(mi))
     with _arena_lock:
         a = _arenas.get(key)
         if a is None or a.docs is None or a.bp != bp \
                 or a.live != len(sessions):
             return None
-        for i, s in enumerate(sessions):
-            if getattr(s, "_arena_tag", None) != (a, a.gen, i):
+        for slot, s in zip(slots, sessions):
+            if getattr(s, "_arena_tag", None) != (a, a.gen, slot):
                 return None
         docs, lens = a.docs, a.lens
         a.docs = a.lens = None      # the donated call consumes them
@@ -112,12 +115,12 @@ def acquire(mesh, cap: int, mi: int, sessions, bp: int):
 
 
 def adopt(mesh, cap: int, mi: int, out_docs, out_lens, sessions,
-          ok: List[bool], bp: int) -> None:
+          ok: List[bool], bp: int, slots) -> None:
     """Park a committed window's output arrays as the next window's
-    arena and tag every COMMITTED session row. Rows that failed the
-    length fence are left untagged — their slot exists in the parked
-    array but can never be matched, so the fast path degrades to the
-    gather path instead of replaying stale bytes."""
+    arena and tag every COMMITTED session row with its slot. Rows that
+    failed the length fence are left untagged — their slot exists in
+    the parked array but can never be matched, so the fast path
+    degrades to the stacking path instead of replaying stale bytes."""
     key = (mesh, int(cap), int(mi))
     with _arena_lock:
         a = _arenas.setdefault(key, WindowArena())
@@ -126,6 +129,6 @@ def adopt(mesh, cap: int, mi: int, out_docs, out_lens, sessions,
         a.live = len(sessions)
         a.docs = out_docs
         a.lens = out_lens
-        for i, s in enumerate(sessions):
-            if ok[i]:
-                s._arena_tag = (a, a.gen, i)
+        for good, slot, s in zip(ok, slots, sessions):
+            if good:
+                s._arena_tag = (a, a.gen, slot)

@@ -162,75 +162,152 @@ def _home(arr):
     return next(iter(devs)) if len(devs) == 1 else None
 
 
-def _gather_rows(sh: NamedSharding, rows, bp: int, row_shape: tuple,
-                 fill: int):
-    """Assemble `rows` (each resident on whatever chip its session
-    lives on) plus inert padding rows of `fill` into one `[bp, ...]`
-    int32 array sharded by `sh`, device-side: every row moves
-    chip-to-chip at most once, straight to the mesh device whose slice
-    it falls in, and is stacked there. (`jnp.stack` over rows committed
-    to different chips is an error, and over uncommitted rows a detour
-    through the default device.)"""
-    devs = list(sh.mesh.devices.flat)
-    per = bp // len(devs)
-    blocks = []
-    for k, dev in enumerate(devs):
-        part = [jax.device_put(r, dev)
-                for r in rows[k * per:(k + 1) * per]]
-        if len(part) < per:
-            pad = jnp.full(row_shape, fill, jnp.int32, device=dev)
-            part += [pad] * (per - len(part))
-        blocks.append(jnp.stack(part))
-    return jax.make_array_from_single_device_arrays(
-        (bp,) + row_shape, sh, blocks)
+def home_blocks(mesh: Mesh, sessions):
+    """A dispatch's layout: for every device of `mesh`, in mesh order,
+    the indexes of the `sessions` whose row lives on it. Slot `i` of a
+    `[bp, ...]` batch lies on mesh device `i // (bp / ndev)`
+    (`mesh_flush_fn`'s `shard_map`), so a block replays on the chip
+    that already holds its rows. A session with no single home, or
+    one that is no device of this mesh, joins the emptiest block and
+    crosses the interconnect; the second value is the set of those."""
+    at = {dev: k for k, dev in enumerate(mesh.devices.flat)}
+    blocks = [[] for _ in at]
+    astray = []
+    for i, s in enumerate(sessions):
+        k = at.get(_home(s.docs))
+        (astray if k is None else blocks[k]).append(i)
+    for i in astray:
+        min(blocks, key=len).append(i)
+    return blocks, set(astray)
 
 
-def _rows_at(out, homes):
-    """Row i of a docs-sharded result as its own single-device array
-    on `homes[i]` (None: wherever it was computed), cut from the shard
-    that holds it — never from the global array, whose row slices
-    come back replicated on every chip of the mesh."""
-    rows = [None] * len(homes)
-    for shard in out.addressable_shards:
-        start = shard.index[0].start or 0
-        for j in range(shard.data.shape[0]):
-            i = start + j
-            if i < len(homes):
-                row = shard.data[j]
-                rows[i] = row if homes[i] is None \
-                    else jax.device_put(row, homes[i])
-    return rows
+def block_classes(ndev: int, rows: int):
+    """The block sizes (rows a chip) of the batch classes
+    `pad_batch_count` gives a mesh of `ndev` devices for up to `rows`
+    rows a dispatch."""
+    return sorted({pad_batch_count(b, ndev) // ndev
+                   for b in range(1, max(int(rows), 1) + 1)})
+
+
+_pad_pairs = {}
+
+
+def _pad_pair(dev, cap: int):
+    """The inert row and its `lens = -1` sentinel on `dev`, committed
+    there: what fills a block up to its class. One pair a (device,
+    cap); the stack donates nothing, so it is never consumed."""
+    key = (dev, int(cap))
+    with _mesh_jit_lock:
+        pair = _pad_pairs.get(key)
+    if pair is None:
+        pair = (jax.device_put(np.zeros((cap,), np.int32), dev),
+                jax.device_put(np.int32(-1), dev))
+        with _mesh_jit_lock:
+            pair = _pad_pairs.setdefault(key, pair)
+    return pair
+
+
+def _at(dev, x):
+    """`x` committed to `dev`: itself where it is (a row the last
+    window handed back), a copy from another chip else. A row as a
+    build leaves it is on its bank's chip and not committed to it;
+    committing it there moves nothing, and the row programs then meet
+    one kind of argument whatever the block holds."""
+    if x.committed and _home(x) == dev:
+        return x
+    return jax.device_put(x, dev)
+
+
+def _stack_blocks(sh: NamedSharding, blocks, per: int, cap: int):
+    """The `[ndev x per, cap]` batch and its lengths from `blocks`,
+    one `(rows, lens)` a mesh device: ONE `jit_dt_stack_rows` a chip
+    (`flush_fuse._row_programs`), on that chip, over its rows and the
+    padding pair, and the blocks joined into the array `sh` shards
+    without a copy."""
+    from ..tpu.flush_fuse import _row_programs
+    stack, _unstack = _row_programs()
+    docs, lens = [], []
+    for dev, (rows, row_lens) in zip(sh.mesh.devices.flat, blocks):
+        pad_row, pad_len = _pad_pair(dev, cap)
+        fill = per - len(rows)
+        d, n = stack(tuple(_at(dev, r) for r in rows) + (pad_row,) * fill,
+                     tuple(_at(dev, x) for x in row_lens)
+                     + (pad_len,) * fill)
+        docs.append(d)
+        lens.append(n)
+    bp = per * len(docs)
+    return (jax.make_array_from_single_device_arrays((bp, cap), sh, docs),
+            jax.make_array_from_single_device_arrays((bp,), sh, lens))
+
+
+def _cut_blocks(out_docs, out_lens):
+    """Every slot of a docs-sharded result as a row and a length of
+    its own, on the chip that computed it: ONE `jit_dt_unstack_rows`
+    a chip over its shard, never a slice of the global array (whose
+    rows come back replicated over the mesh)."""
+    from ..tpu.flush_fuse import _row_programs
+    _stack, unstack = _row_programs()
+    lens_at = {sh.device: sh.data for sh in out_lens.addressable_shards}
+    rows = [None] * out_docs.shape[0]
+    row_lens = list(rows)
+    for shard in out_docs.addressable_shards:
+        lo = shard.index[0].start or 0
+        r, n = unstack(shard.data, lens_at[shard.device])
+        rows[lo:lo + len(r)] = r
+        row_lens[lo:lo + len(n)] = n
+    return rows, row_lens
+
+
+def warm_block_programs(devs, cap: int, pers) -> None:
+    """Compile the two row programs of a mesh dispatch on each of
+    `devs` for blocks of `pers` rows of capacity class `cap`, by
+    running them on padding: a live window's block, however uneven the
+    dispatch, is then never the first of its size on its chip."""
+    from ..tpu.flush_fuse import _row_programs
+    stack, unstack = _row_programs()
+    for dev in devs:
+        pad_row, pad_len = _pad_pair(dev, cap)
+        for per in pers:
+            jax.block_until_ready(
+                unstack(*stack((pad_row,) * per, (pad_len,) * per)))
 
 
 def mesh_fused_replay(mesh: Mesh, sessions, plans):
     """Replay MANY shards' pending tails in ONE mesh-sharded program.
 
-    `sessions`/`plans` are the fusable rows of a whole flush window —
-    every shard's bucket concatenated — all sharing (cap, max_ins).
-    The padded shape `(bp, n)` is STEERED onto a warm mesh jit class
-    (`tpu/steer.py`) from the `pad_batch_count` / pow2 floors, and
-    state assembly is device-resident by default (`parallel/arena.py`):
+    `sessions`/`plans` are fusable rows of a flush window — every
+    shard's bucket concatenated — all sharing (cap, max_ins). The
+    batch is laid out by HOME CHIP (`home_blocks`): chip k's block of
+    slots holds the sessions whose rows live on mesh device k, padded
+    to `per` rows, the power of two at or above the largest block, so
+    a row is replayed where it lives and crosses nothing. The padded
+    shape `(ndev x per, n)` is STEERED onto a warm mesh jit class
+    (`tpu/steer.py`), and state assembly is device-resident by default
+    (`parallel/arena.py`):
 
       * arena fast path — the previous window's donated output arrays
-        are reused verbatim when the same session list recurs in the
-        same shape class (zero staging, zero allocation);
-      * device-side gather — otherwise sessions' resident rows move
-        chip-to-chip to the mesh device whose slice they fall in and
-        are stacked there (`_gather_rows`), without a host round trip;
-        only the host-built op PLAN arrays cross the boundary
+        are reused verbatim when the same sessions recur in the same
+        slots of the same shape class (zero staging, zero allocation);
+      * one stack a chip — otherwise each chip's block is stacked on
+        that chip from its resident rows and lengths by one
+        `jit_dt_stack_rows` (`_stack_blocks`) and the blocks become
+        the global array as they lie; only the host-built op PLAN
+        arrays cross the host boundary, straight to their sharding
         (accounted as purpose="plan").
 
-    Committed rows go back to the chip each session lived on before
-    the window (`_rows_at`), so a bank's sessions stay on the bank's
-    device and its slot budget counts what that chip really holds.
-    Rows are batched in class order, not by placement: a row whose
-    session lives on another chip than the one that replays it crosses
-    the interconnect once each way.
+    After the replay each chip's shard is cut by one
+    `jit_dt_unstack_rows` into rows of their own (`_cut_blocks`),
+    which are at home already: a dispatch is at most `2 x ndev + 1`
+    device programs whatever it holds. Only a session with no home on
+    this mesh is moved, to the emptiest block and back where it came
+    from, and only such a one is counted `rows_off_home`. Nothing is
+    donated into a stack: a session that fails the fence keeps its
+    row.
 
     With `DEVICE_STAGE` disabled (the `--no-device-stage` control
-    arm) the legacy host-numpy staging runs instead and every state
-    byte is accounted as purpose="stage" — the A/B that makes the
-    staging saving measurable.
+    arm) the legacy host-numpy staging runs instead, in the same slot
+    order, and every state byte is accounted as purpose="stage" — the
+    A/B that makes the staging saving measurable.
 
     Returns (ok-per-session, device_wait_s, padded_b, staged_bytes);
     `staged_bytes` is the host->device bytes this window's staging
@@ -238,29 +315,31 @@ def mesh_fused_replay(mesh: Mesh, sessions, plans):
     byte-identical to `fused_replay` (`adopt_results` is shared), so
     the bank's fallback ladder catches violating rows exactly as
     before — and a violating doc in one shard cannot corrupt another
-    shard's rows. Padding rows enter with the `lens = -1` sentinel and
-    zero ops on EVERY staging path, so they stay identifiably inert."""
+    shard's rows. Padding slots, between the blocks and not only at
+    the batch's end, enter with the `lens = -1` sentinel and zero ops
+    on EVERY staging path, so they stay identifiably inert."""
     with phase("mesh.replay") as ph:
         return _mesh_fused_replay(mesh, sessions, plans, ph)
 
 
 def _mesh_fused_replay(mesh: Mesh, sessions, plans, ph):
     """`mesh_fused_replay` under its `mesh.replay` phase `ph`. The
-    steps are the host pack, staging (arena hand-back, device-side
-    gather or the control arm's host staging), dispatch (jit lookup,
-    plan upload, the call), the length fence and adoption (rows back
-    to their chips, `adopt_results`, the arena). The row's own counts
-    say what the window moved: `rows`, `rows_off_home` (a row whose
-    session's chip is not the mesh device whose slice replays it),
-    `ici_bytes` (such a row and its length cross the interconnect on
-    the way back, and on the way in too when it was gathered),
-    `arena_hits` / `arena_misses`, and by capacity class `cap.<cap>.dispatches` / `.docs` / `.padded_rows`."""
+    steps are the host pack (layout, plan arrays in slot order),
+    staging (arena hand-back, one stack a chip or the control arm's
+    host staging), dispatch (jit lookup, plan upload, the call, and
+    the cut of each chip's shard queued behind it), the length fence
+    and adoption (`adopt_results` in session order, a homeless row's
+    trip back, the arena). The row's own counts say what the window
+    moved: `rows`, `rows_off_home` (a row whose session has no home
+    on this mesh, replayed on the emptiest block's chip), `ici_bytes`
+    (such a row and its length cross the interconnect on the way in
+    when it was stacked, and on the way back where it has a home),
+    `arena_hits` / `arena_misses`, and by capacity class
+    `cap.<cap>.dispatches` / `.docs` / `.padded_rows`."""
     import time
 
-    import jax.numpy as jnp
-
     from ..obs.devprof import note_transfer
-    from ..tpu.flush_fuse import adopt_results, pack_plans
+    from ..tpu.flush_fuse import _empty_plan, adopt_results, pack_plans
     from ..tpu.merge_kernel import _pow2
     from ..tpu.steer import STEER
     from . import arena as _arena
@@ -271,26 +350,32 @@ def _mesh_fused_replay(mesh: Mesh, sessions, plans, ph):
     mi = sessions[0].max_ins
     ndev = int(mesh.devices.size)
     ph.step("mesh.pack")
+    blocks, astray = home_blocks(mesh, sessions)
     n0 = _pow2(max(max(p.n_ops for p in plans), 1))
-    bp0 = pad_batch_count(b, ndev)
+    bp0 = pad_batch_count(ndev * max(len(blk) for blk in blocks), ndev)
     # warm mesh classes are mesh-legal by construction; multiple=ndev
     # keeps a hypothetical second mesh in-process from cross-matching
     bp, n = STEER.snap("mesh", bp0, n0, mi, cap, multiple=ndev)
-    pos, dlen, ilen, chars = pack_plans(plans, n, mi, bp)
+    per = bp // ndev
+    # the slot of each session is this side's to keep: the plan arrays
+    # are filled in slot order, the results read back through it
+    slots = [0] * b
+    for k, blk in enumerate(blocks):
+        for j, i in enumerate(blk):
+            slots[i] = k * per + j
+    by_slot = [_empty_plan((), 0, 0, mi)] * bp
+    for i, slot in enumerate(slots):
+        by_slot[slot] = plans[i]
+    pos, dlen, ilen, chars = pack_plans(by_slot, n, mi, bp)
     plan_bytes = (pos.nbytes + dlen.nbytes + ilen.nbytes + chars.nbytes)
     note_transfer(plan_bytes, rung="mesh", purpose="plan")
     staged_bytes = plan_bytes
     sh = NamedSharding(mesh, P(mesh.axis_names[0]))
     ph.step("mesh.stage")
-    # where each session lives, and the mesh device whose slice
-    # replays its row (rows are batched in class order)
-    homes = [_home(s.docs) for s in sessions]
-    mesh_devs = list(mesh.devices.flat)
-    per = bp // ndev
-    off_home = sum(h is not None and h != mesh_devs[i // per]
-                   for i, h in enumerate(homes))
-    crossings = off_home            # every such row goes back home
-    reuse = _arena.acquire(mesh, cap, mi, sessions, bp) \
+    # a row with a home that is not of this mesh goes back to it
+    homes = {i: _home(sessions[i].docs) for i in astray}
+    crossings = sum(h is not None for h in homes.values())
+    reuse = _arena.acquire(mesh, cap, mi, sessions, bp, slots) \
         if _arena.DEVICE_STAGE.enabled else None
     if reuse is not None:
         # donated-buffer fast path: window k's outputs are window
@@ -298,47 +383,53 @@ def _mesh_fused_replay(mesh: Mesh, sessions, plans, ph):
         docs_d, lens_d = reuse
         ph.count("arena_hits")
     elif _arena.DEVICE_STAGE.enabled:
-        # device-side gather: resident rows never visit host numpy
-        docs_d = _gather_rows(sh, [s.docs for s in sessions], bp,
-                              (cap,), 0)
-        lens_d = _gather_rows(sh, [jnp.asarray(s.lens, jnp.int32)
-                                   for s in sessions], bp, (), -1)
+        # one stack a chip: resident rows never visit host numpy
+        docs_d, lens_d = _stack_blocks(
+            sh, [([sessions[i].docs for i in blk],
+                  [sessions[i].lens for i in blk]) for blk in blocks],
+            per, cap)
         ph.count("arena_misses")
-        crossings += off_home       # and came over chip-to-chip
+        crossings += len(astray)    # and came over chip-to-chip
     else:
         # control arm: legacy host staging — every resident byte
         # round-trips through numpy and is accounted as staged
         docs_h = np.zeros((bp, cap), np.int32)
         lens_h = np.full((bp,), -1, np.int32)   # padding sentinels
-        for i, s in enumerate(sessions):
-            docs_h[i] = np.asarray(s.docs)
-            lens_h[i] = int(np.asarray(s.lens))
+        for slot, s in zip(slots, sessions):
+            docs_h[slot] = np.asarray(s.docs)
+            lens_h[slot] = int(np.asarray(s.lens))
         note_transfer(docs_h.nbytes + lens_h.nbytes,
                       rung="mesh", purpose="stage")
         staged_bytes += docs_h.nbytes + lens_h.nbytes
-        docs_d = jax.device_put(jnp.asarray(docs_h), sh)
-        lens_d = jax.device_put(jnp.asarray(lens_h), sh)
+        docs_d = jax.device_put(docs_h, sh)
+        lens_d = jax.device_put(lens_h, sh)
     ph.step("mesh.dispatch")
     fn = mesh_flush_fn(mesh, bp, n, mi, cap)
+    # the plan arrays go up from host numpy straight to their
+    # sharding, a quarter to a chip
     out_docs, out_lens = fn(docs_d, lens_d,
-                            *(jax.device_put(jnp.asarray(x), sh)
-                              for x in (pos, dlen, ilen, chars)))
+                            *jax.device_put((pos, dlen, ilen, chars), sh))
+    # queued behind the replay: each chip cuts its own shard while
+    # this thread waits at the fence
+    rows, row_lens = _cut_blocks(out_docs, out_lens)
     # the length fetch is the completion fence + parity cross-check
     ph.step("mesh.fence")
     t_fence = time.perf_counter()
     got = np.asarray(out_lens)
     device_s = time.perf_counter() - t_fence
     ph.step("mesh.adopt")
-    # each committed row goes back to the chip its session lives on
-    # (its bank's): a plain `out_docs[i]` of the sharded result comes
-    # back replicated over the whole mesh — one copy per chip
-    ok = adopt_results(sessions, plans, _rows_at(out_docs, homes),
-                       _rows_at(out_lens, homes), got)
+    rows = [rows[slot] for slot in slots]
+    row_lens = [row_lens[slot] for slot in slots]
+    for i, home in homes.items():
+        if home is not None:
+            rows[i] = jax.device_put(rows[i], home)
+            row_lens[i] = jax.device_put(row_lens[i], home)
+    ok = adopt_results(sessions, plans, rows, row_lens, got[slots])
     if _arena.DEVICE_STAGE.enabled:
         _arena.adopt(mesh, cap, mi, out_docs, out_lens, sessions,
-                     ok, bp)
+                     ok, bp, slots)
     ph.count("rows", b)
-    ph.count("rows_off_home", off_home)
+    ph.count("rows_off_home", len(astray))
     ph.count("ici_bytes", crossings * (4 * cap + 4))
     ph.count(f"cap.{cap}.dispatches")
     ph.count(f"cap.{cap}.docs", b)

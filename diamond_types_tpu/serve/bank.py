@@ -285,7 +285,19 @@ class SessionBank:
         from ..tpu.flush_fuse import FusedDocSession
         with self._on_device():
             sess = FusedDocSession(oplog, **self.fused_opts)
-            self._warm_growth(sess.cap)
+            first = self._warm_growth(sess.cap)
+        if first and self.mesh_shards and self.device is not None:
+            # under mesh flush windows the class's blocks are warmed
+            # too: the two row programs of a mesh dispatch, on this
+            # chip, for every block size a window can give it. OUTSIDE
+            # `_on_device()`: `jax.default_device` is part of a jitted
+            # program's cache key, and a window runs under none
+            from ..parallel.mesh import (block_classes, serve_mesh,
+                                         warm_block_programs)
+            ndev = int(serve_mesh(self.mesh_shards).devices.size)
+            warm_block_programs(
+                [self.device], sess.cap,
+                block_classes(ndev, self.mesh_shards * self.flush_docs))
         # the slots a growth adds are found BEFORE the copy, at the cost
         # of other documents and never of this one, so the budget holds
         # while both rows live: by `_plan_fused` itself on the fused
@@ -296,21 +308,23 @@ class SessionBank:
         self._resyncs_seen[doc_id] = sess.resyncs
         return sess
 
-    def _warm_growth(self, cap: int) -> None:
+    def _warm_growth(self, cap: int) -> bool:
         """At the first session this bank builds in capacity class
-        `cap`: compile the copy programs a growth out of or into that
-        class can need, to the next class up and between it and every
-        class built before, so that a session which outgrows its class
-        under traffic compiles nothing on the flush path. Runs on the
-        bank's chip, as the growth will."""
+        `cap` (the return says it was): compile the copy programs a
+        growth out of or into that class can need, to the next class
+        up and between it and every class built before, so that a
+        session which outgrows its class under traffic compiles
+        nothing on the flush path. Runs on the bank's chip, as the
+        growth will."""
         if cap in self._classes_built:
-            return
+            return False
         from ..tpu.flush_fuse import warm_grow
         pairs = {(cap, 2 * cap)} | {(min(cap, c), max(cap, c))
                                     for c in self._classes_built}
         self._classes_built.add(cap)
         for lo, hi in sorted(pairs):
             warm_grow(lo, hi)
+        return True
 
     def session(self, doc_id: str, oplog):
         """Get-or-build the doc's resident session, updating LRU order

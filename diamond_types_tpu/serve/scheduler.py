@@ -73,9 +73,11 @@ from .router import ShardRouter
 # that acknowledge. A shard's flush worker keeps its host part (what a
 # flush takes besides the wait for the device: resolve, plan, the
 # dispatches, adopt, and its own waits for the lock) to one part in
-# this many of its time, and sits out the rest (`_worker_loop`). A
-# flush that mostly waits for the device is never held back, and a
-# forced one (drain, shutdown: somebody waits for it) is not either.
+# this many of its time, and sits out the rest (`_worker_loop`); the
+# pump thread does the same after a mesh flush window, which runs on
+# it (`start_pump`; the arithmetic is `_pause_s`). A flush that mostly
+# waits for the device is never held back, and a forced one (drain,
+# shutdown: somebody waits for it) is not either.
 FLUSH_HOST_SHARE = 8
 
 # The longest a read waits for a flush in flight that carries its
@@ -121,10 +123,12 @@ class MergeScheduler:
         device dispatches per window), `pump()` assembles EVERY due
         shard's fusable tails into one mesh-sharded super-batch and
         issues a single `shard_map` program over the `docs` axis —
-        see `_flush_window`; `mesh_window_rows` is the most rows of one
-        class that go out in one such program (default `n_shards x
-        flush_docs`, one bucket a shard: a deployment states it when
-        its warm-up is built around it). `reads` says where the served
+        see `_flush_window`, which the pump thread's loop paces as a
+        flush worker paces its flushes (`FLUSH_HOST_SHARE`);
+        `mesh_window_rows` is the most rows of one class that go out
+        in one such program, an even share of it a chip (default
+        `n_shards x flush_docs`, one bucket a shard: a deployment
+        states it when its warm-up is built around it). `reads` says where the served
         `GET /doc/{id}` at the tip is answered, and is part of what a
         deployment states: `"host"` (the default) checks the oplog out
         under the store lock, so the body shares nothing with the
@@ -403,6 +407,16 @@ class MergeScheduler:
         concurrently and submits never wait on device calls (ROADMAP
         item (a)). Queue depths are re-recorded in a single pass after
         dispatch — one lock acquisition, each touched shard once."""
+        return self._pump(now, force, False)[0]
+
+    def _pump(self, now: Optional[float], force: bool, paced: bool):
+        """`pump()`, and what the pump thread's loop paces itself by:
+        (docs dispatched, the seconds to sit out). `paced` says the
+        caller will sit them out (`start_pump`'s loop alone, which
+        never forces): a mesh window then counts `paced` and its pause
+        is reckoned from the window's own clock, one flush deadline of
+        host time a bucket taken (`_pause_s`). 0.0 for everything
+        else: batches handed to workers pace themselves."""
         now = time.monotonic() if now is None else now
         taken = []      # (shard, reason, items)
         with self.lock:
@@ -424,15 +438,18 @@ class MergeScheduler:
                 with self._idle_cv:
                     self._inflight += 1
         synced = 0
+        pause_s = 0.0
         if window:
             # window coordinator: every due shard's bucket folds into
             # ONE mesh-sharded program instead of N worker dispatches
             try:
-                synced = self._flush_window(taken)
+                synced, wall_s, device_s = self._flush_window(taken, paced)
             finally:
                 with self._idle_cv:
                     self._inflight -= 1
                     self._idle_cv.notify_all()
+            if paced:
+                pause_s = self._pause_s(wall_s, device_s, len(taken))
         else:
             for shard, reason, items in taken:
                 if self._flush_workers:
@@ -451,7 +468,30 @@ class MergeScheduler:
                 for shard in {s for s, _r, _i in taken}:
                     self.metrics.observe_queue(
                         shard, self.queue.depth(shard))
-        return synced
+        return synced, pause_s
+
+    def _pause_s(self, wall_s: float, device_s: float,
+                 buckets: int) -> float:
+        """FLUSH_HOST_SHARE: the seconds a merge thread sits out after
+        a flush of `wall_s` seconds that waited `device_s` of them for
+        the device. The wait for the device counts towards the rest;
+        the host part counts up to one flush deadline a bucket
+        flushed, so that a slow flush (a session built, a class
+        compiled) is not sat out for seconds. A flush that mostly
+        waited for the device comes out at or below 0: no pause."""
+        host_s = min(wall_s - device_s,
+                     buckets * self.queue.flush_deadline_s)
+        return (FLUSH_HOST_SHARE - 1) * host_s - device_s
+
+    def _sit_out(self, pause_s: float) -> None:
+        """The pause itself: a root of its own (`sched.flush` has
+        closed), so a row, and under a profiler session a span on the
+        device trace's clock; a stop ends it. A pause at or below 0
+        writes none."""
+        if pause_s > 0:
+            with self.obs.phases.phase("sched.pause") \
+                    if self.obs is not None else NOOP_PHASE:
+                self._pump_stop.wait(pause_s)
 
     # ---- worker pool -----------------------------------------------------
 
@@ -480,22 +520,7 @@ class MergeScheduler:
             try:
                 wall_s, device_s = self._flush_items(shard, reason, items)
                 if reason != "force":
-                    # FLUSH_HOST_SHARE: the wait for the device counts
-                    # towards the rest; the host part counts up to one
-                    # flush deadline, so that a slow flush (a session
-                    # built, a class compiled) is not sat out for
-                    # seconds; a stop ends the pause
-                    host_s = min(wall_s - device_s,
-                                 self.queue.flush_deadline_s)
-                    pause_s = (FLUSH_HOST_SHARE - 1) * host_s - device_s
-                    if pause_s > 0:
-                        # a root of its own (`sched.flush` has closed):
-                        # a row, and under a profiler session a span on
-                        # the device trace's clock; a flush that mostly
-                        # waited for the device writes none
-                        with self.obs.phases.phase("sched.pause") \
-                                if self.obs is not None else NOOP_PHASE:
-                            self._pump_stop.wait(pause_s)
+                    self._sit_out(self._pause_s(wall_s, device_s, 1))
             except Exception as e:      # keep the shard alive, loudly
                 self._loop_error("flush_worker", shard, e)
             finally:
@@ -722,7 +747,7 @@ class MergeScheduler:
                 m = self._mesh
         return m
 
-    def _flush_window(self, taken) -> int:
+    def _flush_window(self, taken, paced: bool):
         """The mesh flush-window coordinator: ONE device program per
         window instead of one per shard.
 
@@ -732,11 +757,13 @@ class MergeScheduler:
           2. host-side planning per shard (`bank.plan_window`,
              min_fuse=1: lone docs join the shared dispatch);
           3. fusable rows concatenated ACROSS shards by (cap, max_ins)
-             shape class and replayed by `mesh_fused_replay` — one
-             `shard_map` program over the serve mesh's `docs` axis per
-             class (uniform-shape window ⇒ exactly one dispatch), and
-             one more for every `shards x flush_docs` rows beyond the
-             first when several buckets of a shard were due;
+             shape class, laid out by the chip each row lives on
+             (`parallel.mesh.home_blocks`) and replayed by
+             `mesh_fused_replay` — one `shard_map` program over the
+             serve mesh's `docs` axis per class (uniform-shape window
+             ⇒ exactly one dispatch), and one more whenever a chip's
+             block would pass `mesh_window_rows / ndev` rows (several
+             buckets of a shard due at once);
           4. per-shard adoption (`bank.adopt_window`): poisoned /
              length-drift rows evict to the host oracle, serial
              leftovers run the per-doc ladder — the SAME data-fault
@@ -751,14 +778,20 @@ class MergeScheduler:
 
         The `sched.flush` root's steps are `window.plan` (2),
         `window.replay` (3, device locks included) and `window.adopt`
-        (4); its counts say where the window's documents went.
+        (4); its counts say where the window's documents went, and its
+        kind: `forced` (drain, shutdown, the warm rounds), `paced` (the
+        pump thread's own loop, which sits out `FLUSH_HOST_SHARE - 1`
+        parts of the window's host time afterwards: `paced` says so)
+        or `inline` (another caller's `pump()`).
 
         Lock order: shard locks (sorted) → oplog lock (inside
         plan/adopt) → device locks (sorted, deduped); the mesh device
         phase holds ONLY the device locks of the shards in the window.
-        Returns the number of docs flushed (post-fencing)."""
+        Returns the number of docs flushed (post-fencing), the
+        window's seconds and, of them, those its dispatches waited for
+        the device."""
         from ..obs.devprof import PROFILER
-        from ..parallel.mesh import mesh_fused_replay
+        from ..parallel.mesh import home_blocks, mesh_fused_replay
         obs = self.obs
         entries = []        # (shard, reason, items) — post-fencing
         for shard, reason, items in taken:
@@ -771,7 +804,7 @@ class MergeScheduler:
             # out of the device_calls_per_window denominator)
             self.metrics.record_window(
                 0, 0, len({s for s, _r, _i in taken}))
-            return 0
+            return 0, 0.0, 0.0
         mesh = self._get_mesh()     # needs self.lock: before shard locks
         shards = sorted({s for s, _r, _i in entries})
         n_docs = sum(len(i) for _s, _r, i in entries)
@@ -820,16 +853,21 @@ class MergeScheduler:
             failed: List[List[str]] = [[] for _ in entries]
             replayed: List[set] = [set() for _ in entries]
             err: Optional[BaseException] = None
-            # a class's rows go out in dispatches of at most
-            # `mesh_window_rows` (default `shards x flush_docs`: the
-            # largest batch class the boot warm-up and a flush of one
-            # bucket a shard can have compiled). Several buckets of a
-            # shard due at once would otherwise make a batch class of
-            # their own and compile it on the pump
-            max_rows = self.mesh_window_rows
-            chunks = [(key, rows[lo:lo + max_rows])
-                      for key, rows in sorted(classes.items())
-                      for lo in range(0, len(rows), max_rows)]
+            # a class's rows go out in dispatches in which no chip's
+            # block passes `mesh_window_rows / ndev` rows (default
+            # `shards x flush_docs` over the mesh: the largest batch
+            # class the boot warm-up and a flush of one bucket a shard
+            # can have compiled). Several buckets of a shard due at
+            # once would otherwise make a batch class of their own
+            # and compile it on the pump
+            most = max(self.mesh_window_rows // int(mesh.devices.size), 1)
+            chunks = []
+            for key, rows in sorted(classes.items()):
+                blocks, _astray = home_blocks(mesh, [r[2] for r in rows])
+                chunks += [(key, [rows[i] for blk in blocks
+                                  for i in blk[lo:lo + most]])
+                           for lo in range(0, max(map(len, blocks)), most)]
+            waited_s = 0.0
             for (cap, mi), rows in chunks:
                 sessions = [r[2] for r in rows]
                 plans = [r[3] for r in rows]
@@ -855,6 +893,7 @@ class MergeScheduler:
                     mesh_docs += len(rows)
                     padded_rows += bp
                     staged_bytes += staged
+                    waited_s += device_s
                     dspan.end(padded_b=bp, staged_bytes=staged)
                     dispatches += 1
                 wall = time.perf_counter() - t_cls
@@ -888,9 +927,9 @@ class MergeScheduler:
                                     "device_replayed")
             # adoption + per-bucket flush accounting, per shard
             root.step("window.adopt")
-            # by kind, as `_flush_items`: a window is never paced
+            # by kind, as `_flush_items`
             root.count("forced" if all(r == "force" for _s, r, _i in entries)
-                       else "inline")
+                       else "paced" if paced else "inline")
             # where the window's documents went: the mesh rung, no
             # device work (an empty plan), or the per-doc ladder
             root.count("window_docs", n_docs)
@@ -934,7 +973,7 @@ class MergeScheduler:
                                fspan.context().trace_id)
         if err is not None:
             raise err
-        return n_docs
+        return n_docs, dur, waited_s
 
     def drain(self) -> int:
         """Flush everything regardless of triggers (shutdown, rebalance,
@@ -1111,7 +1150,9 @@ class MergeScheduler:
         def loop():
             while not self._pump_stop.wait(interval):
                 try:
-                    self.pump()
+                    # a mesh window ran on this thread: it keeps the
+                    # flush workers' rule (FLUSH_HOST_SHARE)
+                    self._sit_out(self._pump(None, False, True)[1])
                 except Exception as e:      # keep pumping, loudly
                     self._loop_error("pump", 0, e)
 

@@ -191,7 +191,7 @@ def warm_grow(cap: int, cap2: int) -> None:
     it builds its first session of a class, so that a session which
     outgrows its class later compiles nothing. JAX keeps one program
     for a row that is committed to its chip (how a mesh window hands
-    rows back, `parallel.mesh._rows_at`) and one for a row that is
+    rows back, `parallel.mesh._cut_blocks`) and one for a row that is
     not, so both are run."""
     import jax
     import jax.numpy as jnp
@@ -215,8 +215,10 @@ def warmup_fused_cache(flush_docs: int = 8, cap: int = DEFAULT_CAP,
     `mesh_shards > 0` additionally pre-compiles the MESH flush program
     (`parallel.mesh.mesh_flush_fn`) for every super-batch shape class a
     `mesh_shards`-shard window can assemble — B padded to the mesh per
-    `pad_batch_to_mesh` — so the first mesh window doesn't eat a cold
-    compile either (cache "mesh")."""
+    `pad_batch_to_mesh` — and runs the two row programs on every mesh
+    device for each of those classes' blocks (`warm_block_programs`),
+    so the first mesh window doesn't eat a cold compile either (cache
+    "mesh"), however unevenly its rows lie over the chips."""
     import jax
     import jax.numpy as jnp
 
@@ -247,8 +249,9 @@ def warmup_fused_cache(flush_docs: int = 8, cap: int = DEFAULT_CAP,
             jax.block_until_ready(unstack(*fn(docs, lens, z, z, z, ch)))
             compiled += 1
     if mesh_shards > 0:
-        from ..parallel.mesh import (mesh_flush_fn, pad_batch_count,
-                                     serve_mesh)
+        from ..parallel.mesh import (block_classes, mesh_flush_fn,
+                                     pad_batch_count, serve_mesh,
+                                     warm_block_programs)
         mesh = serve_mesh(mesh_shards)
         ndev = mesh.devices.size
         sh = jax.sharding.NamedSharding(
@@ -259,6 +262,8 @@ def warmup_fused_cache(flush_docs: int = 8, cap: int = DEFAULT_CAP,
         bps = sorted({pad_batch_count(b, ndev)
                       for b in range(1, mesh_shards * flush_docs + 1)})
         from ..obs.devprof import note_transfer
+        warm_block_programs(mesh.devices.flat, cap,
+                            block_classes(ndev, mesh_shards * flush_docs))
         for bp in bps:
             for ncls in shape_classes:
                 n = _pow2(ncls)

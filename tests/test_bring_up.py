@@ -215,12 +215,12 @@ def test_pump_loop_exception_is_counted_not_eaten(capsys):
         calls.append(1)
         raise RuntimeError("pump exploded")
 
-    sched.pump = boom
+    sched._pump = boom           # the loop's turn (`pump()` and its pause)
     sched.start_pump(interval_s=0.005)
     deadline = time.monotonic() + 5
     while len(calls) < 3 and time.monotonic() < deadline:
         time.sleep(0.01)
-    sched.pump = lambda *a, **k: 0
+    sched._pump = lambda *a, **k: (0, 0.0)
     sched.stop_pump(drain=False)
     assert len(calls) >= 3                          # the loop kept going
     m = sched.metrics_json()

@@ -7,11 +7,12 @@ CPU runs prove parity and counts, never a time: what is pinned here is
 (a) HTTP body = `scheduler.text()` = the plain reference for every
 document after typed pushes, with no fallback; (b) every session's row
 and length on its bank's device after every window; (c) the mesh rung's
-`rows_off_home` / `ici_bytes` against a count made from the window's
-session order and the sessions' devices; (d) the arena's hits and
-misses; (e) the new steps close on their roots, and a scheduler without
-`mesh_window` writes none of them; and the repair that keeps a class's
-dispatch at `shards x flush_docs` rows or fewer.
+`rows_off_home` / `ici_bytes` against a count made from the sessions'
+devices (a dispatch is laid out by home chip: only a row with no home
+on the mesh crosses); (d) the arena's hits and misses; (e) the new
+steps close on their roots, and a scheduler without `mesh_window`
+writes none of them; and the repair that keeps a class's dispatch at
+`shards x flush_docs` rows or fewer.
 """
 
 import json
@@ -143,12 +144,12 @@ class Recorder:
 
     def expected(self):
         """rows, rows off home, interconnect bytes: a row whose home is
-        not the device of its slot crosses on its way back, and on its
-        way in too unless the arena handed the state back."""
+        no device of the mesh crosses on its way back, and on its way
+        in too unless the arena handed the state back. Every other row
+        is replayed in its home chip's block."""
         rows = off = ici = 0
         for devs, homes, bp, cap, n_acquired in self.calls:
-            per = bp // len(devs)
-            away = sum(h != devs[i // per] for i, h in enumerate(homes))
+            away = sum(h not in devs for h in homes)
             hit = self.handed_back[n_acquired - 1]
             rows += len(homes)
             off += away
@@ -199,7 +200,7 @@ def test_a_two_class_fleet_on_four_shards_through_mesh_windows(
         assert rows == win["mesh_docs"]
         assert (got["rows"], got["rows_off_home"], got["ici_bytes"]) \
             == (rows, off, ici)
-        assert off > 0      # shard order is not placement order
+        assert off == 0     # every row is replayed on its own chip
         for c in classes:
             cap = c["cap"]
             mine = [call for call in rec.calls if call[3] == cap]
@@ -277,8 +278,10 @@ def _type(ol, pos, text):
 
 def test_rows_off_home_interconnect_bytes_and_the_arena():
     """Six sessions on devices 0, 0, 1, 2, 3, 3 in a batch padded to 8
-    (two slots a device): slots 3, 4 and 5 hold rows of devices 2, 3
-    and 3 on devices 1, 2 and 2."""
+    (two slots a device): every row lies in its own chip's block,
+    whatever the order of the list. A seventh lives on a device that
+    is none of the mesh's: it alone crosses."""
+    import jax
     mesh = pm.serve_mesh(SHARDS)
     devs = list(mesh.devices.flat)
     homes = [devs[i] for i in (0, 0, 1, 2, 3, 3)]
@@ -299,26 +302,39 @@ def test_rows_off_home_interconnect_bytes_and_the_arena():
         return _counts(table, "mesh.replay")
 
     got = window(sessions)
-    # gathered: three rows came over and went back
+    # stacked at home: nothing came over, nothing goes back
     assert (got["rows"], got["rows_off_home"], got["ici_bytes"]) \
-        == (6, 3, 6 * row)
+        == (6, 0, 0)
     assert (got.get("arena_hits", 0), got["arena_misses"]) == (0, 1)
     assert [s.docs.devices() for s in sessions] == [{h} for h in homes]
+    assert [s._arena_tag[2] for s in sessions] == [0, 1, 2, 4, 6, 7]
     assert (got[f"cap.{cap}.dispatches"], got[f"cap.{cap}.docs"],
             got[f"cap.{cap}.padded_rows"]) == (1, 6, 8)
-    # (d) the same session list again: the arena hands the state back,
-    # and the three rows cross once, on their way home
+    # (d) the same session list again: the arena hands the state back
     got = window(sessions)
     assert (got["arena_hits"], got["arena_misses"]) == (1, 1)
-    assert (got["rows_off_home"], got["ici_bytes"]) == (6, 9 * row)
-    # another order is another list: a miss, and other rows off home
-    # (slots 0-1 now hold rows of devices 3 and 3, slot 5 one of 0)
+    assert (got["rows_off_home"], got["ici_bytes"]) == (0, 0)
+    # another order is another layout (the two rows of device 0 and of
+    # device 3 swap slots): a miss, and still every row at home
     got = window(sessions[::-1])
     assert (got["arena_hits"], got["arena_misses"]) == (1, 2)
-    assert got["rows_off_home"] == 6 + 5
-    assert got["ici_bytes"] == (9 + 10) * row
+    assert [s._arena_tag[2] for s in sessions] == [1, 0, 2, 4, 7, 6]
+    assert (got["rows_off_home"], got["ici_bytes"]) == (0, 0)
     assert [s.docs.devices() for s in sessions] == [{h} for h in homes]
     assert [s.text() for s in sessions] == ["xxxhello world"] * 6
+    # a row that lives on no device of the mesh joins the emptiest
+    # block (device 1's or 2's), comes over and goes back home
+    far = jax.devices()[SHARDS + 1]
+    pairs.append(_session("far", far, text="xxxhello world"))
+    sessions.append(pairs[-1][1])
+    got = window(sessions)
+    assert (got["rows"], got["rows_off_home"], got["ici_bytes"]) \
+        == (6 * 3 + 7, 1, 2 * row)
+    assert sessions[-1]._arena_tag[2] in (3, 5)
+    assert sessions[-1].docs.devices() == {far} \
+        == sessions[-1].lens.devices()
+    assert [s.docs.devices() for s in sessions[:6]] == [{h} for h in homes]
+    assert [s.text() for s in sessions] == ["xxxxhello world"] * 7
     ph = table.snapshot()["phases"]
     _closes(ph, "mesh.replay", MESH_STEPS)
     # with no root open on the thread the rung records nowhere
@@ -420,11 +436,15 @@ def test_a_row_left_off_its_banks_chip_is_counted(monkeypatch):
 
     window()                                # sessions built
     assert window()["homes_off_bank"] == 0
-    # rows stay where the mesh computed them: no trip home
-    monkeypatch.setattr(pm, "_rows_at", lambda out, homes: [
-        shard.data[j] for shard in sorted(
-            out.addressable_shards, key=lambda sh: sh.index[0].start or 0)
-        for j in range(shard.data.shape[0])][:len(homes)])
+    # a rung that lays chip k's rows into chip k+1's block, and
+    # leaves them where they were computed
+    inner = pm.home_blocks
+
+    def shifted(mesh, sessions):
+        blocks, _astray = inner(mesh, sessions)
+        return blocks[-1:] + blocks[:-1], set()
+
+    monkeypatch.setattr(pm, "home_blocks", shifted)
     got = window()
     assert 0 < got["homes_off_bank"] <= 8
     assert got["window_mesh_docs"] == 3 * 8 - 8

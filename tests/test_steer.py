@@ -401,9 +401,11 @@ def test_session_mutation_clears_arena_tag():
         mesh, sess, [s.plan_tail() for s in sess])
     assert all(ok)
     assert getattr(sess[0], "_arena_tag", None) is not None
+    # both rows live on the first device: its block, slots 0 and 1
+    assert [s._arena_tag[2] for s in sess] == [0, 1] and _bp == 4
     sess[0]._materialize()
     assert sess[0]._arena_tag is None
-    assert arena.acquire(mesh, sess[0].cap, MI, sess, 2) is None
+    assert arena.acquire(mesh, sess[0].cap, MI, sess, 4, [0, 1]) is None
 
 
 def test_device_stage_off_is_host_control_arm(monkeypatch):
