@@ -73,8 +73,9 @@ NO_SITE = "other"
 # the chip's host)
 GIL_PROBE_S = 0.1
 # `snapshot()["cpu"]`: a live Python thread's class by the start of its
-# name (the server names its long-lived threads); the accept loop is
-# whatever thread called `claim_thread("accept_loop_s")`
+# name (the server names its long-lived threads); `accept_loop_s` is
+# whatever thread called `claim_thread("accept_loop_s")`: the one
+# inside `serve_forever`, which since PR 46 watches and accepts nothing
 CPU_CLASSES = (("merge-pump", "pump_s"), ("flush-worker-", "flush_workers_s"),
                ("autosave", "autosave_s"), ("gil-probe", "gil_probe_s"),
                ("http-worker-", "http_workers_s"))
@@ -394,6 +395,8 @@ class PhaseTable:
         self._probe = None      # (thread, its stop event)
         # native thread id -> class of `snapshot()["cpu"]`
         self._claimed: Dict[int, str] = {}
+        # class -> CPU seconds of its threads that have ended
+        self._ended: Dict[str, float] = {}
 
     def phase(self, name: str, span=None,
               slow: Optional[dict] = None) -> _Phase:
@@ -626,21 +629,30 @@ class PhaseTable:
 
     def claim_thread(self, cls: Optional[str]) -> None:
         """File the calling thread's CPU under `cls` in the `cpu`
-        block whatever its name (the accept loop runs on a thread the
+        block whatever its name (`serve_forever` runs on a thread the
         server did not make); None gives the claim up."""
         if cls is None:
             self._claimed.pop(threading.get_native_id(), None)
         else:
             self._claimed[threading.get_native_id()] = cls
 
+    def end_thread(self, cls: str) -> None:
+        """The calling thread is at its last line: its CPU seconds stay
+        under `cls` (a resident handler thread that was replaced), so
+        the class never runs backwards between two scrapes and
+        `exited_s` keeps only the threads nobody filed."""
+        with self._lock:
+            self._ended[cls] = self._ended.get(cls, 0.0) + time.thread_time()
+
     def _cpu(self) -> Optional[dict]:
         """Cumulative CPU seconds (user + system) of the process and of
         its live threads by class, from `/proc/self/stat` and
         `/proc/self/task/<tid>/stat`; `exited_s` is the process less
-        every live thread: the threads that came and went, which for a
-        server are the handler threads born for one connection (its
-        resident ones are `http_workers_s`). None where `/proc` is
-        not."""
+        every live thread and every ended one that was filed
+        (`end_thread`: a resident handler thread that was replaced
+        stays in `http_workers_s`): the threads that came and went
+        unfiled, which for a server are the handler threads born for
+        one connection. None where `/proc` is not."""
         try:
             tids = os.listdir("/proc/self/task")
             tck = os.sysconf("SC_CLK_TCK")
@@ -673,6 +685,8 @@ class PhaseTable:
                     cls = next((c for prefix, c in CPU_CLASSES
                                 if name.startswith(prefix)),
                                "live_handlers_s")
+            out[cls] += s
+        for cls, s in list(self._ended.items()):
             out[cls] += s
         live = sum(out.values())
         try:

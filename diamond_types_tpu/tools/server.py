@@ -120,9 +120,9 @@ from __future__ import annotations
 import argparse
 import email.parser
 import http.client
+import itertools
 import json
 import os
-import queue
 import re
 import select
 import socket
@@ -133,7 +133,6 @@ import time
 import traceback
 import urllib.parse
 import urllib.request
-from collections import deque
 from http import HTTPStatus
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Dict, Optional
@@ -1699,6 +1698,8 @@ class SyncHandler(BaseHTTPRequestHandler):
                     if trav or remaining <= 0:
                         return self._send(200,
                                           json.dumps(out).encode("utf8"))
+                    # this thread is the poll's from here on
+                    self.server.leave_pool()
                     c.wait(timeout=min(remaining, 5.0))
         if action == "ops":
             # In-browser CRDT peer protocol (reference: the wiki app's
@@ -1802,7 +1803,7 @@ _TCPI_UNACKED = 24
 _TCPI_SACKED = 28
 _TCPI_LAST_DATA_RECV = 52
 _u32_at = struct.Struct("I").unpack_from
-# Every push pays for what the accept loop and its own thread do, and
+# Every push pays for what its thread does at the socket and after, and
 # on the chip's host a line of Python costs 2.5 times what it costs
 # elsewhere and a system call 6 us: so the listening socket is sampled
 # once in 32 accepts, and one connection in 8 is CLOCKED (its wait in
@@ -1810,18 +1811,30 @@ _u32_at = struct.Struct("I").unpack_from
 # counter and a type check more than before these clocks.
 LISTEN_SAMPLE_EVERY = 32
 CLOCKED_EVERY = 8
-# Resident handler threads (`http-worker-<n>`). On the chip's host a
-# thread's birth, and the accept loop's wait for its first breath, is
-# 0.93 of the loop's 1.385 ms a connection, and that cycle is the
-# saturated cells' rate: so the loop hands a connection to a thread
-# that is already there, where one is parked, and gives it a thread of
-# its own only where none is. Small on purpose: the pool is also how
-# many handlers the cheap path can set against the store lock at once
-# (PERF.md section 6 has the chip pairs of 2, 4 and 8).
+# Resident handler threads (`http-worker-<n>`): how many go to the
+# listening socket for themselves, each serving the connection its own
+# `accept()` returned. Under saturation all of them are inside a
+# connection at any moment, so this is also how many handlers stand
+# against the store lock at once: the chip pairs of 2, 4 and 8
+# (PERF.md section 6, PRs 40 and 43) set it, not the cores (one
+# interpreter runs them all). With four, a take of the lock waits
+# 0.2 ms and the workers wait for their clients (PERF.md section 5).
 HANDLER_THREADS = 4
-# `server_close()` waits this long in all for workers that are inside
-# a connection (one parked ends at once); a worker still inside a
-# long-poll by then ends with it, as a thread born for it would
+# The watch's tick (`serve_forever`'s own thread, which accepts
+# nothing): a worker inside ONE connection for longer than this is
+# replaced and ends with its connection (a silent client, a slow
+# reader; a `changes` long-poll does not wait for the watch, it leaves
+# the pool where it starts to wait), so a connection that finds every
+# worker held by such clients waits a tick or two for a thread. From
+# the records (PERF.md section 5): a push's p99 at the client is
+# 50-71 ms and the longest hold of `DocStore.lock` 52 ms on one chip,
+# 1.04 s on four. Much under 0.25 s would replace workers that merely
+# queue for the lock (harmless, a birth each); much over is what an
+# edit behind four silent clients waits.
+WATCH_TICK_S = 0.25
+# `server_close()` and `shutdown()` wait this long in all for workers
+# that are inside a connection (one at the socket ends at once); a
+# worker still inside a long-poll by then ends with it
 WORKERS_JOIN_S = 2.0
 
 
@@ -1853,11 +1866,34 @@ class _Clocked:
         self.root = None
 
 
+class _Worker(threading.Thread):
+    """A resident handler thread, and what the watch reads of it."""
+
+    def __init__(self, server: "_Server", n: int, slot: int, poller,
+                 born: bool):
+        super().__init__(target=server._worker_loop, args=(self,),
+                         name=f"http-worker-{n}", daemon=True)
+        # the listening socket and the wake-up pipe, for its turn there
+        self.poller = poller
+        # its place in the pool: `_Server._pooled[slot]` is its alone
+        # to write, and its replacement's after it
+        self.slot = slot
+        # when it took the connection it is inside (`perf_counter`);
+        # None at the socket
+        self.since: Optional[float] = None
+        # ends with its connection, or at once at the socket: it was
+        # replaced, or the server stops
+        self.retired = False
+        # a replacement that has taken no connection yet: its first
+        # one counts `born`
+        self.born = born
+
+
 class _Server(ThreadingHTTPServer):
     store: DocStore = None
     # The listen queue. The stdlib's 5 overflows when a few dozen
-    # clients, each on a connection a request, reconnect while the accept
-    # loop waits for the interpreter: a connection that falls out waits
+    # clients, each on a connection a request, reconnect while the
+    # workers wait for the interpreter: a connection that falls out waits
     # for TCP's retransmission timers (seconds, then tens of seconds),
     # outlives its client's time-out and leaves a push with no answer.
     request_queue_size = 128
@@ -1866,30 +1902,43 @@ class _Server(ThreadingHTTPServer):
         super().__init__(*a, **kw)
         # connection -> when accept() returned it (a `_Clocked` for one
         # in CLOCKED_EVERY): `http.accept_wait` runs from there to the
-        # handler's first line (thread start, request line, headers)
+        # handler's first line (request line, headers)
         self.accepted_at: dict = {}
-        self._accepts = 0
-        # the resident handler threads: None until the first
-        # connection, () once closed. A worker that is back from a
-        # connection puts a token on `_parked` BEFORE it blocks on
-        # `_handoff`; the accept loop alone takes tokens, one a
-        # connection it hands over, so a connection on `_handoff` always
-        # has a worker on its way to it. Both are C-level: neither side
-        # waits for the other or takes a Python lock.
+        # the resident handler threads: None until `serve_forever`, ()
+        # once closed. A worker that is back from a connection calls
+        # `accept()` itself; one that finds nothing queued takes `_turn`
+        # to wait for the socket, so a connection wakes one waiting
+        # thread alone; `_wake` is (read end, write end, poller) of the
+        # pipe that ends that wait when the server stops.
         self._workers = None
-        self._parked = deque()
-        self._handoff = queue.SimpleQueue()
-        self._pool_lock = threading.Lock()    # start and close only
-        # connections handed to a parked worker / given a born thread:
-        # plain integers, the accept loop's own; folded into the
-        # `http.accept_wait` row's counts with the listen queue's
-        # sample and at `server_close()`
-        self.pooled = self.born = 0
-        self._folded = (0, 0)
+        self._worker_ids = itertools.count()
+        self._turn = threading.Lock()
+        self._wake = None
+        self._pool_lock = threading.Lock()    # start, replace, close, fold
+        # `shutdown()` asks, `serve_forever` answers (the stdlib's pair
+        # is private to its own loop)
+        self._stop = threading.Event()
+        self._stopped = threading.Event()
+        # A connection counts once: in its worker's place of `_pooled`
+        # (one writer a place, so no lock on a push's road), or, the
+        # first of a thread born for it (a replacement's,
+        # `handle_request()`'s), in `born` under `_pool_lock`.
+        # `accept_waited`: connections that were waited for at the
+        # socket, the turn's own. All folded into the `http.accept_wait`
+        # row's counts with the listen queue's sample and at
+        # `server_close()`
+        self._pooled: list = []
+        self.born = self.accept_waited = 0
+        self._folded = (0, 0, 0)
         # does the kernel fill `TCP_INFO`? One that only has the call
         # (a sandbox kernel) reads 0 for a listening socket's limit,
         # and is treated as one without it: no `http.listen_wait`
         self._tcp_info = bool(_tcp_info(self.socket, _TCPI_SACKED))
+
+    @property
+    def pooled(self) -> int:
+        """Connections a cycling worker took from the socket."""
+        return sum(self._pooled)
 
     def _phases(self):
         """The bundle's phase table, None with no bundle."""
@@ -1899,23 +1948,49 @@ class _Server(ThreadingHTTPServer):
         return store.obs.phases
 
     def serve_forever(self, poll_interval=0.5):
+        """Start the resident workers, which accept for themselves,
+        and WATCH them until `shutdown()`: once a `WATCH_TICK_S` a
+        worker inside one connection for longer than that is replaced.
+        Nothing is polled here (`poll_interval` is the stdlib's and
+        unused: a worker waits at the socket until a connection or the
+        wake-up comes), and `shutdown()` returns as soon as the workers
+        have ended. `service_actions()` is still called once a round."""
         phases = self._phases()
-        if phases is None:
-            return super().serve_forever(poll_interval)
-        # the accept loop runs on the caller's thread, whatever its name
-        phases.claim_thread("accept_loop_s")
+        if phases is not None:
+            # the caller's thread, whatever its name, and still the
+            # `cpu` block's `accept_loop_s`
+            phases.claim_thread("accept_loop_s")
+        if self._stopped.is_set():
+            # a request that the last round answered, or one that came
+            # after it and was answered at once: not this round's (one
+            # made before the FIRST round is, as the stdlib's)
+            self._stop.clear()
+        self._stopped.clear()
         try:
-            super().serve_forever(poll_interval)
+            self._start_workers()
+            while not self._stop.wait(WATCH_TICK_S):
+                self._replace_held()
+                self.service_actions()
         finally:
-            phases.claim_thread(None)
+            self._stop_workers()
+            if phases is not None:
+                phases.claim_thread(None)
+            self._stopped.set()
+
+    def shutdown(self):
+        """Stop `serve_forever` and wait until it has returned (as the
+        stdlib's: from another thread than the one that serves)."""
+        self._stop.set()
+        self._stopped.wait()
 
     def _sample_listen_queue(self, phases) -> None:
-        """Just after an accept: is a further connection waiting
-        already (a poll that does not block: any kernel answers it),
-        and how many (`tcpi_unacked`, where the kernel fills it)? On
-        the `http.accept_wait` row's own counts: `listen_samples`,
+        """Just after an accept, on its thread (another worker may have
+        accepted since): is a further connection waiting already (a
+        poll that does not block: any kernel answers it), and how many
+        (`tcpi_unacked`, where the kernel fills it)? On the
+        `http.accept_wait` row's own counts: `listen_samples`,
         `listen_waiting`, `listen_depth` (a sum), `listen_depth_max`;
-        `pooled` / `born` ride with it."""
+        `pooled` / `born` / `accept_waited` ride with it."""
         adds, maxima = self._unfolded(), {}
         try:
             waiting = select.select((self.socket,), (), (), 0)[0]
@@ -1933,94 +2008,193 @@ class _Server(ThreadingHTTPServer):
             phases.tally("http.accept_wait", adds, maxima)
 
     def _unfolded(self) -> dict:
-        """`pooled` / `born` since they were last folded into the
-        table, as the adds of a tally."""
-        now = (self.pooled, self.born)
-        was, self._folded = self._folded, now
-        return {k: v - v0 for k, v, v0 in zip(("pooled", "born"), now, was)
+        """`pooled` / `born` / `accept_waited` since they were last
+        folded into the table, as the adds of a tally."""
+        with self._pool_lock:
+            now = (self.pooled, self.born, self.accept_waited)
+            was, self._folded = self._folded, now
+        return {k: v - v0 for k, v, v0
+                in zip(("pooled", "born", "accept_waited"), now, was)
                 if v != v0}
 
-    def process_request(self, request, client_address):
-        store = self.store
-        if store is not None and store.obs is not None:
-            t = time.perf_counter()
-            n = self._accepts = self._accepts + 1
-            if n % CLOCKED_EVERY:
-                self.accepted_at[request] = t
-            else:
-                ms = _tcp_info(request, _TCPI_LAST_DATA_RECV) \
-                    if self._tcp_info else None
-                self.accepted_at[request] = _Clocked(
-                    t, None if ms is None else ms * 1e-3)
-                if n % LISTEN_SAMPLE_EVERY == 0:
-                    self._sample_listen_queue(store.obs.phases)
-        parked = self._parked
-        if not parked and self._workers is None:
-            self._start_workers()
-        if parked:
-            parked.pop()
-            self.pooled += 1
-            self._handoff.put((request, client_address))
+    def _accepted(self, request, t: float, n: int) -> None:
+        """What follows an `accept()` that returned at `t`, on the
+        thread that made it, the `n`th of its place: the connection's
+        stamp, a `_Clocked` for one in CLOCKED_EVERY, and the listening
+        socket's sample every LISTEN_SAMPLE_EVERYth."""
+        phases = self._phases()
+        if phases is None:
+            return
+        if n % CLOCKED_EVERY:
+            self.accepted_at[request] = t
         else:
-            # liveness, not speed: a `changes` long-poll or a silent
-            # client holds its thread, so with every worker inside a
-            # connection this one gets a thread of its own, as before
+            ms = _tcp_info(request, _TCPI_LAST_DATA_RECV) \
+                if self._tcp_info else None
+            self.accepted_at[request] = _Clocked(
+                t, None if ms is None else ms * 1e-3)
+            if n % LISTEN_SAMPLE_EVERY == 0:
+                self._sample_listen_queue(phases)
+
+    def process_request(self, request, client_address):
+        """The stdlib's road, which `handle_request()` keeps (no
+        worker is at the socket without `serve_forever`): a thread born
+        for this connection."""
+        with self._pool_lock:
             self.born += 1
-            super().process_request(request, client_address)
+            n = self.born
+        self._accepted(request, time.perf_counter(), n)
+        super().process_request(request, client_address)
+
+    def _new_worker(self, slot: int, born: bool) -> _Worker:
+        """Started; under `_pool_lock`, between `_start_workers` and
+        `_stop_workers`."""
+        w = _Worker(self, next(self._worker_ids), slot, self._wake[2], born)
+        w.start()
+        return w
 
     def _start_workers(self) -> None:
-        """The first connection starts the pool. A new worker goes
-        straight to `_handoff`, so its first token is put here."""
+        """`serve_forever`'s first act (a server that never served has
+        no worker). From here on the listening socket does not block:
+        a worker asks it first and waits for it second."""
         with self._pool_lock:
-            if self._workers is not None:       # closed meanwhile
+            if self._workers is not None:       # closed, or serving
                 return
-            self._workers = workers = [
-                threading.Thread(target=self._worker_loop,
-                                 args=(self._parked, self._handoff),
-                                 name=f"http-worker-{i}", daemon=True)
-                for i in range(HANDLER_THREADS)]
-            for w in workers:
-                w.start()
-                self._parked.append(None)
+            self.socket.setblocking(False)
+            r, w = os.pipe()
+            poller = select.poll()
+            poller.register(self.socket, select.POLLIN)
+            poller.register(r, select.POLLIN)
+            self._wake = (r, w, poller)
+            # places of their own, whatever an earlier round's workers
+            # still write
+            first = len(self._pooled)
+            self._pooled += [0] * HANDLER_THREADS
+            self._workers = [self._new_worker(first + i, False)
+                             for i in range(HANDLER_THREADS)]
 
-    def _worker_loop(self, parked, handoff) -> None:
-        """A resident handler thread: a connection at a time through
-        `process_request_thread`, the path of a born thread, until
-        `server_close()`'s sentinel."""
-        while True:
-            item = handoff.get()
+    def _replace(self, w: _Worker) -> None:
+        """Under `_pool_lock`: `w`, one of the pool, ends with the
+        connection it is inside (as a thread born for it would) and
+        another takes its place at once: `HANDLER_THREADS` workers are
+        always cycling, or about to."""
+        w.retired = True
+        self._workers[self._workers.index(w)] = self._new_worker(w.slot, True)
+
+    def _replace_held(self) -> None:
+        """The watch's round. Liveness, not speed: a silent client and
+        a slow reader hold their thread, and under saturation "nobody
+        at the socket" is how things should be, so the sign is a worker
+        that does not come back."""
+        long_ago = time.perf_counter() - WATCH_TICK_S
+        with self._pool_lock:
+            for w in list(self._workers or ()):
+                since = w.since
+                if since is not None and since < long_ago:
+                    self._replace(w)
+
+    def leave_pool(self) -> None:
+        """Said by a handler that is about to wait for something other
+        than its client (a `changes` long-poll, at its condition): it
+        knows now what the watch would find out in a tick. If its
+        thread is one of the pool it is replaced at once, so an edit
+        behind any number of long-polls waits a thread's birth at
+        most, and never a tick."""
+        me = threading.current_thread()
+        if me.__class__ is _Worker and not me.retired:
+            with self._pool_lock:
+                # (not if the watch or the close came first)
+                if not me.retired and self._workers:
+                    self._replace(me)
+
+    def _take(self, me: _Worker):
+        """A worker's next connection. `accept()` FIRST, with nobody's
+        leave: the kernel's queue gives each connection to one caller,
+        so under saturation a connection costs its thread one system
+        call before its handler and wakes no other thread. Only where
+        nothing is queued does a worker take `_turn` and wait for the
+        socket, turn in hand until it has a connection, so that a
+        connection wakes ONE waiting thread and not every idle worker.
+        None: this worker is to end."""
+        item = self._accept()
+        if item is None:
+            with self._turn:
+                while item is None:
+                    if me.retired:
+                        return None
+                    me.poller.poll()    # a connection, or the wake-up
+                    item = self._accept()
+                self.accept_waited += 1     # the turn's own
+        t = time.perf_counter()
+        if me.born:
+            me.born = False
+            with self._pool_lock:
+                self.born += 1
+        else:
+            self._pooled[me.slot] += 1
+        me.since = t
+        self._accepted(item[0], t, self._pooled[me.slot])
+        return item
+
+    def _accept(self):
+        """`get_request()`, or None where nothing is queued (or a
+        connection was aborted in the queue, or no descriptor is left:
+        the stdlib's loop tries again too)."""
+        try:
+            return self.get_request()
+        except OSError:
+            return None
+
+    def _worker_loop(self, me: _Worker) -> None:
+        """A resident handler thread: a connection at a time, each
+        taken from the listening socket by this thread itself and
+        served through `process_request_thread`, the path of a born
+        thread, until it is retired."""
+        while not me.retired:
+            item = self._take(me)
             if item is None:
-                return
+                break
             try:
-                self.process_request_thread(*item, resident=True)
+                if self.verify_request(*item):
+                    self.process_request_thread(*item, resident=True)
+                else:
+                    self.shutdown_request(item[0])
             except Exception:
                 # what `process_request_thread` could not handle itself
                 # (its own `handle_error` failing): the connection is
                 # lost, never the worker
                 traceback.print_exc()
-            item = None
-            parked.append(None)
+            item = me.since = None
+        phases = self._phases()
+        if phases is not None:      # its CPU stays the workers'
+            phases.end_thread("http_workers_s")
 
-    def _stop_workers(self) -> None:
-        """A sentinel a worker, then wait for them (`WORKERS_JOIN_S`
-        in all). No connection is taken off a worker: one inside a
-        request ends it first."""
+    def _stop_workers(self, closed: bool = False) -> None:
+        """Retire every worker, wake the one at the socket, then wait
+        for them (`WORKERS_JOIN_S` in all). No connection is taken off
+        a worker: one inside a request ends it first, and looks at
+        neither the socket nor the pipe again."""
         with self._pool_lock:
-            workers, self._workers = self._workers or (), ()
-            # falsy for good: a connection accepted from here on finds
-            # nobody parked (the workers keep the old deque)
-            self._parked = ()
-        for _ in workers:
-            self._handoff.put(None)
+            workers, wake = self._workers or (), self._wake
+            if self._workers != ():             # () is for good
+                self._workers = () if closed else None
+            self._wake = None
+            for w in workers:
+                w.retired = True
+        if wake is None:
+            return
+        os.write(wake[1], b"x")
         deadline = time.monotonic() + WORKERS_JOIN_S
         for w in workers:
             w.join(max(0.0, deadline - time.monotonic()))
+        os.close(wake[0])
+        os.close(wake[1])
 
     def process_request_thread(self, request, client_address,
                                resident=False):
         """A clocked connection on its thread, from the thread's first
-        line ON THIS CONNECTION (a born thread's first breath, a parked
-        worker's wake) to its last. `http.thread_cpu` is the CPU of
+        line ON THIS CONNECTION (a born thread's first breath, a
+        resident worker's first line after its own `accept()`) to its
+        last. `http.thread_cpu` is the CPU of
         this connection: a born thread's whole life (start, `setup()`,
         parse, the handler, `finish()`, the close) in ONE
         `thread_time()` at its end, a resident thread's difference of
@@ -2068,8 +2242,8 @@ class _Server(ThreadingHTTPServer):
             finally:
                 if store is not None and store.obs is not None:
                     store.obs.phases.stop_probe()
+                self._stop_workers(closed=True)
                 super().server_close()
-                self._stop_workers()
                 phases = self._phases()
                 counts = self._unfolded()
                 if phases is not None and counts:

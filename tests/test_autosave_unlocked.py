@@ -11,6 +11,7 @@ the Python writer under the store lock, as before. Lock order:
 from __future__ import annotations
 
 import json
+import os
 import sys
 import threading
 import time
@@ -372,8 +373,11 @@ def test_writers_a_flusher_and_walks_at_once(tmp_path, monkeypatch):
     real_replace = srv.os.replace
 
     def recording(src, dst):
-        with open(src, "rb") as f:
-            replaced.append((dst, f.read()))
+        # `os` is the whole process's: a server another test left
+        # behind may still be saving its own documents
+        if os.path.dirname(dst) == str(tmp_path):
+            with open(src, "rb") as f:
+                replaced.append((dst, f.read()))
         return real_replace(src, dst)
 
     monkeypatch.setattr(srv.os, "replace", recording)

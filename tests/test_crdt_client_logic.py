@@ -223,6 +223,7 @@ def test_astral_agent_patch_rejected_on_push(tmp_path):
             assert r.read() == b""       # nothing applied
     finally:
         httpd.shutdown()
+        httpd.server_close()
 
 
 def test_transpiler_rejects_chained_assignment(tmp_path):

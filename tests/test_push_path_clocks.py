@@ -42,6 +42,14 @@ def every_thread(monkeypatch):
     monkeypatch.setattr(server_mod, "CLOCKED_EVERY", 1)
 
 
+@pytest.fixture
+def one_worker(monkeypatch):
+    """One resident worker: what its `accept()` leaves in the kernel's
+    queue is still there when it samples the socket (with four, the
+    other three take it meanwhile)."""
+    monkeypatch.setattr(server_mod, "HANDLER_THREADS", 1)
+
+
 def _serve(**kw):
     kw.setdefault("obs_opts", {"sample_rate": 0.0})
     httpd = server_mod.serve(port=0, engine="host", serve_shards=1, **kw)
@@ -113,27 +121,33 @@ def _listen_counts(httpd) -> dict:
 
 # ---- the kernel's queue --------------------------------------------------------
 
-def _hold_the_accept_loop(httpd, seconds):
-    """Arms a hold: the accept loop sleeps `seconds` at the end of its
-    next round. Returns (arm, held)."""
+def _hold_the_next_accept(httpd, seconds):
+    """Arms a hold: the worker that waits at the socket, woken by the
+    next connection, sleeps `seconds` before its `accept()`, turn in
+    hand (what a wait for the interpreter does there), so that
+    connection and what connects meanwhile lie in the kernel. Returns
+    (arm, held)."""
     hold, held = threading.Event(), threading.Event()
+    get_request = httpd.get_request
 
-    def service_actions():      # the accept loop calls it every round
+    def slow_get_request():
         if hold.is_set():
             hold.clear()
             held.set()
             time.sleep(seconds)
-    httpd.service_actions = service_actions
+        return get_request()
+    httpd.get_request = slow_get_request
     return hold, held
 
 
 @needs_tcp_info
-def test_a_held_accept_loop_shows_as_listen_wait_and_depth(every_thread):
+def test_a_held_accept_shows_as_listen_wait_and_depth(every_thread,
+                                                      one_worker):
     httpd, addr = _serve()
     if not httpd._tcp_info:
         _stop(httpd)
         pytest.skip("this kernel has the call and fills nothing in")
-    hold, held = _hold_the_accept_loop(httpd, 0.08)
+    hold, held = _hold_the_next_accept(httpd, 0.08)
     try:
         assert b"200" in _edit(addr, "q")
         assert _wait_for(lambda: _count(httpd, "http.thread_cpu") == 1)
@@ -141,10 +155,10 @@ def test_a_held_accept_loop_shows_as_listen_wait_and_depth(every_thread):
         assert before["count"] == 1
         assert "counts" not in _rows(httpd)["http.accept_wait"]
         # the first accept after the hold samples the listening socket
-        httpd._accepts = server_mod.LISTEN_SAMPLE_EVERY - 1
+        httpd._pooled[0] = server_mod.LISTEN_SAMPLE_EVERY - 1
         hold.set()
-        assert held.wait(timeout=10)
         conns = [_send(addr, _edit_bytes("q")) for _ in range(6)]
+        assert held.wait(timeout=10)
         for s in conns:
             assert b"200" in _answer(s)
         assert _wait_for(lambda: _count(httpd, "http.thread_cpu") == 7)
@@ -188,7 +202,7 @@ def test_a_client_that_sends_late_is_not_charged_to_the_listen_queue(
 
 @pytest.mark.parametrize("kernel", ["none", "unfilled"])
 def test_without_tcp_info_the_queue_is_polled_and_no_row_is_written(
-        monkeypatch, kernel, every_thread):
+        monkeypatch, kernel, every_thread, one_worker):
     """Not Linux (no `TCP_INFO`), or a kernel that has the call and
     fills nothing in (the listening socket's limit reads 0): no
     `http.listen_wait`, no depth; whether a connection waits is still
@@ -198,14 +212,14 @@ def test_without_tcp_info_the_queue_is_polled_and_no_row_is_written(
     else:
         monkeypatch.setattr(server_mod, "_tcp_info", lambda sock, off: 0)
     httpd, addr = _serve()
-    hold, held = _hold_the_accept_loop(httpd, 0.05)
+    hold, held = _hold_the_next_accept(httpd, 0.05)
     try:
         assert httpd._tcp_info is False
         assert b"200" in _edit(addr, "n")
-        httpd._accepts = server_mod.LISTEN_SAMPLE_EVERY - 1
+        httpd._pooled[0] = server_mod.LISTEN_SAMPLE_EVERY - 1
         hold.set()
-        assert held.wait(timeout=10)
         conns = [_send(addr, _edit_bytes("n")) for _ in range(3)]
+        assert held.wait(timeout=10)
         for s in conns:
             assert b"200" in _answer(s)
         assert _wait_for(lambda: _count(httpd, "http.edit") == 4)
@@ -215,7 +229,7 @@ def test_without_tcp_info_the_queue_is_polled_and_no_row_is_written(
         assert _listen_counts(httpd) == {
             "listen_samples": 1, "listen_waiting": 1}
         # an idle server's sample finds nobody waiting
-        httpd._accepts = server_mod.LISTEN_SAMPLE_EVERY - 1
+        httpd._pooled[0] = server_mod.LISTEN_SAMPLE_EVERY - 1
         assert b"200" in _edit(addr, "n")
         assert _wait_for(lambda: _count(httpd, "http.edit") == 5)
         assert _listen_counts(httpd) == {
@@ -287,7 +301,8 @@ def test_thread_cpu_counts_every_connection_refused_ones_included(
         _stop(httpd)
 
 
-def test_one_connection_in_eight_is_clocked():
+def test_one_connection_in_eight_is_clocked(one_worker):
+    # (of each worker's place: with one, of the server's)
     assert server_mod.CLOCKED_EVERY == 8
     assert server_mod.LISTEN_SAMPLE_EVERY % server_mod.CLOCKED_EVERY == 0
     httpd, addr = _serve()
@@ -348,9 +363,23 @@ def test_the_cpu_block_adds_up_and_a_burst_raises_its_threads_class(
         every_thread, monkeypatch, threads, burnt_in):
     if not os.path.isdir("/proc/self/task"):
         pytest.skip("no /proc here")
-    if threads == "born":       # nobody is ever parked: a thread each
-        monkeypatch.setattr(server_mod, "HANDLER_THREADS", 0)
-    httpd, addr = _serve()
+    if threads == "born":
+        # the stdlib's road, a thread a connection: `handle_request()`
+        # in a loop, with no `serve_forever` and so no worker
+        httpd = server_mod.serve(port=0, engine="host", serve_shards=1,
+                                 obs_opts={"sample_rate": 0.0})
+        addr = ("127.0.0.1", httpd.server_address[1])
+        httpd.timeout, over = 0.05, threading.Event()
+
+        def one_at_a_time():
+            while not over.is_set():
+                httpd.handle_request()
+        loop = threading.Thread(target=one_at_a_time, daemon=True)
+        loop.start()
+    else:
+        # no stall of this machine replaces a worker mid-test
+        monkeypatch.setattr(server_mod, "WATCH_TICK_S", 30.0)
+        httpd, addr = _serve()
     try:
         _edit(addr, "b")
         assert _wait_for(lambda: _count(httpd, "http.thread_cpu") == 1)
@@ -385,21 +414,28 @@ def test_the_cpu_block_adds_up_and_a_burst_raises_its_threads_class(
         assert set(httpd.store.scheduler.metrics_json()["phases"]["cpu"]) \
             == set(cpu0)
     finally:
-        _stop(httpd)
+        if threads == "born":
+            over.set()
+            loop.join(timeout=10)
+            assert httpd._workers is None
+            httpd.server_close()
+        else:
+            _stop(httpd)
 
 
 def test_the_servers_threads_are_filed_under_their_class(tmp_path):
     if not os.path.isdir("/proc/self/task"):
         pytest.skip("no /proc here")
     httpd, addr = _serve(data_dir=str(tmp_path))
-    spin = threading.Event()
+    spin, spun = threading.Event(), threading.Event()
 
-    def service_actions():      # on the accept loop's thread
+    def service_actions():      # on the watch's thread, once a tick
         if spin.is_set():
             spin.clear()
             t_end = time.thread_time() + 0.08
             while time.thread_time() < t_end:
                 pass
+            spun.set()
     httpd.service_actions = service_actions
     try:
         _edit(addr, "k")
@@ -410,9 +446,7 @@ def test_the_servers_threads_are_filed_under_their_class(tmp_path):
         table = httpd.store.obs.phases
         cpu0 = table.snapshot()["cpu"]
         spin.set()
-        assert _wait_for(lambda: not spin.is_set())
-        _edit(addr, "k")        # a round of the loop: the spin is over
-        _edit(addr, "k")
+        assert spun.wait(timeout=10)
         cpu1 = table.snapshot()["cpu"]
         assert cpu1["accept_loop_s"] - cpu0["accept_loop_s"] >= 0.05
         assert cpu1["flush_workers_s"] - cpu0["flush_workers_s"] < 0.05
@@ -608,7 +642,7 @@ def test_a_server_with_no_bundle_writes_none_of_it_and_imports_no_jax():
         "doc = json.loads(urllib.request.urlopen(\n"
         "    base + '/metrics', timeout=10).read())\n"
         "assert 'obs' not in doc and 'phases' not in doc['serve'], doc\n"
-        "assert h.accepted_at == {} and h._accepts == 0\n"
+        "assert h.accepted_at == {} and h.pooled + h.born == 41\n"
         "names = [t.name for t in threading.enumerate()]\n"
         "assert 'gil-probe' not in names, names\n"
         "assert 'merge-pump' in names, names\n"

@@ -37,6 +37,7 @@ def test_two_clients_collaborate(tmp_path):
         assert ol.checkout_tip().snapshot() == a.text()
     finally:
         httpd.shutdown()
+        httpd.server_close()
 
 
 def _api(base, doc, action, body):
@@ -113,6 +114,7 @@ def test_browser_dumb_clients_converge(tmp_path):
         assert "red" in w1.text and "very" in w1.text
     finally:
         httpd.shutdown()
+        httpd.server_close()
 
 
 def test_browser_pages_and_graph_endpoints(tmp_path):
@@ -143,6 +145,7 @@ def test_browser_pages_and_graph_endpoints(tmp_path):
         assert at0["text"] == "hello"
     finally:
         httpd.shutdown()
+        httpd.server_close()
 
 
 def test_edit_endpoint_rejects_bad_ops(tmp_path):
@@ -218,6 +221,7 @@ def test_edit_endpoint_rejects_bad_ops(tmp_path):
             assert r.read().decode() == "hello"
     finally:
         httpd.shutdown()
+        httpd.server_close()
 
 
 def test_flush_races_concurrent_edits(tmp_path):
@@ -259,6 +263,7 @@ def test_flush_races_concurrent_edits(tmp_path):
         assert len(ol) > 0 and "alice0" in ol.checkout_tip().snapshot()
     finally:
         httpd.shutdown()
+        httpd.server_close()
 
 
 def test_flush_encode_failure_backoff(tmp_path, capsys):
@@ -394,6 +399,7 @@ def test_changes_long_poll_streams_edits(tmp_path):
         assert resp["op"] == [] and _time.monotonic() - t0 < 3
     finally:
         httpd.shutdown()
+        httpd.server_close()
 
 
 def test_history_strip_endpoint(monkeypatch):
